@@ -132,6 +132,12 @@ LOCK_HIERARCHY: Tuple[LockLevel, ...] = (
     LockLevel("Tracer._lock", 60,
               ("tracer.py", "Tracer", None),
               "span buffer"),
+    LockLevel("*warehouse.py::_seg_lock", 65,
+              ("warehouse.py", None, "<module>"),
+              "telemetry-warehouse segment writer: one sealed rewrite "
+              "per QUERY, held across the file IO and the retention "
+              "prune; taken at query exit with nothing else held, "
+              "above only the flight/metric leaves"),
     LockLevel("FlightRecorder._lock", 70,
               ("recorder.py", "FlightRecorder", None),
               "flight-recorder ring"),

@@ -291,6 +291,11 @@ class GangIciShuffleTransport(IciShuffleTransport):
 
     @staticmethod
     def _local_rows(garr, out: Dict[int, np.ndarray]) -> None:
+        if garr.size == 0:  # zero-width lanes carry no bytes: jax keeps
+            # them whole on every device instead of sharding them
+            for g in range(garr.shape[0]):
+                out[g] = np.zeros(garr.shape[1:], garr.dtype)
+            return
         for s in garr.addressable_shards:
             g = s.index[0].start if isinstance(s.index[0], slice) \
                 else int(s.index[0])

@@ -190,7 +190,7 @@ class TpuAQEJoinExec(UnaryExec):
 
     1. materialize the BUILD-side exchange (its map phase runs);
     2. read the stage size from capacity metadata — NO device sync, so
-       the decision is free even through a tunnel;
+       the decision never waits on the device;
     3. small build (<= spark.sql.autoBroadcastJoinThreshold): demote to
        a broadcast-shaped join — the STREAM side's exchange is skipped
        entirely (its child feeds the join directly), which is the real

@@ -288,19 +288,15 @@ class _MinMax(AggregateFunction):
         if dt.is_floating(t):
             # Spark: NaN is the largest value; -0.0 == 0.0 (keep either)
             key_col = TpuColumnVector(t, data=data, validity=valid)
-            from ..ops.sort_keys import orderable_int
+            from ..ops.sort_keys import (orderable_int,
+                                         orderable_int_to_float)
             keys = orderable_int(key_col)
             fill = jnp.iinfo(keys.dtype).min if self.largest else \
                 jnp.iinfo(keys.dtype).max
             keys = jnp.where(valid, keys, fill)
             red = _seg_max(keys, seg, cap) if self.largest else \
                 _seg_min(keys, seg, cap)
-            # map orderable int back to float: invert the bit transform
-            bits_t = keys.dtype
-            min_int = jnp.array(jnp.iinfo(bits_t).min, bits_t)
-            bits = jnp.where(red < 0, ~(red - min_int), red)
-            out = jax.lax.bitcast_convert_type(
-                bits, t.np_dtype)
+            out = orderable_int_to_float(red, t.np_dtype)
             cnt = _seg_count_valid(valid, seg, cap)
             return TpuColumnVector(t, data=out,
                                    validity=(cnt > 0) & out_live)

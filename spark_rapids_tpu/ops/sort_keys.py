@@ -20,9 +20,11 @@ from .. import datatypes as dt
 from ..columnar.column import TpuColumnVector
 from .strings import gather_window
 
-__all__ = ["SortSpec", "orderable_int", "canonicalize_floats",
+__all__ = ["SortSpec", "orderable_int", "orderable_int_to_float",
+           "canonicalize_floats",
            "string_order_ranks", "string_order_ranks_multi",
-           "sort_permutation", "segment_ids_for_keys", "key_lanes",
+           "lex_sort", "sort_permutation", "segment_ids_for_keys",
+           "key_lanes",
            "lex_leq", "lex_min_tuple"]
 
 _RANK_WINDOW = 7  # bytes per refinement pass: 7 x 9 bits = 63 bits / int64
@@ -54,32 +56,80 @@ def normalize_float_key_col(col: TpuColumnVector) -> TpuColumnVector:
     return col.with_arrays(data=canonicalize_floats(col.data))
 
 
+def _total_order(bits: jax.Array) -> jax.Array:
+    """Signed total-order map over IEEE float bits (int32 or int64
+    lanes): positives (incl. +0, +inf, NaN) keep their bits (already
+    ascending); negatives map to ~bits + INT_MIN, a wrapping add that
+    lands them ascending in the negative int range (-inf lowest,
+    -0.0 -> -1 just below +0.0 -> 0)."""
+    min_int = jnp.array(jnp.iinfo(bits.dtype).min, bits.dtype)
+    return jnp.where(bits < 0, ~bits + min_int, bits)
+
+
+def _total_order_inv(keys: jax.Array) -> jax.Array:
+    """Inverse of ``_total_order``."""
+    min_int = jnp.array(jnp.iinfo(keys.dtype).min, keys.dtype)
+    return jnp.where(keys < 0, ~(keys - min_int), keys)
+
+
+def _f64_is_f32_pair() -> bool:
+    """Off the CPU a float64 lane is a PAIR of float32 (hi + lo, about
+    49 significand bits, float32's exponent range): the TPU has no f64
+    hardware and XLA's X64 rewriter expands every f64 op into arithmetic
+    on the pair (v5e, jax 0.9 — the compiled HLO shows it, and
+    chip_smoke.py's types phase measures it). The rewriter implements
+    bitcast s64->f64 but NOT f64->s64 (UNIMPLEMENTED), so ordering keys
+    are built from the pair instead of the IEEE bits."""
+    return jax.default_backend() != "cpu"
+
+
+_LO_BIAS = 1 << 31
+
+
 def orderable_int(col: TpuColumnVector) -> jax.Array:
     """Map a fixed-width column's data lane to a signed integer lane whose
     ascending order is Spark's ascending order (nulls excluded — handled by
-    a separate rank lane). Floats: -0.0 == 0.0, all NaNs equal and largest."""
+    a separate rank lane). Floats: -0.0 == 0.0, all NaNs equal and largest.
+    ``orderable_int_to_float`` inverts the float maps."""
     t = col.dtype
     d = col.data
     if isinstance(t, dt.BooleanType):
         return d.astype(jnp.int8)
     if dt.is_floating(t):
-        bits_t = jnp.int32 if t.np_dtype == jnp.float32 else jnp.int64
         d = canonicalize_floats(d)
-        if t.np_dtype == jnp.float64 and jax.default_backend() != "cpu":
-            # the TPU stores f64 as f32 (no f64 hardware) and its X64
-            # rewriter cannot bitcast f64<->s64: order via the f32 bits
-            # (a physical no-op for the stored values)
-            d = d.astype(jnp.float32)
-            bits_t = jnp.int32
-        bits = jax.lax.bitcast_convert_type(d, bits_t)
-        # Signed total-order map: positives (incl. +0, +inf, NaN) keep their
-        # bits (already ascending); negatives map to ~bits + INT_MIN, a
-        # wrapping add that lands them ascending in the negative int range
-        # (-inf lowest, -0.0 -> -1 just below +0.0 -> 0).
-        min_int = jnp.array(jnp.iinfo(bits_t).min, bits_t)
-        return jnp.where(bits < 0, ~bits + min_int, bits)
+        if t.np_dtype == jnp.float32:
+            return _total_order(jax.lax.bitcast_convert_type(d, jnp.int32))
+        if not _f64_is_f32_pair():
+            return _total_order(jax.lax.bitcast_convert_type(d, jnp.int64))
+        # (hi, lo) as the device holds them: lexicographic order on the
+        # pair is numeric order, packed into one int64 lane. A cast to
+        # float32 alone would tie every pair of keys closer than 2^-24
+        hi = d.astype(jnp.float32)
+        lo = jnp.where(jnp.isfinite(hi),
+                       (d - hi.astype(jnp.float64)).astype(jnp.float32),
+                       jnp.float32(0))
+        lo = jnp.where(lo == 0, jnp.zeros_like(lo), lo)  # -0.0
+        hk = _total_order(jax.lax.bitcast_convert_type(hi, jnp.int32))
+        lk = _total_order(jax.lax.bitcast_convert_type(lo, jnp.int32))
+        return (hk.astype(jnp.int64) << 32) \
+            | (lk.astype(jnp.int64) + _LO_BIAS)
     # ints / date / timestamp / decimal already compare as ints
     return d
+
+
+def orderable_int_to_float(keys: jax.Array, np_dtype) -> jax.Array:
+    """The float lane an ``orderable_int`` key lane came from (min/max
+    reduce over keys, then map the winner back)."""
+    bitcast = jax.lax.bitcast_convert_type
+    if np_dtype == jnp.float32:
+        return bitcast(_total_order_inv(keys), jnp.float32)
+    if not _f64_is_f32_pair():
+        return bitcast(_total_order_inv(keys), jnp.float64)
+    hk = (keys >> 32).astype(jnp.int32)
+    lk = ((keys & 0xFFFFFFFF) - _LO_BIAS).astype(jnp.int32)
+    hi = bitcast(_total_order_inv(hk), jnp.float32)
+    lo = bitcast(_total_order_inv(lk), jnp.float32)
+    return hi.astype(jnp.float64) + lo.astype(jnp.float64)
 
 
 def string_order_ranks_multi(cols: Sequence[TpuColumnVector],
@@ -249,16 +299,82 @@ def lex_min_tuple(blanes: Sequence[jax.Array], bvalid: jax.Array):
     return best
 
 
+def _unsigned_pieces(lane: jax.Array, one_bit: bool):
+    """A signed orderable lane as (uint32 array, bit width) pieces, most
+    significant first, whose unsigned order is the lane's signed order."""
+    if one_bit:  # a 0/1 rank lane
+        return [(lane.astype(jnp.uint32), 1)]
+    bits = jnp.iinfo(lane.dtype).bits  # signed ints only
+    if bits == 64:
+        u = jax.lax.bitcast_convert_type(lane, jnp.uint64) \
+            ^ jnp.uint64(1 << 63)
+        return [((u >> jnp.uint64(32)).astype(jnp.uint32), 32),
+                (u.astype(jnp.uint32), 32)]
+    bias = 1 << (bits - 1)
+    return [((lane.astype(jnp.int32) + bias).astype(jnp.uint32), bits)]
+
+
+def lex_sort(lanes: Sequence[jax.Array], one_bit: Sequence[int] = ()):
+    """(perm, boundary): the STABLE permutation ordering rows
+    lexicographically by the signed-int ``lanes`` (most significant
+    first), and — in sorted order — whether each row's lane tuple differs
+    from the row before it (row 0: True). ``one_bit`` names the lanes
+    that only hold 0/1 (rank lanes).
+
+    Same order as ``lax.sort(lanes + (row index,), num_keys=all)``, with
+    fewer operands: the chip's compiler takes 30-40 s PER 32-BIT KEY
+    OPERAND of a 2^21-row sort (v5e, PR 21), and an int8 rank lane costs
+    as much as an int32 one. So the lanes' significant bits are
+    concatenated, most significant first, into as few uint32 words as
+    hold them, and the row index (the stability tiebreak; log2(n) bits)
+    rides in the low bits of the last word — for one int32 key 2 words
+    instead of 4 operands, for two int32 keys 3 instead of 6."""
+    n = lanes[0].shape[0]
+    idx_bits = max(1, (n - 1).bit_length())
+    words: List[jax.Array] = []
+    cur, free = jnp.zeros((n,), jnp.uint32), 32
+    for li, lane in enumerate(lanes):
+        for piece, w in _unsigned_pieces(lane, li in one_bit):
+            while w > 0:
+                take = min(w, free)
+                part = piece if take == 32 else \
+                    (piece >> (w - take)) & jnp.uint32((1 << take) - 1)
+                cur = cur | (part << (free - take))
+                w -= take
+                free -= take
+                if free == 0:
+                    words.append(cur)
+                    cur, free = jnp.zeros((n,), jnp.uint32), 32
+    if free < idx_bits:  # no room left beside the key bits
+        words.append(cur)
+        cur, free = jnp.zeros((n,), jnp.uint32), 32
+    key_bits_in_last = 32 - free
+    words.append(cur | jnp.arange(n, dtype=jnp.uint32))
+    swords = jax.lax.sort(tuple(words), num_keys=len(words))
+    perm = (swords[-1] & jnp.uint32((1 << idx_bits) - 1)).astype(jnp.int32)
+    keyed = list(swords[:-1])
+    if key_bits_in_last:
+        keyed.append(swords[-1] >> idx_bits)
+    boundary = jnp.zeros((n,), jnp.bool_).at[0].set(True)
+    for w_ in keyed:
+        boundary = boundary | jnp.concatenate(
+            [jnp.zeros((1,), jnp.bool_), w_[1:] != w_[:-1]])
+    return perm, boundary
+
+
+def _rank_lane_positions(n_keys: int) -> List[int]:
+    """Where ``_key_lanes`` puts its 0/1 lanes: the live rank first, then
+    one null rank before each key's value lane."""
+    return [0] + list(range(1, 2 * n_keys + 1, 2))
+
+
 def sort_permutation(key_cols: Sequence[TpuColumnVector],
                      specs: Sequence[SortSpec],
                      live: jax.Array) -> jax.Array:
     """Stable permutation ordering rows by the keys, padding rows last."""
-    n = live.shape[0]
     lanes = _key_lanes(key_cols, specs, live)
-    idx = jnp.arange(n, dtype=jnp.int32)
-    # idx participates as the least-significant key -> stable
-    out = jax.lax.sort(tuple(lanes) + (idx,), num_keys=len(lanes) + 1)
-    return out[-1]
+    perm, _ = lex_sort(lanes, _rank_lane_positions(len(key_cols)))
+    return perm
 
 
 def segment_ids_for_keys(key_cols: Sequence[TpuColumnVector],
@@ -267,17 +383,9 @@ def segment_ids_for_keys(key_cols: Sequence[TpuColumnVector],
     adjacent (live rows first), seg ids over the sorted order, and the
     group count among live rows. Grouping equality is Spark's: null==null,
     NaN==NaN, -0.0==0.0."""
-    n = live.shape[0]
     specs = [SortSpec()] * len(key_cols)
     lanes = _key_lanes(key_cols, specs, live)
-    idx = jnp.arange(n, dtype=jnp.int32)
-    sorted_all = jax.lax.sort(tuple(lanes) + (idx,),
-                              num_keys=len(lanes) + 1)
-    sorted_lanes, perm = sorted_all[:-1], sorted_all[-1]
-    boundary = jnp.zeros((n,), jnp.bool_).at[0].set(True)
-    for lane in sorted_lanes:
-        boundary = boundary | jnp.concatenate(
-            [jnp.zeros((1,), jnp.bool_), lane[1:] != lane[:-1]])
+    perm, boundary = lex_sort(lanes, _rank_lane_positions(len(key_cols)))
     from .gather import inclusive_int_cumsum
     seg = inclusive_int_cumsum(boundary) - 1
     live_sorted = live[perm]

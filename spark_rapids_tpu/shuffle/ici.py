@@ -34,12 +34,6 @@ import jax.numpy as jnp
 import numpy as np
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
-# jax.shard_map moved between JAX releases: top-level alias (>=0.5),
-# jax.experimental before that
-_shard_map = getattr(jax, "shard_map", None)
-if _shard_map is None:
-    from jax.experimental.shard_map import shard_map as _shard_map
-
 from ..columnar.batch import TpuBatch, bucket_bytes, bucket_rows
 from ..columnar.column import TpuColumnVector
 from .transport import ShuffleTransport, ShuffleWriteHandle
@@ -177,19 +171,32 @@ def make_ici_all_to_all(mesh: Mesh, axis: str = "x"):
                      tuple(P(axis, None) for _ in ndims),
                      P(axis, None), P(axis),
                      tuple(P(axis, None) for _ in range(n_char)))
-        return jax.jit(_shard_map(spmd, mesh=mesh, in_specs=in_specs,
+        return jax.jit(jax.shard_map(spmd, mesh=mesh, in_specs=in_specs,
                                      out_specs=out_specs))
 
-    def fn(datas, valids, pids, live, char_offs=(), char_bytes=(),
-           char_caps=()):
-        datas = tuple(datas)
+    def program(datas, char_offs, char_caps):
         key = (tuple(d.ndim for d in datas), len(char_offs),
                tuple(char_caps))
         if key not in cache:
             cache[key] = build(*key)
-        return cache[key](datas, tuple(valids), pids, live,
-                          tuple(char_offs), tuple(char_bytes))
+        return cache[key]
 
+    def fn(datas, valids, pids, live, char_offs=(), char_bytes=(),
+           char_caps=()):
+        return program(datas, char_offs, char_caps)(
+            tuple(datas), tuple(valids), pids, live, tuple(char_offs),
+            tuple(char_bytes))
+
+    def lower(datas, valids, pids, live, char_offs=(), char_bytes=(),
+              char_caps=()):
+        """The same program lowered, not run (arrays or
+        ShapeDtypeStructs): chip_smoke.py and tests/test_chip_compile.py
+        read the collective out of ``.compile().as_text()``."""
+        return program(datas, char_offs, char_caps).lower(
+            tuple(datas), tuple(valids), pids, live, tuple(char_offs),
+            tuple(char_bytes))
+
+    fn.lower = lower
     return fn
 
 
@@ -222,7 +229,7 @@ def make_ici_broadcast(mesh: Mesh, axis: str = "x"):
                     tuple(P(axis, None) for _ in ndims), P(axis, None))
         out_specs = (tuple(lane(nd) for nd in ndims),
                      tuple(P(axis, None) for _ in ndims), P(axis, None))
-        return jax.jit(_shard_map(spmd, mesh=mesh, in_specs=in_specs,
+        return jax.jit(jax.shard_map(spmd, mesh=mesh, in_specs=in_specs,
                                      out_specs=out_specs))
 
     def fn(datas, valids, live):
@@ -441,6 +448,51 @@ def _mesh_shard(mesh: Mesh, axis: str):
         mesh, P(axis, *([None] * (a.ndim - 1)))))
 
 
+def _owner_rows(garr) -> List[jax.Array]:
+    """Row d of a (D, ...) array sharded over its leading axis, as the
+    single-device array chip d already holds. Indexing the global array
+    instead (``garr[d]``) runs an SPMD slice whose result is REPLICATED
+    on every mesh device: an all-gather of the whole exchange in
+    disguise, which a virtual CPU mesh never shows."""
+    if garr.size == 0:  # zero-width lanes carry no bytes: jax keeps
+        # them whole on every device instead of sharding them
+        return [np.zeros(garr.shape[1:], garr.dtype)] * garr.shape[0]
+    rows: List[jax.Array] = [None] * garr.shape[0]
+    for s in garr.addressable_shards:
+        rows[s.index[0].start] = s.data.reshape(s.data.shape[1:])
+    return rows
+
+
+def _consumer_device():
+    """Where the in-process executor consumes an exchanged stage: one
+    task runs the whole plan, on the device everything else it touches
+    (arrow uploads, scans) already lives on."""
+    return jax.config.jax_default_device or jax.local_devices()[0]
+
+
+def _tight(landed: TpuBatch, rows) -> TpuBatch:
+    """A landed batch compacted, on the chip that owns it, to the bucket
+    of the rows it really holds (``rows``: the epoch's host-side count).
+    Landing reserves the block capacity once per SOURCE device, so a
+    landed batch is ndev x its live rows wide; left like that, every
+    program downstream runs over the padding and capacity-bounded
+    concats multiply it (at 2^23 rows over four chips the aggregate's
+    final program met 32M-row lanes, where the chip's compiler
+    segfaulted in rehearsal — PR 21)."""
+    from ..ops.gather import ensure_compacted, shrink_batch
+    return shrink_batch(ensure_compacted(landed),
+                        bucket_rows(max(int(rows), 1)))
+
+
+def _on_device(b: TpuBatch, device) -> TpuBatch:
+    rc = b.row_count  # a host scalar stays one (no dispatch to read it)
+    cols, sel, rc = jax.device_put(
+        (b.columns, b.selection, rc if isinstance(rc, jax.Array) else None),
+        device)
+    return TpuBatch(cols, b.schema, b.row_count if rc is None else rc,
+                    selection=sel)
+
+
 def _len_lane_indices(spec):
     """Lane indices whose landed live sums size the ragged rebuilds."""
     return [li for li, (_, _, kind, _) in enumerate(spec)
@@ -552,9 +604,11 @@ def ici_broadcast_batches(mesh: Mesh, batches: List[TpuBatch],
         valids = tuple(shard(jnp.stack(ls)) for ls in lane_valids)
         od, ov, ol = bcast(datas, valids, shard(jnp.stack(lives)))
 
-        # every shard holds the full table; shard 0's view builds the
+        # every shard holds the full table; shard 0's copy builds the
         # engine-facing batch. One readback for all payload totals.
-        live_full = ol[0]
+        od = [_owner_rows(a)[:1] for a in od]
+        ov = [_owner_rows(a)[:1] for a in ov]
+        live_full = _owner_rows(ol)[0]
         flat_caps: Dict[int, int] = {}
         len_lanes = _len_lane_indices(spec)
         if len_lanes:
@@ -789,10 +843,23 @@ class IciShuffleTransport(ShuffleTransport):
         if not self._owns_partition(partition_id, nparts):
             return
         SHUF_PARTS_FETCHED.labels("ici").inc()
+        # landed rows stay on the chip that owns the partition until
+        # read; the single consuming task takes them on its own device
+        consumer = _consumer_device()
         for b in self._results.get(shuffle_id, [[]] * nparts)[
                 partition_id]:
             SHUF_BYTES_FETCHED.labels("ici").inc(b.device_size_bytes())
-            yield b
+            yield _on_device(b, consumer)
+
+    def landed_devices(self, shuffle_id: int) -> List[List[int]]:
+        """Per partition, the ids of the devices its landed batches sit
+        on, read off the arrays themselves (realizes the collective if
+        it is still pending) — chip_smoke.py's evidence that an
+        exchange really spread over the mesh."""
+        self._realize(shuffle_id)
+        return [sorted({d.id for b in part for c in b.columns
+                        for a in c.arrays() for d in a.devices()})
+                for part in self._results.get(shuffle_id, [])]
 
     def unregister_shuffle(self, shuffle_id: int):
         with self._lock:
@@ -894,7 +961,7 @@ class IciShuffleTransport(ShuffleTransport):
         si = 0
         for li, (ci, path, kind, _) in enumerate(spec):
             if kind == "str_mat":
-                payloads[li] = (out_chars[si], cb_list[si])
+                payloads[li] = (_owner_rows(out_chars[si]), cb_list[si])
                 si += 1
 
         # ONE readback for everything host sizing needs this epoch:
@@ -928,6 +995,10 @@ class IciShuffleTransport(ShuffleTransport):
             st[0, :len(rows)] += rows
             st[1, :len(rows)] += rows * (epoch_bytes / total_rows)
 
+        # each chip rebuilds its own partition from the rows it holds
+        own_datas = [_owner_rows(a) for a in out_datas]
+        own_valids = [_owner_rows(a) for a in out_valids]
+        own_live = _owner_rows(out_live)
         for d in range(ndev):
             if sizes_host[0][d] == 0:
                 continue
@@ -939,17 +1010,19 @@ class IciShuffleTransport(ShuffleTransport):
                 else:  # arr_len sits after (arr_mat, arr_vmat)
                     flat_caps[li - 2] = bucket_rows(total)
             cols, pid_lane = _unpack_device(
-                schema, lane_meta, out_datas, out_valids, d, out_live[d],
+                schema, lane_meta, own_datas, own_valids, d, own_live[d],
                 flat_caps, payloads=payloads, ndev=ndev)
             landed = TpuBatch(cols, schema, ndev * cap,
-                              selection=out_live[d])
+                              selection=own_live[d])
             if not fold:
-                results[d].append(landed)
+                results[d].append(_tight(landed, sizes_host[0][d]))
             else:
                 # split the landed rows by original partition id
                 for p in range(d, nparts, ndev):
-                    results[p].append(
-                        landed.with_selection(pid_lane == p))
+                    if pcounts_host[p]:
+                        results[p].append(_tight(
+                            landed.with_selection(pid_lane == p),
+                            pcounts_host[p]))
 
 
 def _pad1(a, cap: int):

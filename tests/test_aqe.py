@@ -67,7 +67,7 @@ def _skewed_source(n=4000, hot_frac=0.8, seed=7):
 def _aqe_conf(**extra):
     base = {
         "spark.sql.adaptive.enabled": "true",
-        # tests run untunneled: let the local transport sync for stats
+        # let the local transport sync for exact stats
         "spark.rapids.sql.adaptive.freeStatsOnly": "false",
         # tiny thresholds so test-sized data triggers both paths
         "spark.sql.adaptive.advisoryPartitionSizeInBytes": "4096",

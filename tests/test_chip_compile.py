@@ -1,0 +1,229 @@
+"""Chip-compiler guards: the main path's programs compiled for a described
+(not attached) TPU v5e, at real shapes, by the TPU compiler this sandbox
+has installed.
+
+Nothing runs and nothing is timed; a case passes when the chip's compiler
+accepts the program (or, for the two documented refusals, still refuses
+it in the documented words). All cases live in THIS file and describe the
+topology inside a module-scoped fixture: only one process may load the
+TPU library, so the call must not happen at import, in a ``skipif``, in
+``parametrize`` or in ``conftest.py`` (on-chip-measurement guide, §2).
+The persistent compile cache is off around them — a compile for a
+described chip can be written to it but never read back.
+"""
+import os
+
+import jax
+import jax.numpy as jnp
+import numpy as np
+import pytest
+from jax.sharding import (Mesh, NamedSharding, PartitionSpec as P,
+                          SingleDeviceSharding)
+
+from spark_rapids_tpu import datatypes as dt
+from spark_rapids_tpu.columnar.batch import TpuBatch
+from spark_rapids_tpu.columnar.column import TpuColumnVector
+
+ROWS = 1 << 21          # the engine's default batch capacity at bench sizes
+KERNEL_ROWS = 8 * 2048 * 128   # bench.py's Pallas A/B shape (8 chunks)
+
+
+@pytest.fixture(scope="module")
+def topo():
+    os.environ.setdefault("TPU_LOG_DIR", "disabled")
+    from jax.experimental import topologies
+    try:
+        return topologies.get_topology_desc(platform="tpu",
+                                            topology_name="v5e:2x2")
+    except Exception as e:  # noqa: BLE001 — no TPU compiler installed here
+        pytest.skip(f"no v5e:2x2 topology can be described here: {e}")
+
+
+@pytest.fixture(scope="module")
+def one_chip(topo):
+    return SingleDeviceSharding(topo.devices[0])
+
+
+@pytest.fixture(scope="module", autouse=False)
+def no_persistent_cache():
+    from jax.experimental.compilation_cache import compilation_cache
+    before = jax.config.jax_enable_compilation_cache
+    jax.config.update("jax_enable_compilation_cache", False)
+    compilation_cache.reset_cache()
+    yield
+    jax.config.update("jax_enable_compilation_cache", before)
+    compilation_cache.reset_cache()
+
+
+@pytest.fixture
+def chip(one_chip, no_persistent_cache):
+    """``chip(shape, dtype)`` -> a ShapeDtypeStruct on the described chip."""
+    def sds(shape, dtype):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+    return sds
+
+
+def _col(chip, dtype, n=ROWS):
+    return TpuColumnVector(dtype, data=chip((n,), dtype.np_dtype),
+                           validity=chip((n,), jnp.bool_))
+
+
+def _batch(chip, fields, n=ROWS):
+    schema = dt.Schema([dt.StructField(name, t, True) for name, t in fields])
+    return TpuBatch([_col(chip, t, n) for _, t in fields], schema,
+                    chip((), jnp.int32))
+
+
+# --- the Pallas kernels that are kept ------------------------------------------
+
+def test_masked_product_sum_pallas_compiles(chip):
+    from spark_rapids_tpu.ops.pallas_kernels import masked_product_sum_pallas
+    f32 = chip((KERNEL_ROWS,), jnp.float32)
+    compiled = masked_product_sum_pallas.lower(
+        f32, f32, f32, chip((KERNEL_ROWS,), jnp.int32), False).compile()
+    assert "tpu_custom_call" in compiled.as_text()
+
+
+def test_fused_filter_agg_pallas_compiles(chip):
+    from spark_rapids_tpu.ops.pallas_kernels import fused_filter_agg_pallas
+    f32 = chip((KERNEL_ROWS,), jnp.float32)
+    i32 = chip((KERNEL_ROWS,), jnp.int32)
+    compiled = fused_filter_agg_pallas.lower(
+        i32, f32, f32, f32, i32, False).compile()
+    assert "tpu_custom_call" in compiled.as_text()
+
+
+# --- the q6 stage: decode, then filter -> project -> partial aggregate --------
+
+def test_q6_filter_project_partial_agg_chain_compiles(chip):
+    """The epilogue the fused scan program splices after decode, composed
+    exactly as ``exec.base.fused_batches`` composes it, at a 2^20-row
+    capacity."""
+    import bench
+    from spark_rapids_tpu.exec.base import (DeviceBatchSourceExec, ExecCtx,
+                                            UnaryExec)
+    fields = [("l_quantity", dt.FLOAT32), ("l_extendedprice", dt.FLOAT32),
+              ("l_discount", dt.FLOAT32), ("l_shipdate", dt.DATE)]
+    batch = _batch(chip, fields, 1 << 20)
+    agg, _ = bench.build_q6(DeviceBatchSourceExec([], batch.schema))
+    fns, node = [], agg.children[0]
+    while isinstance(node, UnaryExec) and node.device_fn() is not None:
+        fns.insert(0, node.device_fn())
+        node = node.children[0]
+    assert len(fns) == 2  # filter, project
+    fns.append(agg._partial)
+
+    def composed(b, ectx):
+        for f in fns:
+            b = f(b, ectx)
+        return b
+    jax.jit(composed, static_argnums=1).lower(
+        batch, ExecCtx().eval_ctx).compile()
+
+
+def test_parquet_dictionary_rle_chunk_decode_compiles(chip):
+    """One dictionary/RLE float32 column chunk of a 2^20-row group through
+    the device decoder (run table -> funnel-shift unpack -> dictionary
+    gather -> null scatter), built from shapes alone."""
+    from spark_rapids_tpu.io.parquet_device import _decode_device
+    cap = 1 << 20
+    jax.jit(_decode_device, static_argnums=(6,)).lower(
+        chip((cap // 2,), jnp.uint32),      # bit-packed index words
+        chip((256, 4), jnp.int64),          # run table
+        chip((4096,), jnp.float32),         # dictionary page
+        chip((cap // 32 + 2,), jnp.uint32),  # definition-level words
+        chip((64, 4), jnp.int64),           # definition-level runs
+        chip((), jnp.int64), cap).compile()
+
+
+# --- sorts and scans at the engine's batch size ---------------------------------
+
+def test_compaction_sort_compiles(chip):
+    """``compaction_indices``: the (int8 keep-rank, int32 index) sort
+    every filter compaction and exchange split pays."""
+    from spark_rapids_tpu.ops.gather import compaction_indices
+    jax.jit(compaction_indices).lower(chip((ROWS,), jnp.bool_)).compile()
+
+
+@pytest.mark.slow  # ~30-70 s each on the sandbox's cores (CHANGES.md, PR 21)
+@pytest.mark.parametrize("key", ["int32", "int64", "float64-off-cpu"])
+def test_engine_sort_permutation_compiles(chip, key, monkeypatch):
+    """``sort_permutation`` with the lanes exec/sort.py and the sort-based
+    group-by really hand to ``lax.sort``: (int8 live rank, int8 null rank,
+    value lane, int32 row index). int64 keys ride as a RAW 64-bit lane;
+    float64 keys off the CPU ride the packed (hi, lo) int64 lane."""
+    from spark_rapids_tpu.ops.sort_keys import SortSpec, sort_permutation
+    if key == "float64-off-cpu":
+        monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+        t = dt.FLOAT64
+    else:
+        t = dt.INT32 if key == "int32" else dt.INT64
+    jax.jit(lambda c, live: sort_permutation([c], [SortSpec()], live)) \
+        .lower(_col(chip, t), chip((ROWS,), jnp.bool_)).compile()
+
+
+def test_inclusive_int_cumsum_compiles_as_int32(chip):
+    """int32 by design: the int64 cumsum costs 3x the compile (PR 21), so
+    no caller may widen it."""
+    from spark_rapids_tpu.ops.gather import inclusive_int_cumsum
+    for dtype in (jnp.bool_, jnp.int32, jnp.int64):
+        out = jax.eval_shape(inclusive_int_cumsum,
+                             jax.ShapeDtypeStruct((8,), dtype))
+        assert out.dtype == jnp.int32
+    jax.jit(inclusive_int_cumsum).lower(chip((ROWS,), jnp.int32)).compile()
+
+
+# --- float64 on the chip ----------------------------------------------------------
+
+def test_f64_to_s64_bitcast_is_refused_and_s64_to_f64_is_not(chip):
+    """What ops/sort_keys.py rests on: XLA's X64 rewriter for the TPU
+    cannot bitcast float64 -> int64 (float64 is a pair of float32 there),
+    while int64 -> float64 — the Parquet DOUBLE decode's direction —
+    compiles."""
+    def to_bits(x):
+        return jax.lax.bitcast_convert_type(x, jnp.int64)
+
+    def from_bits(x):
+        return jax.lax.bitcast_convert_type(x, jnp.float64)
+    with pytest.raises(jax.errors.JaxRuntimeError, match="UNIMPLEMENTED"):
+        jax.jit(to_bits).lower(chip((ROWS,), jnp.float64)).compile()
+    jax.jit(from_bits).lower(chip((ROWS,), jnp.int64)).compile()
+
+
+def test_f64_order_key_round_trip_compiles_off_cpu(chip, monkeypatch):
+    """The off-CPU float64 ordering key and its inverse (min/max reduce)
+    use no refused bitcast."""
+    from spark_rapids_tpu.ops.sort_keys import (orderable_int,
+                                                orderable_int_to_float)
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+
+    def there_and_back(c):
+        return orderable_int_to_float(orderable_int(c), jnp.float64)
+    jax.jit(there_and_back).lower(_col(chip, dt.FLOAT64)).compile()
+
+
+# --- four chips: the ICI exchange ---------------------------------------------------
+
+def test_ici_all_to_all_compiles_on_four_chips(topo, no_persistent_cache):
+    """``make_ici_all_to_all`` over a Mesh of the four described devices:
+    one int32 lane, one int64 lane and one string payload at 2^20 rows
+    per device; the compiled program must hold the collective."""
+    from spark_rapids_tpu.shuffle.ici import make_ici_all_to_all
+    mesh = Mesh(np.array(topo.devices), ("x",))
+    ndev, cap, char_cap, pair_bytes = 4, 1 << 20, 1 << 23, 1 << 21
+
+    def sds(shape, dtype):
+        return jax.ShapeDtypeStruct(
+            (ndev,) + shape, dtype,
+            sharding=NamedSharding(mesh, P("x", *([None] * len(shape)))))
+    datas = (sds((cap,), jnp.int32), sds((cap,), jnp.int64),
+             sds((cap, 0), jnp.int8), sds((cap,), jnp.int32))
+    valids = tuple(sds((cap,), jnp.bool_) for _ in datas)
+    compiled = make_ici_all_to_all(mesh).lower(
+        datas, valids, sds((cap,), jnp.int32), sds((cap,), jnp.bool_),
+        char_offs=(sds((cap + 1,), jnp.int32),),
+        char_bytes=(sds((char_cap,), jnp.uint8),),
+        char_caps=(pair_bytes,)).compile()
+    assert "all-to-all" in compiled.as_text()
+    per_device = compiled.memory_analysis()
+    assert per_device.temp_size_in_bytes < (12 << 30), per_device

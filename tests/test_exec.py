@@ -197,25 +197,3 @@ def test_pallas_masked_product_sum_matches_xla():
     want = float(masked_product_sum_xla(q, p, d, s))
     got = float(masked_product_sum_pallas(q, p, d, s, True))
     assert abs(got - want) <= 1e-3 * max(1.0, abs(want)), (got, want)
-
-
-def test_pallas_bitonic_sort_matches_xla():
-    # interpret mode on the CPU mesh; the real-chip A/B lives in
-    # bench.py (pallas_sort_ab)
-    import numpy as np
-    import jax.numpy as jnp
-    from spark_rapids_tpu.ops.pallas_kernels import sort_pallas, sort_xla
-    rng = np.random.default_rng(5)
-    for n, dtype in ((256, np.float32), (4096, np.float32),
-                     (1024, np.int32)):
-        if dtype == np.float32:
-            k = rng.uniform(-1e6, 1e6, n).astype(dtype)
-        else:
-            k = rng.integers(-10**6, 10**6, n).astype(dtype)
-        got = np.asarray(sort_pallas(jnp.asarray(k), True))
-        want = np.asarray(sort_xla(jnp.asarray(k)))
-        assert (got == want).all(), (n, dtype)
-    # non-power-of-two and tiny inputs are rejected, not silently wrong
-    import pytest as _pytest
-    with _pytest.raises(ValueError):
-        sort_pallas(jnp.zeros(300, jnp.float32), True)

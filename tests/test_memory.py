@@ -725,3 +725,51 @@ def test_leak_report(tmp_path):
     assert "test_memory" in rep  # the alloc site traceback names us
     sb.release()
     assert mm.leak_report() == "no leaked catalog entries"
+
+
+# what a TPU v5e raised on exhaustion (jax 0.9.0, chip run of PR 21)
+_V5E_PROGRAM_OOM = ("RESOURCE_EXHAUSTED: Error allocating device buffer: "
+                    "Attempting to allocate 64.00G. That was not possible. "
+                    "There are 15.75G free.; (0x0x0_HBM0)")
+
+
+@pytest.mark.parametrize("exc,is_oom", [
+    (TpuRetryOOM("injected"), True),
+    # a program that cannot get its buffers: the runtime's own error type
+    ("runtime:" + _V5E_PROGRAM_OOM, True),
+    # an eager array creation that cannot: same status text, ValueError
+    (ValueError(_V5E_PROGRAM_OOM.replace("64.00G", "3.00G")), True),
+    ("runtime:INVALID_ARGUMENT: something else entirely", False),
+    # marker text inside an unrelated error must not be split-and-retried
+    (ValueError("bad conf: RESOURCE_EXHAUSTED is not a valid mode"), False),
+    (RuntimeError(_V5E_PROGRAM_OOM), False),
+])
+def test_is_oom_error_recognises_what_the_v5e_raises(exc, is_oom):
+    from jax.errors import JaxRuntimeError
+    from spark_rapids_tpu.memory import _is_oom_error
+    if isinstance(exc, str):
+        exc = JaxRuntimeError(exc.split(":", 1)[1])
+    assert _is_oom_error(exc) is is_oom
+
+
+def test_device_memory_comes_from_the_device_or_fails_on_a_tpu(monkeypatch):
+    """The budget derives from the device's own bytes_limit; only a CPU
+    backend (which reports none) gets the 6 GiB stand-in."""
+    import jax
+
+    class _Dev:
+        def __init__(self, platform, stats):
+            self.platform, self._stats = platform, stats
+            self.device_kind = "TPU v5 lite" if platform == "tpu" else "cpu"
+
+        def memory_stats(self):
+            return self._stats
+
+    def with_device(dev):
+        monkeypatch.setattr(jax, "local_devices", lambda: [dev])
+        return DeviceMemoryManager._device_memory()
+    assert with_device(_Dev("cpu", None)) == 6 << 30
+    assert with_device(_Dev("tpu", {"bytes_limit": 16909336064})) \
+        == 16909336064
+    with pytest.raises(RuntimeError, match="bytes_limit"):
+        with_device(_Dev("tpu", {"bytes_in_use": 1}))

@@ -168,3 +168,99 @@ def test_sort_computed_key_with_nulls():
         source([IntegerGen(null_frac=0.4), IntegerGen(null_frac=0.4),
                 LongGen(nullable=False)]))
     assert_tpu_and_cpu_plan_equal(plan)
+
+
+# --- packed sort keys and the off-CPU float64 key (PR 21) ---------------------
+
+@pytest.mark.parametrize("seed", range(6))
+def test_lex_sort_packs_lanes_without_changing_the_order(seed):
+    """``lex_sort`` sorts bit-packed uint32 words instead of one operand
+    per lane; permutation and boundaries must equal the plain
+    lexicographic ``lax.sort`` over (lanes..., row index)."""
+    import jax
+    import jax.numpy as jnp
+    import numpy as np
+    from spark_rapids_tpu.ops.sort_keys import lex_sort
+    rng = np.random.default_rng(seed)
+    for _ in range(12):
+        n = int(rng.choice([1, 2, 7, 64, 1000, 4096]))
+        lanes, one_bit = [], []
+        for i in range(int(rng.integers(1, 7))):
+            kind = rng.choice(["bit", "i8", "i16", "i32", "i64"])
+            if kind == "bit":
+                lanes.append(rng.integers(0, 2, n).astype(np.int8))
+                one_bit.append(i)
+                continue
+            t = {"i8": np.int8, "i16": np.int16, "i32": np.int32,
+                 "i64": np.int64}[kind]
+            info = np.iinfo(t)
+            if rng.random() < 0.5:  # few distinct values incl. extremes
+                lanes.append(rng.choice(
+                    [info.min, info.max, 0, -1, 1], n).astype(t))
+            else:
+                lanes.append(rng.integers(info.min, info.max, n,
+                                          dtype=t, endpoint=True))
+        jl = [jnp.asarray(x) for x in lanes]
+        perm, boundary = lex_sort(jl, one_bit)
+        ref = jax.lax.sort(tuple(jl) + (jnp.arange(n, dtype=jnp.int32),),
+                           num_keys=len(jl) + 1)
+        assert (np.asarray(perm) == np.asarray(ref[-1])).all()
+        want = np.zeros(n, bool)
+        want[0] = True
+        for lane in ref[:-1]:
+            lane = np.asarray(lane)
+            want[1:] |= lane[1:] != lane[:-1]
+        assert (np.asarray(boundary) == want).all()
+
+
+def test_float64_key_off_the_cpu_orders_below_float32_precision(
+        monkeypatch):
+    """Off the CPU float64 is a pair of float32 and f64->s64 bitcasts are
+    refused, so the key is built from (hi, lo): same order as the IEEE
+    key, keys 2^-40 apart stay apart, and the inverse recovers the value
+    to the pair's precision."""
+    import jax
+    import jax.numpy as jnp
+    import numpy as np
+    from spark_rapids_tpu import datatypes as dt
+    from spark_rapids_tpu.columnar.column import TpuColumnVector
+    from spark_rapids_tpu.ops.sort_keys import (orderable_int,
+                                                orderable_int_to_float)
+    vals = np.array([0.0, -0.0, 1.0, 1 + 2.0 ** -40, 1 + 2.0 ** -39,
+                     -1 - 2.0 ** -40, -1.0, np.inf, -np.inf, np.nan, 1e30,
+                     -1e30, 3.5e-20, 0.1, 1 / 3, -0.1, 123456789.125])
+    col = TpuColumnVector(dt.FLOAT64, data=jnp.asarray(vals),
+                          validity=jnp.ones(len(vals), bool))
+    ieee = np.asarray(orderable_int(col))
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    pair = orderable_int(col)
+    assert pair.dtype == jnp.int64
+    assert (np.argsort(ieee, kind="stable")
+            == np.argsort(np.asarray(pair), kind="stable")).all()
+    back = np.asarray(orderable_int_to_float(pair, jnp.float64))
+    finite = np.isfinite(vals) & (vals != 0)
+    assert np.max(np.abs(back[finite] - vals[finite])
+                  / np.abs(vals[finite])) < 2.0 ** -44
+    assert np.isnan(back[9]) and back[7] == np.inf and back[8] == -np.inf
+
+
+def test_float64_min_max_off_the_cpu_branch(monkeypatch):
+    """Min/Max reduce over the ordering key and map the winner back —
+    through the pair key too (the old int32-bits key could not be bitcast
+    back to float64 at all)."""
+    import jax
+    import numpy as np
+    import pyarrow as pa
+    from spark_rapids_tpu.exec.aggregate import TpuHashAggregateExec
+    from spark_rapids_tpu.exec.base import HostBatchSourceExec, collect_arrow
+    from spark_rapids_tpu.expr import Alias, UnresolvedColumn as col
+    from spark_rapids_tpu.expr.aggregates import Max, Min
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    x = np.array([1 + 2.0 ** -30, 1 + 2.0 ** -31, -2.5, 7.0, 1.0, -2.5 - 2.0 ** -30])
+    k = np.array([0, 0, 1, 1, 0, 1], np.int32)
+    rb = pa.record_batch({"k": pa.array(k), "x": pa.array(x)})
+    got = collect_arrow(TpuHashAggregateExec(
+        [col("k")], [Alias(Min(col("x")), "lo"), Alias(Max(col("x")), "hi")],
+        HostBatchSourceExec([rb]))).to_pandas().sort_values("k")
+    assert got["lo"].tolist() == [1.0, -2.5 - 2.0 ** -30]
+    assert got["hi"].tolist() == [1 + 2.0 ** -30, 7.0]

@@ -1,6 +1,12 @@
 #!/usr/bin/env python
 """Schema checks for the observability outputs CI smoke exercises.
 
+A CPU-ONLY tool: its smokes run queries in this process and then start
+``TpuProcessCluster`` workers, and a chip belongs to one process at a
+time — so the platform is pinned to the CPU below, before jax is
+imported, wherever the script is started (tools/ci_smoke.sh does the
+same for every step; the on-chip proof is ``chip_smoke.py``).
+
 Two validators and one driver:
 
 - ``--trace FILE``   validate a Chrome trace_event JSON written under
@@ -63,6 +69,7 @@ import sys
 # runnable from anywhere: the package lives next to this script's parent
 sys.path.insert(0, os.path.dirname(os.path.dirname(
     os.path.abspath(__file__))))
+os.environ["JAX_PLATFORMS"] = "cpu"  # never takes a chip (see above)
 
 _SAMPLE_RE = re.compile(
     r"^([a-zA-Z_:][a-zA-Z0-9_:]*)"          # metric name

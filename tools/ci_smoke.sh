@@ -1,6 +1,9 @@
 #!/usr/bin/env bash
 # CI smoke: the gate that keeps a syntax error (or any import-breaking
-# change) out of a seed.  Escalating checks; fails fast:
+# change) out of a seed.  A CPU gate: every step runs with
+# JAX_PLATFORMS=cpu (several start worker processes, and a chip belongs
+# to one process at a time); the on-chip proof is `python chip_smoke.py`
+# through the chip tool.  Escalating checks; fails fast:
 #
 #   1. byte-compile every module           (catches SyntaxError anywhere)
 #   2. import the package                  (catches import-time errors)
