@@ -1,6 +1,6 @@
 """The quickest proof that the engine still starts on the chip.
 
-    python chip_smoke.py            # one TPU chip: q6, nds, join, types
+    python chip_smoke.py            # one TPU chip: types, q6, nds
     python chip_smoke.py --chips 4  # four chips: the ICI exchange only
 
 ONE process drives the engine's normal query path — ``TpuSession`` ->
@@ -13,10 +13,10 @@ seeds into the git-ignored ``.bench_cache/``; nothing is read that a
 clean checkout does not hold. It sets no ``JAX_PLATFORMS``, no
 ``XLA_FLAGS`` and starts no child process.
 
-Every query runs twice in the same process (cold, then warm after the
-first result was downloaded) with the XLA compile requests, persistent
-cache hits and compile seconds of each run printed beside its wall
-seconds. The last line of stdout is the result:
+Every one-chip query runs twice in the same process (cold, then warm
+after the first result was downloaded) with the XLA compile requests,
+persistent cache hits and compile seconds of each run printed beside
+its wall seconds. The last line of stdout is the result:
   {"ok": true, "device": {"platform": "tpu", "kind": "...", "count": 1}}
 
 The phases are functions of their sizes (``main`` passes the real ones)
@@ -102,14 +102,15 @@ def meter() -> CompileMeter:
     return _METER
 
 
-def cold_warm(name, once):
-    """Run ``once()`` twice; print wall seconds and compiler traffic of
-    each run; return (cold result, warm result). The warm run starts
-    after the cold run's result was downloaded, so a dispatch regime that
-    changed with the first readback would show here."""
+def cold_warm(name, once, labels=("cold", "warm")):
+    """Run ``once()`` once per label (twice by default); print wall
+    seconds and compiler traffic of each run; return the results. The
+    warm run starts after the cold run's result was downloaded, so a
+    dispatch regime that changed with the first readback would show
+    here."""
     m = meter()
     out = []
-    for label in ("cold", "warm"):
+    for label in labels:
         before = m.snapshot()
         t0 = time.perf_counter()
         out.append(once())
@@ -364,20 +365,20 @@ def phase_ici(devices, n_fact, n_dim, map_batches_per_chip=2):
     dimension side, so byte payloads cross the interconnect too), and
     the folded-partition group-by of ``__graft_entry__`` with its string
     key — each compared with (a) an independent oracle and (b) the same
-    plan on one device with the default local transport, exactly. Then
-    the evidence that the exchange really spread: landed partitions sit
-    on distinct devices, every device's peak memory rose where the
-    backend reports it, and the compiled program holds an all-to-all."""
+    plan on one device with the default local transport, exactly. After
+    the ICI run, the evidence that the exchange really spread: landed
+    partitions sit on distinct devices, every device's peak memory rose
+    where the backend reports it, and the compiled program holds an
+    all-to-all."""
     import jax
     from jax.sharding import Mesh
 
     from __graft_entry__ import (exchange_groupby_plan,
                                  exchange_join_agg_plan, groupby_batches)
     from spark_rapids_tpu import datatypes as dt
-    from spark_rapids_tpu.columnar.arrow_bridge import (arrow_to_device,
-                                                        engine_schema)
+    from spark_rapids_tpu.columnar.arrow_bridge import arrow_to_device
     from spark_rapids_tpu.config import RapidsConf
-    from spark_rapids_tpu.exec.base import (DeviceBatchSourceExec, ExecCtx,
+    from spark_rapids_tpu.exec.base import (DeviceBatchSourceExec,
                                             collect_arrow_cpu)
     from spark_rapids_tpu.expr import (Alias, Length, StartsWith,
                                        UnresolvedColumn as col)
@@ -386,7 +387,6 @@ def phase_ici(devices, n_fact, n_dim, map_batches_per_chip=2):
     from spark_rapids_tpu.expr.conditional import If
     from spark_rapids_tpu.planner import TpuOverrides
     from spark_rapids_tpu.shuffle.ici import IciShuffleTransport
-    from spark_rapids_tpu.shuffle.partitioner import HashPartitioning
     n_dev = len(devices)
     mesh = Mesh(np.array(devices), ("x",))
     # AQE off: its join switch would demote this join to a broadcast
@@ -409,26 +409,13 @@ def phase_ici(devices, n_fact, n_dim, map_batches_per_chip=2):
     grp = rng.integers(1, 13, n_dim).astype(np.int32)
     tag_id = rng.integers(0, len(tags), n_dim)
     n_map = map_batches_per_chip * n_dev
-    per, per_pair = n_fact // n_map, n_fact // n_map // n_dev
-    assert per_pair * n_dev * n_map == n_fact, (n_fact, n_map, n_dev)
-    # Fact keys are drawn so that every (map batch, destination) pair
-    # holds exactly per_pair rows under the engine's own hash
-    # partitioner: every landed batch then fills its bucket exactly. The
-    # aggregate's final program runs over the SUM of its partials'
-    # capacities, and with the slightest imbalance at this scale that sum
-    # buckets to 16M rows, where the chip's compiler segfaulted in every
-    # rehearsal of this phase (PERF.md, open questions).
-    dim_keys = pa.record_batch({"dk": pa.array(np.arange(n_dim,
-                                                         dtype=np.int32))})
-    owner = HashPartitioning([col("dk")], n_dev) \
-        .bind(engine_schema(dim_keys.schema)) \
-        .partition_ids_cpu(dim_keys, ExecCtx(conf).eval_ctx)
-    keys_of = [np.nonzero(owner == p)[0].astype(np.int32)
-               for p in range(n_dev)]
-    fk = np.concatenate([
-        rng.permutation(np.concatenate(
-            [rng.choice(keys_of[p], per_pair) for p in range(n_dev)]))
-        for _ in range(n_map)])
+    per = n_fact // n_map
+    assert per * n_map == n_fact, (n_fact, n_map)
+    # uniform keys: partitions come out as unequal as the engine's hash
+    # partitioner makes them (at 2^23 rows a destination receives
+    # 2^20 +- ~900 rows an epoch, so landed batches straddle a capacity
+    # bucket)
+    fk = rng.integers(0, n_dim, n_fact).astype(np.int32)
 
     def sources():
         fact = [arrow_to_device(pa.record_batch({
@@ -470,9 +457,7 @@ def phase_ici(devices, n_fact, n_dim, map_batches_per_chip=2):
     # the dimension's STRING lane crosses the interconnect as byte
     # payloads, rides through the join, and reaches the result as two
     # exact integers per group: its characters, and its rows starting "s"
-    # (as a group KEY it would put 8M-row string lanes into the
-    # aggregate's final program; string keys are compared exactly in the
-    # small group-by below)
+    # (string group KEYS are compared exactly in the small group-by below)
     keys = ["grp"]
     one, zero = Literal(1, dt.INT32), Literal(0, dt.INT32)
     string_aggs = [
@@ -480,24 +465,11 @@ def phase_ici(devices, n_fact, n_dim, map_batches_per_chip=2):
         Alias(Sum(If(StartsWith(col("tag"), "s"), one, zero)), "s_rows")]
     ici_plan = exchange_join_agg_plan(*sources(), n_dev, keys,
                                       WitnessTransport, string_aggs)
-    ici_join, ici_warm = cold_warm(
-        "ici_join_agg", lambda: collect_sorted(ici_plan, keys))
-    assert ici_join.equals(ici_warm)
-    # per collect: one epoch for the dimension side, and one per n_dev
-    # map batches for the fact side
-    epochs = len(witness["programs"])
-    assert epochs == 2 * (1 + map_batches_per_chip), epochs
-    # no landed batch is wider than one map batch (balanced keys, and
-    # landing compacts to the bucket of the rows it holds)
-    assert max(witness["capacities"]) == per, \
-        (sorted(set(witness["capacities"])), per)
-    # the same plan on ONE device with the default local transport — and
-    # one partition: that transport hands every partition on at its map
-    # batch's full capacity, so four would quadruple what the final
-    # aggregate program has to span
-    local_join = collect_sorted(exchange_join_agg_plan(
-        *sources(), 1, keys, extra_aggs=string_aggs), keys)
-
+    # once: four chips wait while the host compiles, and a repeat would
+    # show nothing the one-chip phases' warm passes do not
+    [ici_join] = cold_warm("ici_join_agg",
+                           lambda: collect_sorted(ici_plan, keys),
+                           labels=("cold",))
     want = {"t": np.zeros(13, np.int64), "n": np.zeros(13, np.int64),
             "tag_chars": np.zeros(13, np.int64),
             "s_rows": np.zeros(13, np.int64)}
@@ -507,14 +479,54 @@ def phase_ici(devices, n_fact, n_dim, map_batches_per_chip=2):
     np.add.at(want["tag_chars"], fact_grp, np.char.str_len(fact_tag))
     np.add.at(want["s_rows"], fact_grp, np.char.startswith(fact_tag, "s"))
     live = np.nonzero(want["n"])[0]
-    for got, label in ((ici_join, "ici"), (local_join, "local")):
+
+    def assert_equals_numpy(got, label):
         assert (got["grp"].to_numpy() == live).all(), (label, got)
         for name, w in want.items():
             assert (got[name].to_numpy() == w[live]).all(), (label, name)
-    assert ici_join.equals(local_join), (ici_join, local_join)
+
+    assert_equals_numpy(ici_join, "ici")
     print(f"  ici join+agg {n_fact} x {n_dim} rows over {n_dev} devices: "
-          f"{len(ici_join)} groups equal numpy and the one-device local "
-          f"transport exactly")
+          f"{len(ici_join)} groups equal numpy exactly", flush=True)
+
+    # -- was the work really spread?
+    # one epoch for the dimension side, one per n_dev map batches for the
+    # fact side
+    epochs = len(witness["programs"])
+    assert epochs == 1 + map_batches_per_chip, epochs
+    # landing compacts: no batch handed over is as wide as the n_dev
+    # blocks it landed in
+    capacities = sorted(set(witness["capacities"]))
+    assert capacities[-1] < n_dev * per, (capacities, per)
+    landed = witness["landed"]
+    owners = sorted({d for part in landed for d in part})
+    print(f"  ici partitions handed to the join had landed on device ids "
+          f"{landed} at capacities {capacities}")
+    assert all(len(part) == 1 for part in landed) \
+        and owners == sorted(d.id for d in devices), (landed, devices)
+    peaks_after = _peaks(devices)
+    print(f"  ici peak_bytes_in_use per device before={peaks_before} "
+          f"after={peaks_after}")
+    if any(peaks_after):  # the CPU backend reports no memory stats
+        assert all(a > b for a, b in zip(peaks_after, peaks_before)), \
+            (peaks_before, peaks_after)
+    program, (args, kwargs) = witness["programs"][0]
+    hlo = program.lower(*args, **kwargs).compile().as_text()
+    n_a2a = hlo.count(" all-to-all(") + hlo.count(" all-to-all-start(")
+    print(f"  ici compiled exchange holds {n_a2a} all-to-all ops over "
+          f"{n_dev} devices", flush=True)
+    assert n_a2a > 0, hlo[:2000]
+
+    # -- the same plan, as many partitions, on ONE device with the default
+    # local transport
+    [local_join] = cold_warm(
+        "local_join_agg", lambda: collect_sorted(exchange_join_agg_plan(
+            *sources(), n_dev, keys, extra_aggs=string_aggs), keys),
+        labels=("cold",))
+    assert_equals_numpy(local_join, "local")
+    assert ici_join.equals(local_join), (ici_join, local_join)
+    print(f"  ici join+agg equals the same {n_dev}-partition plan on one "
+          f"device with the local transport exactly", flush=True)
 
     # -- the folded-partition group-by (2 x n_dev partitions, string key)
     rbs = groupby_batches(2 * n_dev, np.random.default_rng(7))
@@ -530,26 +542,6 @@ def phase_ici(devices, n_fact, n_dim, map_batches_per_chip=2):
         (g_ici, g_local, g_cpu)
     print(f"  ici group-by {2 * n_dev} folded partitions: {len(g_ici)} "
           f"groups equal the CPU oracle and the local transport exactly")
-
-    # -- was the work really spread?
-    landed = witness["landed"]
-    owners = sorted({d for part in landed for d in part})
-    print(f"  ici partitions handed to the join had landed on device ids "
-          f"{landed}")
-    assert all(len(part) == 1 for part in landed) \
-        and owners == sorted(d.id for d in devices), (landed, devices)
-    peaks_after = _peaks(devices)
-    print(f"  ici peak_bytes_in_use per device before={peaks_before} "
-          f"after={peaks_after}")
-    if any(peaks_after):  # the CPU backend reports no memory stats
-        assert all(a > b for a, b in zip(peaks_after, peaks_before)), \
-            (peaks_before, peaks_after)
-    program, (args, kwargs) = witness["programs"][0]
-    hlo = program.lower(*args, **kwargs).compile().as_text()
-    n_a2a = hlo.count(" all-to-all(") + hlo.count(" all-to-all-start(")
-    print(f"  ici compiled exchange holds {n_a2a} all-to-all ops over "
-          f"{n_dev} devices")
-    assert n_a2a > 0, hlo[:2000]
 
 
 # --- main --------------------------------------------------------------------
@@ -575,45 +567,21 @@ def report_device(devices):
         (budget, stats)
 
 
-#: The driver allows the script 1200 s, compilation included, and a cold
-#: NDS query alone costs the chip's compiler 7 minutes (all phases cold:
-#: 1175 s of compilation plus 140 s of running). So after the two phases
-#: that always run, a phase STARTS only if its cold cost (wall seconds on
-#: the v5e host with an empty compile cache: cold compile + two runs,
-#: measured in PR 21 — CHANGES.md) still fits this budget. Cold, that is
-#: types, q6, q_topn and the join in ~880 s; with a warm cache every
-#: phase runs, in 166 s. A skipped phase is printed as skipped; it has
-#: not failed.
-BUDGET_S = 950
-
-
-def single_chip_plan():
-    """(name, cold cost in seconds, function, args, kwargs) in run order;
-    a cost of None means the phase always runs."""
-    nds = (1 << 21, 1 << 19)
+def single_chip_phases():
+    """(name, function, arguments) in run order. The list is STATIC —
+    what runs never depends on the clock or on what the compile cache
+    holds — and sized so that a run with an EMPTY cache fits the 1200 s
+    the driver allows: about 1000 s on the v5e host, nearly all of it
+    compilation (PERF.md sections 5 and 6). ``phase_join`` (bench.py's
+    join + group-by at 2^23 x 2^17 rows, 290 s cold) does not fit beside
+    the two NDS queries and is not in the list; it ran on the chip in
+    PR 21's calls 1, 2 and 7 (CHANGES.md) and runs on the CPU mesh in
+    tests/test_chip_smoke.py."""
     return [
-        ("types", None, phase_types, (), {}),
-        ("q6", None, phase_q6, (bench.SF_ROWS, 8, 1 << 20), {}),
-        ("nds:q_topn", 460, phase_nds, nds, {"queries": ("q_topn",)}),
-        ("join", 300, phase_join, (1 << 23, 1 << 17), {}),
-        ("nds:q3", 520, phase_nds, nds, {"queries": ("q3",)}),
+        ("types", phase_types, ()),
+        ("q6", phase_q6, (bench.SF_ROWS, 8, 1 << 20)),
+        ("nds", phase_nds, (1 << 21, 1 << 19)),
     ]
-
-
-def run_plan(plan, t_start, budget_s=BUDGET_S):
-    """Run the plan's phases in order, skipping one only when its cold
-    cost no longer fits the time budget; returns the names that ran."""
-    ran = []
-    for name, cold_s, fn, args, kwargs in plan:
-        elapsed = time.perf_counter() - t_start
-        if cold_s is not None and elapsed + cold_s > budget_s:
-            print(f"== phase {name} skipped: its cold cost ({cold_s} s) "
-                  f"does not fit the {budget_s - elapsed:.0f} s left of "
-                  f"the {budget_s} s budget", flush=True)
-            continue
-        phase(name, fn, *args, **kwargs)
-        ran.append(name)
-    return ran
 
 
 def result_line(devices) -> str:
@@ -636,12 +604,11 @@ def main(argv=None):
           f"(JAX_COMPILATION_CACHE_DIR "
           f"{'set' if os.environ.get('JAX_COMPILATION_CACHE_DIR') else 'unset'})")
     report_device(devices)
-    if args.chips == 4:
-        ran = run_plan([("ici", None, phase_ici,
-                         (devices, 1 << 23, 1 << 17), {})], t_start)
-    else:
-        ran = run_plan(single_chip_plan(), t_start)
-    print(f"total: phases={','.join(ran)} "
+    phases = [("ici", phase_ici, (devices, 1 << 23, 1 << 17))] \
+        if args.chips == 4 else single_chip_phases()
+    for name, fn, fn_args in phases:
+        phase(name, fn, *fn_args)
+    print(f"total: phases={','.join(name for name, _, _ in phases)} "
           f"elapsed_s={time.perf_counter() - t_start:.1f} "
           f"{meter().since(before)}")
     for d in devices:

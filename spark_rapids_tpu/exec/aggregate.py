@@ -498,11 +498,19 @@ class TpuHashAggregateExec(UnaryExec):
         elif sum(p.device_size_bytes() for p in partials) \
                 > ctx.mm.budget // 4:
             merged = self._merge_bounded(partials, ctx)
+        elif sum(p.capacity for p in partials) > ctx.conf.batch_size_rows:
+            # A partial keeps its INPUT's capacity however few groups it
+            # holds, so the capacity-bounded concat below would hand the
+            # final a batch wider than the engine's own batch bound made
+            # of padding alone (8 partials of 2^20: an 8M-row sort to
+            # merge 12 groups; 32 of them: 32M). Past that bound, size by
+            # the live group counts instead: one readback for all of them
+            # (~0.6 ms dispatch+block on the v5e: chip run, PR 21).
+            merged = concat_batches(partials)
         else:
             # capacity-bounded concat: sync-free (no row-count readback),
-            # so the partial->final pipeline never waits on the device
-            # mid-query; the final's sort tolerates the extra padding.
-            # (What a mid-query readback costs on the chip: not measured.)
+            # so a small partial->final pipeline never waits on the
+            # device mid-query; the final's sort tolerates the padding
             from ..ops.concat import concat_batches_bounded
             merged = concat_batches_bounded(partials)
         out = self._jit_final(merged, ctx.eval_ctx)

@@ -209,11 +209,13 @@ def _null_rank_lane(validity: jax.Array, spec: SortSpec) -> jax.Array:
 
 
 def _key_lanes(key_cols: Sequence[TpuColumnVector],
-               specs: Sequence[SortSpec],
-               live: jax.Array) -> List[jax.Array]:
-    """Orderable lanes, most-significant first: a live-rank lane (padding
-    always last), then per key a null-placement lane and a value lane."""
+               specs: Sequence[SortSpec], live: jax.Array):
+    """(lanes, one_bit): orderable lanes, most-significant first — a
+    live-rank lane (padding always last), then per key a null-placement
+    lane and a value lane — and the positions of the lanes that only
+    hold 0/1 (the rank lanes), which ``lex_sort`` packs into one bit."""
     lanes: List[jax.Array] = [jnp.where(live, jnp.int8(0), jnp.int8(1))]
+    one_bit = [0]
     for col, spec in zip(key_cols, specs):
         if col.is_string_like:
             vals = string_order_ranks(col, live & col.validity)
@@ -227,9 +229,10 @@ def _key_lanes(key_cols: Sequence[TpuColumnVector],
             vals = jnp.where(col.validity, vals, jnp.zeros_like(vals))
         if not spec.ascending:
             vals = ~vals  # total reversal of the signed int order
+        one_bit.append(len(lanes))
         lanes.append(_null_rank_lane(col.validity, spec))
         lanes.append(vals)
-    return lanes
+    return lanes, one_bit
 
 
 def key_lanes_vs_bounds(col: TpuColumnVector, bcol: TpuColumnVector,
@@ -263,7 +266,7 @@ def key_lanes_vs_bounds(col: TpuColumnVector, bcol: TpuColumnVector,
 def key_lanes(key_cols, specs, live):
     """Public name for the orderable lane stack (out-of-core merge uses it
     to compare rows against run boundaries in the same rank space)."""
-    return _key_lanes(key_cols, specs, live)
+    return _key_lanes(key_cols, specs, live)[0]
 
 
 def lex_leq(lanes: Sequence[jax.Array],
@@ -362,18 +365,11 @@ def lex_sort(lanes: Sequence[jax.Array], one_bit: Sequence[int] = ()):
     return perm, boundary
 
 
-def _rank_lane_positions(n_keys: int) -> List[int]:
-    """Where ``_key_lanes`` puts its 0/1 lanes: the live rank first, then
-    one null rank before each key's value lane."""
-    return [0] + list(range(1, 2 * n_keys + 1, 2))
-
-
 def sort_permutation(key_cols: Sequence[TpuColumnVector],
                      specs: Sequence[SortSpec],
                      live: jax.Array) -> jax.Array:
     """Stable permutation ordering rows by the keys, padding rows last."""
-    lanes = _key_lanes(key_cols, specs, live)
-    perm, _ = lex_sort(lanes, _rank_lane_positions(len(key_cols)))
+    perm, _ = lex_sort(*_key_lanes(key_cols, specs, live))
     return perm
 
 
@@ -384,8 +380,7 @@ def segment_ids_for_keys(key_cols: Sequence[TpuColumnVector],
     group count among live rows. Grouping equality is Spark's: null==null,
     NaN==NaN, -0.0==0.0."""
     specs = [SortSpec()] * len(key_cols)
-    lanes = _key_lanes(key_cols, specs, live)
-    perm, boundary = lex_sort(lanes, _rank_lane_positions(len(key_cols)))
+    perm, boundary = lex_sort(*_key_lanes(key_cols, specs, live))
     from .gather import inclusive_int_cumsum
     seg = inclusive_int_cumsum(boundary) - 1
     live_sorted = live[perm]

@@ -476,9 +476,8 @@ def _tight(landed: TpuBatch, rows) -> TpuBatch:
     Landing reserves the block capacity once per SOURCE device, so a
     landed batch is ndev x its live rows wide; left like that, every
     program downstream runs over the padding and capacity-bounded
-    concats multiply it (at 2^23 rows over four chips the aggregate's
-    final program met 32M-row lanes, where the chip's compiler
-    segfaulted in rehearsal — PR 21)."""
+    concats multiply it. The count costs nothing: the epoch's one
+    readback already holds it."""
     from ..ops.gather import ensure_compacted, shrink_batch
     return shrink_batch(ensure_compacted(landed),
                         bucket_rows(max(int(rows), 1)))

@@ -193,6 +193,31 @@ def test_groupby_string_keys_multi_batch():
     assert_tpu_and_cpu_plan_equal(plan, ignore_order=True)
 
 
+@pytest.mark.parametrize("batch_rows,final_capacity", [
+    (1 << 20, 1024),  # default bound: capacity-bounded concat (256+256+512)
+    (512, 128),       # capacities past the bound: sized by the groups
+])
+@pytest.mark.parametrize("key_gen", [IntegerGen(min_val=0, max_val=10),
+                                     StringGen(max_len=2, charset="abcdefghij",
+                                               special=False)],
+                         ids=["int_key", "string_key"])
+def test_final_is_sized_by_live_groups_past_the_batch_bound(
+        key_gen, batch_rows, final_capacity):
+    """A partial keeps its input's capacity however few groups it holds;
+    once the partials' capacities add up to more than batchSizeRows the
+    final runs over their live groups, not over the padding."""
+    from spark_rapids_tpu.config import RapidsConf
+    from spark_rapids_tpu.exec.base import ExecCtx
+    rbs = [gen_table([key_gen, LongGen()], n, seed=s)
+           for n, s in [(200, 1), (150, 2), (300, 3)]]
+    plan = agg_plan(HostBatchSourceExec(rbs), [col("c0")],
+                    [Alias(Sum(col("c1")), "s"), Alias(Count(), "c")])
+    conf = RapidsConf({"spark.rapids.sql.batchSizeRows": str(batch_rows)})
+    [out] = list(plan.execute(ExecCtx(conf)))
+    assert out.capacity == final_capacity
+    assert_tpu_and_cpu_plan_equal(plan, conf=conf, ignore_order=True)
+
+
 def test_groupby_computed_key_with_nulls():
     # Regression: null==null must hold for computed group keys whose data
     # lane holds garbage under nulls.
