@@ -27,6 +27,7 @@ from ..expr.base import Alias, Expression, bind_expr
 from ..ops.concat import concat_batches
 from ..ops.gather import gather_column
 from ..ops.sort_keys import segment_ids_for_keys
+from ..programs import named_jit
 from .base import ExecCtx, TpuExec, UnaryExec, fused_batches
 from .basic import bind_all
 
@@ -479,7 +480,8 @@ class TpuHashAggregateExec(UnaryExec):
             return
         if self._jit_partial is None:
             self._jit_partial = jax.jit(self._partial, static_argnums=1)
-            self._jit_final = jax.jit(self._final, static_argnums=1)
+            self._jit_final = named_jit("agg_final", self._final,
+                                        static_argnums=1)
         op_time = ctx.metric(self, "opTime")
         # the partial phase fuses with the project/filter chain feeding it
         # into one XLA program per batch (fused_batches)

@@ -28,6 +28,7 @@ from ..columnar.arrow_bridge import arrow_to_device, device_to_arrow
 from ..columnar.batch import TpuBatch
 from ..config import RapidsConf
 from ..expr.base import EvalCtx
+from ..programs import named_jit
 
 __all__ = ["ExecCtx", "TpuMetric", "TpuExec", "LeafExec", "UnaryExec",
            "HostBatchSourceExec", "OpContract", "collect_arrow",
@@ -118,8 +119,10 @@ class ExecCtx:
         # (the reference's GpuSemaphore/RapidsBufferCatalog are singletons)
         self.mm = DeviceMemoryManager.shared(self.conf)
         # span tracer: the shared no-op unless spark.rapids.trace.dir is
-        # set; cluster workers overwrite this with a tracer joined to
-        # the driver's trace context
+        # set or a JAX profiler session is running NOW (so a caller that
+        # profiles starts its session before it makes the ctx); cluster
+        # workers overwrite this with a tracer joined to the driver's
+        # trace context
         from ..obs.tracer import tracer_from_conf
         self.tracer = tracer_from_conf(self.conf)
         from ..obs.metrics import maybe_start_http_server
@@ -498,8 +501,6 @@ def fused_batches(consumer: TpuExec, ctx: ExecCtx, tail_fn=None,
     wrapper may re-run them over batch halves, yielding each half as its
     own stream item (the exchange writer's side effects therefore live
     outside the tail, after the yield)."""
-    import jax
-
     node = consumer.children[0]
     fns = []
     fused_nodes = []
@@ -546,8 +547,9 @@ def fused_batches(consumer: TpuExec, ctx: ExecCtx, tail_fn=None,
                     # residual chain compute, not a re-count of the
                     # scan's read/plan/upload wall
                     t0 = time.perf_counter()
-                    with ctx.tracer.span(label, cat="op",
-                                         args={"fused": "scan"}):
+                    with ctx.tracer.span(label, cat="op", kind="op",
+                                         args={"op": label,
+                                               "fused": "scan"}):
                         if ctx.sync_metrics and isinstance(out, TpuBatch):
                             out.block_until_ready()
                         _record_stage_time(ctx, metric, t0, out)
@@ -566,13 +568,15 @@ def fused_batches(consumer: TpuExec, ctx: ExecCtx, tail_fn=None,
             return b
         # hold the fns alongside the program: the key is content-based,
         # but the compiled program closes over these exact callables
-        entry = (jax.jit(composed, static_argnums=1), fns)
+        entry = (named_jit("fused_stage", composed, static_argnums=1),
+                 fns)
         cache[key] = entry
     jitted = entry[0]
     rows = ctx.metric(consumer, "numOutputRows") if ctx.sync_metrics \
         else None
     for b in node.execute(ctx):
-        with ctx.tracer.span(label, cat="op"):
+        with ctx.tracer.span(label, cat="op", kind="op",
+                             args={"op": label, "fused": "stage"}):
             t0 = time.perf_counter()
             # split-and-retry on device OOM: the fused stage re-runs
             # over batch halves (memory.py; SURVEY.md §5.3 layer 3);
@@ -650,8 +654,8 @@ class HostBatchSourceExec(LeafExec):
         t = ctx.metric(self, "uploadTime")
         label = self.node_label()
         for rb in self._normalized():
-            with ctx.tracer.span(label, cat="op",
-                                 args={"phase": "upload"}):
+            with ctx.tracer.span(label, cat="op", kind="op",
+                                 args={"op": label, "phase": "upload"}):
                 t0 = time.perf_counter()
                 b = arrow_to_device(rb, self._schema)
                 t.value += time.perf_counter() - t0

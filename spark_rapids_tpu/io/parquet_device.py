@@ -65,6 +65,8 @@ from .. import datatypes as dt
 from ..columnar.batch import (bucket_bytes, bucket_fine,
                               bucket_fine_even, bucket_rows)
 from ..columnar.column import TpuColumnVector
+from ..obs.tracer import StageClock
+from ..programs import module_name, named_jit
 
 __all__ = ["plan_chunk", "decode_chunk_device",
            "decode_row_group_device", "merge_chunk_plans", "ChunkPlan",
@@ -1046,31 +1048,31 @@ _JIT_LOCK = threading.Lock()
 _STAGING = threading.local()
 
 
-def _staging_arena(n_words: int) -> Tuple[np.ndarray, float]:
-    """Pooled per-thread host staging arena for the fused-decode blob:
-    segments are written in place instead of a fresh ``np.concatenate``
-    per row group. Before handing the buffer out, wait for the PREVIOUS
-    decode dispatched from this thread — its outputs being ready proves
-    the program (and therefore the async host->device copy feeding it)
-    consumed the buffer; blocking only on the ``device_put`` result is
-    NOT enough on backends that defer the copy into the consuming
-    computation. Returns (buffer, seconds spent in that wait —
-    transfer time, accounted to upload)."""
-    import time
-
+def _await_staging_arena(clock: StageClock) -> None:
+    """Before this thread's staging arena is written again, wait for
+    the PREVIOUS decode dispatched from this thread — its outputs being
+    ready proves the program (and therefore the async host->device copy
+    feeding it) consumed the buffer; blocking only on the ``device_put``
+    result is NOT enough on backends that defer the copy into the
+    consuming computation. The wait is a stage of its own
+    (``arena_wait``): it is the device being busy, not a transfer."""
     import jax
-    wait = 0.0
     pending = getattr(_STAGING, "pending", None)
     if pending is not None:
-        t0 = time.perf_counter()
-        jax.block_until_ready(pending)
-        wait = time.perf_counter() - t0
+        with clock.stage("arena_wait"):
+            jax.block_until_ready(pending)
         _STAGING.pending = None
+
+
+def _staging_arena(n_words: int) -> np.ndarray:
+    """Pooled per-thread host staging arena for the fused-decode blob:
+    segments are written in place instead of a fresh ``np.concatenate``
+    per row group (``_await_staging_arena`` first)."""
     buf = getattr(_STAGING, "buf", None)
     if buf is None or buf.shape[0] < n_words:
         buf = np.zeros(max(n_words, 1 << 12), np.uint32)
         _STAGING.buf = buf
-    return buf, wait
+    return buf
 
 
 def _seg_bucket(n: int) -> int:
@@ -1096,7 +1098,7 @@ def _lane_of(name: str):
 
 def decode_row_group_device(plans: Dict[str, Tuple[ChunkPlan, dt.DataType]],
                             capacity: int,
-                            timers: Optional[Dict[str, float]] = None,
+                            clock: Optional[StageClock] = None,
                             mm=None, chain=None, chain_key=None,
                             schema: Optional[dt.Schema] = None,
                             extra_cols=None, row_count=None,
@@ -1117,9 +1119,13 @@ def decode_row_group_device(plans: Dict[str, Tuple[ChunkPlan, dt.DataType]],
     per distinct raw offset tuple. Segments are written into a pooled per-thread host
     staging arena rather than np.concatenate'd fresh per group.
 
-    ``timers`` (optional dict) accumulates ``assemble`` (host arena
-    build) and ``upload`` (device_put + dispatch + arena-reuse wait)
-    seconds for the scan's metric split. ``mm`` (optional
+    ``clock`` (``obs.tracer.StageClock``) times the stages where they
+    happen, one span and one clock pair each: ``assemble`` (segments
+    and spec, then the arena fill), ``arena_wait`` between them,
+    ``upload`` (the blob's ``device_put``; ``bytes`` is its ``nbytes``)
+    and ``dispatch`` (the call of the jitted program, which on a cold
+    run holds its compilation). The scan folds ``clock.seconds`` into
+    its counters. ``mm`` (optional
     DeviceMemoryManager) takes a transient ledger reservation for the
     encoded blob while the upload + dispatch are in flight, so the
     staging bytes the widened envelope ships (string stores, delta
@@ -1143,13 +1149,11 @@ def decode_row_group_device(plans: Dict[str, Tuple[ChunkPlan, dt.DataType]],
     (and the chain's extra columns) into the program — XLA reuses
     their HBM for outputs instead of holding both live (skip on the
     CPU backend, where donation is unimplemented)."""
-    import time
-
     import jax
     import jax.numpy as jnp
     from jax import lax
 
-    t_asm0 = time.perf_counter()
+    clock = clock or StageClock()
     segs: List[Tuple[np.ndarray, int]] = []  # (u32 array, word offset)
     off = 0
 
@@ -1161,45 +1165,49 @@ def decode_row_group_device(plans: Dict[str, Tuple[ChunkPlan, dt.DataType]],
         off += blen
         return start, blen
 
-    spec = []
-    names = []
-    nrs = []
-    for name, (plan, eng_dtype) in plans.items():
-        lane = plan.lane
-        # +2 guard words inside the bucketed slice: the funnel-shift
-        # gather reads widx+1 (and +2 for w=64 at sh==32)
-        w_off, w_len = add(plan.packed, guard=2)
-        t = _pad_rows(plan.runs)
-        t_off, _ = add(np.ascontiguousarray(t).view(np.uint32)
-                       .reshape(-1))
-        dw_off, dw_len = add(plan.def_packed, guard=2)
-        dtab = _pad_rows(plan.def_runs)
-        dt_off, _ = add(np.ascontiguousarray(dtab).view(np.uint32)
-                        .reshape(-1))
-        d = _pad_pow2(plan.dictionary)
-        d_u32 = np.ascontiguousarray(d).view(np.uint32).reshape(-1) \
-            if d.dtype != np.bool_ else np.zeros(2, np.uint32)
-        dict_off, _ = add(d_u32)
-        if plan.str_dict is not None:
-            s_offs, s_chars = plan.str_dict
-            so = _pad_pow2(s_offs)
-            so_off, _ = add(np.ascontiguousarray(so).view(np.uint32))
-            sc_off, _ = add(_as_words(s_chars.tobytes()))
-            str_info = (so_off, so.shape[0], sc_off, plan.str_char_cap)
-        else:
-            str_info = None
-        names.append(name)
-        nrs.append(plan.n_rows)
-        spec.append((str(lane), str(np.dtype(eng_dtype.np_dtype))
-                     if eng_dtype.np_dtype is not None else "str",
-                     w_off, w_len, t_off, t.shape[0],
-                     dw_off, dw_len, dt_off, dtab.shape[0],
-                     dict_off, d.shape[0], str_info, plan.is_delta))
-    total = _seg_bucket(off + 4)  # trailing slice-overrun guard
-    buf, reuse_wait = _staging_arena(total)
-    for arr, start in segs:
-        buf[start:start + arr.shape[0]] = arr
-    view = buf[:total]
+    with clock.stage("assemble"):  # segments and the program's spec
+        spec = []
+        names = []
+        nrs = []
+        for name, (plan, eng_dtype) in plans.items():
+            lane = plan.lane
+            # +2 guard words inside the bucketed slice: the funnel-shift
+            # gather reads widx+1 (and +2 for w=64 at sh==32)
+            w_off, w_len = add(plan.packed, guard=2)
+            t = _pad_rows(plan.runs)
+            t_off, _ = add(np.ascontiguousarray(t).view(np.uint32)
+                           .reshape(-1))
+            dw_off, dw_len = add(plan.def_packed, guard=2)
+            dtab = _pad_rows(plan.def_runs)
+            dt_off, _ = add(np.ascontiguousarray(dtab).view(np.uint32)
+                            .reshape(-1))
+            d = _pad_pow2(plan.dictionary)
+            d_u32 = np.ascontiguousarray(d).view(np.uint32).reshape(-1) \
+                if d.dtype != np.bool_ else np.zeros(2, np.uint32)
+            dict_off, _ = add(d_u32)
+            if plan.str_dict is not None:
+                s_offs, s_chars = plan.str_dict
+                so = _pad_pow2(s_offs)
+                so_off, _ = add(np.ascontiguousarray(so).view(np.uint32))
+                sc_off, _ = add(_as_words(s_chars.tobytes()))
+                str_info = (so_off, so.shape[0], sc_off, plan.str_char_cap)
+            else:
+                str_info = None
+            names.append(name)
+            nrs.append(plan.n_rows)
+            spec.append((str(lane), str(np.dtype(eng_dtype.np_dtype))
+                         if eng_dtype.np_dtype is not None else "str",
+                         w_off, w_len, t_off, t.shape[0],
+                         dw_off, dw_len, dt_off, dtab.shape[0],
+                         dict_off, d.shape[0], str_info, plan.is_delta))
+        total = _seg_bucket(off + 4)  # trailing slice-overrun guard
+    _await_staging_arena(clock)
+    with clock.stage("assemble", rows=max(nrs, default=0),
+                     bytes=total * 4):
+        buf = _staging_arena(total)
+        for arr, start in segs:
+            buf[start:start + arr.shape[0]] = arr
+        view = buf[:total]
     cap = capacity
     eng_dtypes = [plans[n][1] for n in names]
     if chain is not None:
@@ -1210,6 +1218,7 @@ def decode_row_group_device(plans: Dict[str, Tuple[ChunkPlan, dt.DataType]],
                schema_sig, bool(donate))
     else:
         key = ("rg", cap, total, tuple(spec), bool(donate))
+    program = "scan_decode_chain" if chain is not None else "scan_decode"
     with _JIT_LOCK:  # one compile per key even across feeder threads
         fn = _JIT_CACHE.get(key)
         if fn is None:
@@ -1299,33 +1308,29 @@ def decode_row_group_device(plans: Dict[str, Tuple[ChunkPlan, dt.DataType]],
                     for f in chain_fns:
                         batch = f(batch, e)
                     return batch
-                fn = jax.jit(build, static_argnums=4,
-                             donate_argnums=(0, 3) if donate else ())
+                fn = named_jit(program, build, static_argnums=4,
+                               donate_argnums=(0, 3) if donate else ())
             else:
                 def build(b, nr):
                     return tuple(decode_cols(b, nr))
-                fn = jax.jit(build,
-                             donate_argnums=(0,) if donate else ())
+                fn = named_jit(program, build,
+                               donate_argnums=(0,) if donate else ())
             _JIT_CACHE[key] = fn
-    t_up0 = time.perf_counter()
     import contextlib
     charge = mm.transient_reservation(view.nbytes) if mm is not None \
         and hasattr(mm, "transient_reservation") else contextlib.nullcontext()
     with charge:
-        blob = jax.device_put(view)
-        nr_dev = jnp.asarray(np.asarray(nrs, np.int64))
-        if chain is not None:
-            extras = tuple((extra_cols or {}).values())
-            outs = fn(blob, nr_dev, np.int32(row_count), extras, ectx)
-        else:
-            outs = fn(blob, nr_dev)
+        with clock.stage("upload", bytes=view.nbytes):
+            blob = jax.device_put(view)
+            nr_dev = jnp.asarray(np.asarray(nrs, np.int64))
+        with clock.stage("dispatch", program=module_name(program),
+                         fused=chain is not None):
+            if chain is not None:
+                extras = tuple((extra_cols or {}).values())
+                outs = fn(blob, nr_dev, np.int32(row_count), extras, ectx)
+            else:
+                outs = fn(blob, nr_dev)
     _STAGING.pending = outs  # arena reusable once the decode ran
-    t_up1 = time.perf_counter()
-    if timers is not None:
-        timers["assemble"] = timers.get("assemble", 0.0) \
-            + max(0.0, t_up0 - t_asm0 - reuse_wait)
-        timers["upload"] = timers.get("upload", 0.0) \
-            + (t_up1 - t_up0) + reuse_wait
     if chain is not None:
         return outs  # the chain's output pytree (ONE dispatch, fused)
     result = {}

@@ -22,6 +22,7 @@ matters for TPC-H/DS date filters.
 from __future__ import annotations
 
 import concurrent.futures
+import os
 import queue
 import re
 import threading
@@ -42,7 +43,9 @@ from ..config import (CSV_ENABLED, JSON_ENABLED, MAX_PARTITION_BYTES,
                       SCAN_UPLOAD_THREADS)
 from ..exec.base import ExecCtx, LeafExec
 from ..obs.metrics import REGISTRY as _METRICS, TRANSFER_BUCKETS
+from ..obs.tracer import StageClock
 from ..pipeline import pipelined_map
+from ..programs import module_name, named_jit
 
 __all__ = ["FileSplit", "TpuFileScanExec", "plan_splits"]
 
@@ -685,15 +688,16 @@ class TpuFileScanExec(LeafExec):
                 tuple(fb_reasons))
 
     def _assemble_device_batch(self, n_rows, plans, host_rb, part_vals,
-                               timers=None, mm=None, chain=None,
+                               clock=None, mm=None, chain=None,
                                chain_key=None, ectx=None,
                                donate=False):
         """Feeder side: ONE fused decode dispatch for every planned
         column + uploads for host-fallback/partition columns, then the
-        TpuBatch (all async — no host sync). ``timers`` accumulates the
-        assemble/upload split (decode_row_group_device contributes its
-        own; the per-column uploads here add to "upload"); ``mm`` lets
-        the decode take its transient staging-blob ledger charge.
+        TpuBatch (all async — no host sync). ``clock`` times the stages
+        where they happen (decode_row_group_device its own; the
+        per-column uploads here are ``upload`` stages whose ``bytes``
+        are the Arrow column's); ``mm`` lets the decode take its
+        transient staging-blob ledger charge.
 
         With ``chain`` (scan-rooted whole-stage fusion), the
         host-fallback / partition / schema-evolution columns upload
@@ -708,6 +712,7 @@ class TpuFileScanExec(LeafExec):
         from ..columnar.batch import bucket_rows
         from ..columnar.arrow_bridge import arrow_column_to_device
         from ..columnar.column import TpuColumnVector
+        clock = clock or StageClock()
         cap = bucket_rows(max(n_rows, 1))
         part_fields = {f.name for f in self._part_schema.fields} \
             if self._part_schema is not None else set()
@@ -726,50 +731,47 @@ class TpuFileScanExec(LeafExec):
             """A non-device-planned column as a device TpuColumnVector
             (partition constant, host-fallback decode, or nulls),
             upload accounted to the transfer side."""
-            nonlocal up_s
-            if fld.name in part_fields:
-                v = (part_vals or {}).get(fld.name)
-                arr = pa.array([v] * n_rows, type=dt.to_arrow(fld.dtype))
-            elif host_rb is not None \
-                    and host_rb.schema.get_field_index(fld.name) >= 0:
-                arr = host_rb.column(
-                    host_rb.schema.get_field_index(fld.name))
-                if arr.type != dt.to_arrow(fld.dtype):
-                    arr = arr.cast(dt.to_arrow(fld.dtype))
-            else:
-                return TpuColumnVector.nulls(fld.dtype, cap)
-            t0 = time.perf_counter()
-            col = arrow_column_to_device(arr, fld.dtype, cap)
-            up_s += time.perf_counter() - t0
-            return col
+            with clock.stage("assemble"):
+                if fld.name in part_fields:
+                    v = (part_vals or {}).get(fld.name)
+                    arr = pa.array([v] * n_rows,
+                                   type=dt.to_arrow(fld.dtype))
+                elif host_rb is not None \
+                        and host_rb.schema.get_field_index(fld.name) >= 0:
+                    arr = host_rb.column(
+                        host_rb.schema.get_field_index(fld.name))
+                    if arr.type != dt.to_arrow(fld.dtype):
+                        arr = arr.cast(dt.to_arrow(fld.dtype))
+                else:
+                    return TpuColumnVector.nulls(fld.dtype, cap)
+            with clock.stage("upload", bytes=arr.nbytes):
+                return arrow_column_to_device(arr, fld.dtype, cap)
 
-        up_s = 0.0
         if chain is not None and typed:
             extra = {fld.name: other_column(fld)
                      for fld in self._schema.fields
                      if fld.name not in typed}
             out = decode_row_group_device(
-                typed, cap, timers, mm=mm, chain=chain,
+                typed, cap, clock, mm=mm, chain=chain,
                 chain_key=chain_key, schema=self._schema,
                 extra_cols=extra, row_count=n_rows, ectx=ectx,
                 donate=donate)
-            if timers is not None:
-                timers["upload"] = timers.get("upload", 0.0) + up_s
             return out, encoded, decoded, "fused"
-        dev_cols = decode_row_group_device(typed, cap, timers, mm=mm,
+        dev_cols = decode_row_group_device(typed, cap, clock, mm=mm,
                                            donate=donate) \
             if typed else {}
         cols = [dev_cols[fld.name] if fld.name in dev_cols
                 else other_column(fld) for fld in self._schema.fields]
-        if timers is not None:
-            timers["upload"] = timers.get("upload", 0.0) + up_s
         from ..columnar.batch import TpuBatch
         batch = TpuBatch(cols, self._schema, n_rows)
         if chain is not None:
             # degenerate group (every column host-decoded): the chain
             # still runs as ONE jitted program over the uploaded batch,
             # just not spliced into a decode program
-            batch = self._chain_only(chain, chain_key, cap, batch, ectx)
+            with clock.stage("dispatch", program=module_name("scan_chain"),
+                             fused=False):
+                batch = self._chain_only(chain, chain_key, cap, batch,
+                                         ectx)
             return batch, encoded, decoded, "chain"
         return batch, encoded, decoded, "decode" if dev_cols else "none"
 
@@ -778,14 +780,14 @@ class TpuFileScanExec(LeafExec):
         key = (chain_key, cap)
         fn = cache.get(key)
         if fn is None:
-            import jax
             fns = tuple(chain)
 
             def composed(b, e):
                 for f in fns:
                     b = f(b, e)
                 return b
-            fn = cache[key] = jax.jit(composed, static_argnums=1)
+            fn = cache[key] = named_jit("scan_chain", composed,
+                                        static_argnums=1)
         return fn(batch, ectx)
 
     # --- coalescing (device-decode path) ----------------------------------
@@ -921,13 +923,29 @@ class TpuFileScanExec(LeafExec):
         With ``chain`` (see ``fused_scan_execute``) the feeder
         dispatches the spliced decode+chain program and yields the
         chain's outputs; ``fusedDispatches``/``scanPrograms`` count the
-        programs so the dispatch-granularity claim is verifiable."""
+        programs so the dispatch-granularity claim is verifiable.
+
+        Every stage is timed at ONE site, by the span that is also its
+        record (``scan.*`` in the trace JSON, ``spark:scan.*`` on the
+        profiler): ``scan.read`` per row group on the ``scan-plan``
+        pool; ``scan.assemble`` / ``arena_wait`` / ``upload`` /
+        ``dispatch`` per batch on the ``scan-upload`` feeders (a
+        ``StageClock`` each: ``assembleTime``, ``arenaWaitTime``, and
+        ``uploadTime`` = upload + dispatch); ``scan.wait`` where a
+        thread waits for the stage before it (``on=read``, by the
+        feeders' source thread: ``scanTime``; ``on=upload``, by the
+        consumer: ``uploadWaitTime``). Pool and feeder spans name their
+        parent explicitly: the consumer's innermost span when the scan
+        starts."""
         conf = ctx.conf
+        tracer = ctx.tracer
+        parent = tracer.current_span_id()
         rows = ctx.metric(self, "numOutputRows")
         scan_t = ctx.metric(self, "scanTime")
         asm_t = ctx.metric(self, "assembleTime")
         up_t = ctx.metric(self, "uploadTime")
         wait_t = ctx.metric(self, "uploadWaitTime")
+        arena_t = ctx.metric(self, "arenaWaitTime")
         enc_m = ctx.metric(self, "encodedBytes")
         dec_m = ctx.metric(self, "decodedBytes")
         dev_chunks_m = ctx.metric(self, "deviceChunks")
@@ -959,6 +977,15 @@ class TpuFileScanExec(LeafExec):
         pool = concurrent.futures.ThreadPoolExecutor(
             nthreads, thread_name_prefix="scan-plan")
 
+        def read(path, g):
+            with tracer.span("scan.read", cat="scan", parent_id=parent,
+                             args={"file": os.path.basename(path),
+                                   "rg": g}) as sp:
+                item = self._plan_row_group(path, g)
+                sp.set(chunks=len(item[1]), bytes=sum(
+                    plan.encoded_bytes for plan in item[1].values()))
+            return item
+
         def planned():
             pending: List = []
             it = iter(tasks)
@@ -969,13 +996,14 @@ class TpuFileScanExec(LeafExec):
                         p, g = next(it)
                     except StopIteration:
                         return
-                    pending.append(
-                        pool.submit(self._plan_row_group, p, g))
+                    pending.append(pool.submit(read, p, g))
             topup()
             while pending:
-                t0 = time.perf_counter()
-                item = pending.pop(0).result()
-                scan_t.value += time.perf_counter() - t0
+                with tracer.span("scan.wait", cat="scan",
+                                 parent_id=parent, args={"on": "read"},
+                                 timed=True) as wait:
+                    item = pending.pop(0).result()
+                scan_t.value += wait.dur
                 topup()
                 yield item
 
@@ -984,22 +1012,17 @@ class TpuFileScanExec(LeafExec):
         closed = [False]
 
         def assemble(group):
-            timers = {"assemble": 0.0, "upload": 0.0}
-            t0 = time.perf_counter()
+            clock = StageClock(tracer, "scan", parent)
             # coverage counts from the PRE-merge group: one count per
             # planned column chunk, merge or no merge
             dev_chunks = sum(len(g[1]) for g in group)
-            n_rows, plans, host_rb, part_vals, fb_reasons = \
-                self._merge_planned(group)
+            with clock.stage("assemble"):
+                n_rows, plans, host_rb, part_vals, fb_reasons = \
+                    self._merge_planned(group)
             batch, encoded, decoded, prog = self._assemble_device_batch(
-                n_rows, plans, host_rb, part_vals, timers=timers,
+                n_rows, plans, host_rb, part_vals, clock=clock,
                 mm=mgr, chain=chain, chain_key=chain_key,
                 ectx=ctx.eval_ctx, donate=donate)
-            # whatever the wall spent that was not attributed to the
-            # transfer side is host assembly (merge, arena build, arrow
-            # prep)
-            timers["assemble"] = max(
-                0.0, time.perf_counter() - t0 - timers["upload"])
             # chain outputs that are not batches (the exchange's
             # (batch, split) tail tuples) skip the in-flight ledger
             # charge — the window bound still caps their residency
@@ -1013,7 +1036,7 @@ class TpuFileScanExec(LeafExec):
                     return None
                 if sb is not None:
                     inflight.add(sb)
-            return (batch, sb, n_rows, encoded, decoded, timers,
+            return (batch, sb, n_rows, encoded, decoded, clock.seconds,
                     dev_chunks, fb_reasons, prog)
 
         groups = self._coalesced_groups(planned(), target_bytes, max_rows)
@@ -1028,23 +1051,28 @@ class TpuFileScanExec(LeafExec):
                             weigher=lambda g: sum(
                                 self._decoded_estimate(it) for it in g),
                             max_weight=max_weight,
-                            token=qx.token if qx is not None else None)
+                            token=qx.token if qx is not None else None,
+                            thread_name="scan-upload")
+        done = object()
         try:
             while True:
-                t0 = time.perf_counter()
-                try:
-                    item = next(gen)
-                except StopIteration:
+                with tracer.span("scan.wait", cat="scan",
+                                 args={"on": "upload"},
+                                 timed=True) as wait:
+                    item = next(gen, done)
+                wait_t.value += wait.dur
+                if item is done:
                     break
-                wait_t.value += time.perf_counter() - t0
-                (batch, sb, n_rows, encoded, decoded, timers,
+                (batch, sb, n_rows, encoded, decoded, seconds,
                  dev_chunks, fb_reasons, prog) = item
-                asm_t.value += timers["assemble"]
-                up_t.value += timers["upload"]
-                SCAN_ASSEMBLE_SECONDS.labels("device").observe(
-                    timers["assemble"])
-                SCAN_UPLOAD_SECONDS.labels("device").observe(
-                    timers["upload"])
+                assemble_s = seconds.get("assemble", 0.0)
+                upload_s = seconds.get("upload", 0.0) \
+                    + seconds.get("dispatch", 0.0)
+                asm_t.value += assemble_s
+                up_t.value += upload_s
+                arena_t.value += seconds.get("arena_wait", 0.0)
+                SCAN_ASSEMBLE_SECONDS.labels("device").observe(assemble_s)
+                SCAN_UPLOAD_SECONDS.labels("device").observe(upload_s)
                 enc_m.value += encoded
                 dec_m.value += decoded
                 dev_chunks_m.value += dev_chunks
@@ -1117,7 +1145,8 @@ class TpuFileScanExec(LeafExec):
         qx = getattr(ctx, "qctx", None)
         gen = pipelined_map(upload, timed_source(), threads=1,
                             window=max(depth, 0),
-                            token=qx.token if qx is not None else None)
+                            token=qx.token if qx is not None else None,
+                            thread_name="scan-upload")
         try:
             while True:
                 t0 = time.perf_counter()

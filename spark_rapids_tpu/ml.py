@@ -26,17 +26,6 @@ from typing import Dict, Iterator, List, Optional, Tuple
 __all__ = ["columnar_rdd", "to_feature_matrix", "to_torch"]
 
 
-def _ml_query_span(pp, ctx):
-    """The root query span collect() gets from the planner — the ML
-    execute loop needs the same so its trace stitches under one root."""
-    if not ctx.tracer.enabled:
-        import contextlib
-        return contextlib.nullcontext()
-    from .tools.event_log import plan_fingerprint
-    return ctx.tracer.span("query", cat="query",
-                           args={"fingerprint": plan_fingerprint(pp.root)})
-
-
 def _emit_ml_query_event(pp, ctx, wall_s: float) -> None:
     """The end-of-query observability collect() performs: write the
     Chrome trace this event's embedded summary references, then append
@@ -62,6 +51,7 @@ def columnar_rdd(df) -> Iterator[Dict[str, object]]:
 
     from .exec.base import ExecCtx
     from .ops.gather import ensure_compacted
+    from .planner import query_span
     pp = df._plan()
     ctx = ExecCtx(df._session.conf)
     _t0 = _time.perf_counter()
@@ -69,7 +59,7 @@ def columnar_rdd(df) -> Iterator[Dict[str, object]]:
     # iteration, cleanups (shared-exchange handles) even on abandonment,
     # deferred device checks raised at the natural end-of-stream sync
     try:
-        with _ml_query_span(pp, ctx), \
+        with query_span(ctx, pp.root), \
                 ctx.mm.task_slot():  # admission (GpuSemaphore analog)
             for batch in pp.root.execute(ctx):
                 batch = ensure_compacted(batch)
@@ -113,11 +103,12 @@ def to_feature_matrix(df, feature_cols: List[str],
     from .ops.concat import concat_batches
     from .exec.base import ExecCtx
     from .ops.gather import ensure_compacted
+    from .planner import query_span
     pp = df._plan()
     ctx = ExecCtx(df._session.conf)
     _t0 = _time.perf_counter()
     try:
-        with _ml_query_span(pp, ctx), \
+        with query_span(ctx, pp.root), \
                 ctx.mm.task_slot():  # admission (GpuSemaphore analog)
             batches = [ensure_compacted(b)
                        for b in pp.root.execute(ctx)]

@@ -11,7 +11,10 @@ makes it visible live:
   through a trace context (trace_id + parent span id) propagated in
   ``TaskSpec`` payloads and committed alongside task output, so the
   driver stitches ONE coherent Chrome ``trace_event`` JSON per query
-  (chrome://tracing / Perfetto).
+  (chrome://tracing / Perfetto). A live span is also a
+  ``jax.profiler.TraceAnnotation`` (``spark:query``, ``spark:op``,
+  ``spark:scan.read`` ...), so under a JAX profiler session the same
+  spans lie on the profiler's host plane beside the device operations.
 - ``metrics`` — a process-wide MetricsRegistry (counters / gauges /
   histograms with bounded label sets) exposed as Prometheus text via
   ``dump_prometheus`` and an optional HTTP endpoint
@@ -24,9 +27,13 @@ makes it visible live:
   worker death, OOM/spill cascade, statistical straggler) — forensics
   for queries that ran with tracing and metrics fully OFF.
 
-Tracing and metrics export are off by default and near-zero overhead
-when disabled (the null tracer's ``span()`` is a shared no-op context
-manager; registry updates are plain attribute arithmetic); the flight
+Metrics export is off by default. Tracing is on for a query when
+``spark.rapids.trace.dir`` is set or a JAX profiler session is running
+when the query's ``ExecCtx`` is made (``spark.rapids.profile.path``, or
+a session the caller started); with neither it is near-zero overhead
+(the null tracer's ``span()`` is a shared no-op context manager, and a
+stage whose seconds feed a counter is timed by a bare stopwatch;
+registry updates are plain attribute arithmetic); the flight
 recorder is ON by default — its records are bounded deque appends,
 audited by bench.py's ``obs_overhead_frac``.
 """

@@ -81,8 +81,8 @@ HISTORY_DIR = register(
 #: summed and rendered raw.
 _TIME_METRICS = frozenset((
     "opTime", "spillTime", "uploadTime", "uploadWaitTime", "scanTime",
-    "assembleTime", "downloadTime", "writeTime", "concatTime",
-    "ledgerWaitTime", "dispatchTime"))
+    "assembleTime", "arenaWaitTime", "downloadTime", "writeTime",
+    "concatTime", "ledgerWaitTime", "dispatchTime"))
 
 #: metrics that are identifiers/flags (fold by max across tasks), not
 #: accumulators (fold by sum): the fused-program membership id and the

@@ -25,30 +25,94 @@ https://ui.perfetto.dev).
 Wall-clock ``time.time()`` stamps span starts (cross-process
 comparable on one host / shared filesystem); ``time.perf_counter()``
 measures durations so a clock step cannot produce negative spans.
+
+**One span, two sinks.** A live span also holds a
+``jax.profiler.TraceAnnotation`` open for its whole extent, so the same
+span lies on the JAX profiler's host plane, on the clock of the device
+operations, whenever a profiler session runs (XProf shows the program's
+spans beside the chip's; ``benchmark/span_reduce.py`` lays them over the
+chip's idle gaps). There its name is ``spark:<kind>`` (``spark:query``,
+``spark:op``, ``spark:scan.read``) and it carries ``query`` (``q`` +
+the tracer's ``trace_id``), ``span`` and ``parent`` (``s`` + the ids of
+the store; the letter keeps the profiler from reading ``0.10`` as the
+number 0.1) and the span's own arguments. TraceMe's wire form fences
+arguments with ``#`` and separates them with ``,``: both are replaced in
+names and string values (a node label ``Project#op3`` goes in as
+``Project:op3``).
+A tracer is live when ``spark.rapids.trace.dir`` is set OR a profiler
+session is running when it is made (``tracer_from_conf``); with only the
+profiler to feed it writes no file.
 """
 from __future__ import annotations
 
+import contextlib
+import ctypes
 import json
 import os
+import sys
 import threading
 import time
 import uuid
 from typing import Dict, List, Optional
+
+from jax.profiler import TraceAnnotation
 
 from ..config import register
 from .recorder import RECORDER as _FLIGHT, prune_oldest
 
 __all__ = ["TRACE_DIR", "TRACE_MAX_SPANS", "TRACE_MAX_FILES", "Span",
            "Tracer", "NULL_TRACER", "tracer_from_conf", "spans_to_chrome",
-           "load_chrome_trace"]
+           "load_chrome_trace", "PROFILER_PREFIX", "StageClock"]
+
+#: every name this program writes to the profiler starts with it, so a
+#: reader tells the program's spans from the runtime's events by name
+PROFILER_PREFIX = "spark:"
+_WIRE_SAFE = str.maketrans({"#": ":", ",": ";"})
+
+
+_OS_NAMED = threading.local()
+
+
+def _name_os_thread() -> None:
+    """Give the calling thread's Python name to the operating system
+    too, once (Linux ``PR_SET_NAME``, 15 bytes): the JAX profiler names
+    a host line after the OS thread when it first sees it, and CPython
+    before 3.14 leaves every thread it starts under its creator's name,
+    so without this all of a query's threads read ``python`` in a
+    profile. Called by a live span as it opens, never by the disabled
+    tracer: an untraced query starts its threads as it always did. The
+    main thread keeps its name (it is the process's); does nothing
+    where it cannot."""
+    if getattr(_OS_NAMED, "done", False):
+        return
+    _OS_NAMED.done = True
+    me = threading.current_thread()
+    if me is threading.main_thread() or not sys.platform.startswith("linux"):
+        return
+    try:
+        prctl = ctypes.CDLL(None).prctl
+    except (OSError, AttributeError):
+        return
+    prctl.argtypes = [ctypes.c_int, ctypes.c_char_p, ctypes.c_ulong,
+                      ctypes.c_ulong, ctypes.c_ulong]
+    prctl.restype = ctypes.c_int
+    prctl(15, me.name.encode()[:15], 0, 0, 0)
+
+
+def _wire(args: Dict) -> Dict:
+    """Arguments as TraceMe can carry them (module docstring)."""
+    return {k: v.translate(_WIRE_SAFE) if isinstance(v, str) else v
+            for k, v in args.items()}
 
 TRACE_DIR = register(
     "spark.rapids.trace.dir", "",
     "When set, every query records query/stage/operator spans (driver "
     "AND process-cluster workers, stitched via a propagated trace "
     "context) and writes one Chrome trace_event JSON under this "
-    "directory — open it in chrome://tracing or Perfetto. Off by "
-    "default; the disabled tracer is a shared no-op.")
+    "directory — open it in chrome://tracing or Perfetto. The same "
+    "spans also go to the JAX profiler's host plane (named spark:...) "
+    "while a profiler session runs, with or without this directory. "
+    "With neither, the tracer is a shared no-op.")
 TRACE_MAX_SPANS = register(
     "spark.rapids.trace.maxSpans", 100_000,
     "Per-tracer span buffer bound; spans past it are dropped and "
@@ -94,36 +158,56 @@ class Span:
 
 class _LiveSpan:
     """Context manager for an in-flight span; exposes ``span_id`` so
-    callers can hand it to children in other processes."""
+    callers can hand it to children in other processes (or threads),
+    and ``dur`` (seconds) once it has closed."""
 
     __slots__ = ("_tracer", "name", "cat", "span_id", "parent_id",
-                 "args", "_ts", "_t0")
+                 "args", "kind", "dur", "_ts", "_t0", "_ann")
 
     def __init__(self, tracer: "Tracer", name: str, cat: str,
-                 parent_id: Optional[str], args: Optional[Dict]):
+                 parent_id: Optional[str], args: Optional[Dict],
+                 kind: Optional[str]):
         self._tracer = tracer
         self.name = name
         self.cat = cat
         self.span_id = tracer._next_id()
         self.parent_id = parent_id
         self.args = args
+        self.kind = kind or name
+        self.dur = 0.0
+
+    def set(self, **args) -> None:
+        """Arguments known only once the work is under way (bytes read,
+        rows staged); allowed until the span closes."""
+        self.args = dict(self.args or {}, **args)
+        self._ann.set_metadata(**_wire(args))
 
     def __enter__(self) -> "_LiveSpan":
+        _name_os_thread()
         stack = self._tracer._stack()
         if self.parent_id is None and stack:
             self.parent_id = stack[-1]
         stack.append(self.span_id)
+        ids = {"query": "q" + self._tracer.trace_id,
+               "span": "s" + self.span_id}
+        if self.parent_id is not None:
+            ids["parent"] = "s" + self.parent_id
+        self._ann = TraceAnnotation(
+            PROFILER_PREFIX + self.kind.translate(_WIRE_SAFE),
+            **ids, **_wire(self.args or {}))
         self._ts = time.time()
+        self._ann.__enter__()
         self._t0 = time.perf_counter()
         return self
 
     def __exit__(self, *exc):
-        dur = time.perf_counter() - self._t0
+        self.dur = time.perf_counter() - self._t0
+        self._ann.__exit__(*exc)
         stack = self._tracer._stack()
         if stack and stack[-1] == self.span_id:
             stack.pop()
         self._tracer._record(Span(self.name, self.cat, self.span_id,
-                                  self.parent_id, self._ts, dur,
+                                  self.parent_id, self._ts, self.dur,
                                   self._tracer.pid, self.args))
         return False
 
@@ -133,11 +217,34 @@ class _NullSpan:
 
     __slots__ = ()
     span_id = None
+    dur = 0.0
+
+    def set(self, **args):
+        pass
 
     def __enter__(self):
         return self
 
     def __exit__(self, *exc):
+        return False
+
+
+class _Stopwatch:
+    """The disabled span of a stage whose seconds feed a counter
+    (``span(..., timed=True)``): one clock pair, nothing recorded."""
+
+    __slots__ = ("dur", "_t0")
+    span_id = None
+
+    def set(self, **args):
+        pass
+
+    def __enter__(self):
+        self._t0 = time.perf_counter()
+        return self
+
+    def __exit__(self, *exc):
+        self.dur = time.perf_counter() - self._t0
         return False
 
 
@@ -192,10 +299,16 @@ class Tracer:
 
     def span(self, name: str, cat: str = "default",
              parent_id: Optional[str] = None,
-             args: Optional[Dict] = None) -> _LiveSpan:
+             args: Optional[Dict] = None, kind: Optional[str] = None,
+             timed: bool = False) -> _LiveSpan:
         """Live span context manager; nests via a thread-local stack
-        unless ``parent_id`` pins it explicitly (cross-process join)."""
-        return _LiveSpan(self, name, cat, parent_id, args)
+        unless ``parent_id`` pins it explicitly (a span opened in
+        another process or on another thread than its cause). ``kind``
+        is its name on the profiler where ``name`` is an instance label
+        (operator spans: ``kind="op"``). ``timed`` asks for ``dur`` even
+        from the disabled tracer: the stage's seconds feed a counter
+        and this is the one place they are taken."""
+        return _LiveSpan(self, name, cat, parent_id, args, kind)
 
     def current_span_id(self) -> Optional[str]:
         """This thread's innermost open span — the parent a
@@ -247,7 +360,10 @@ class Tracer:
                      name: Optional[str] = None) -> str:
         """Write one Chrome trace_event JSON; returns its path. The
         write is atomic (tmp + rename) so readers never see a torn
-        trace."""
+        trace. No directory (a tracer that only feeds the profiler):
+        no file, and ``""``."""
+        if not base_dir:
+            return ""
         os.makedirs(base_dir, exist_ok=True)
         fname = name or f"trace-{self.trace_id}.json"
         path = os.path.join(base_dir, fname)
@@ -315,7 +431,8 @@ def load_chrome_trace(path: str) -> List[Dict]:
 
 class _NullTracer:
     """The disabled path: every call is a no-op and ``span()`` returns
-    one shared context manager — no allocation on hot paths."""
+    one shared context manager — no allocation on hot paths (a
+    ``timed`` span is a bare stopwatch: its caller needs the seconds)."""
 
     enabled = False
     trace_id = ""
@@ -323,8 +440,9 @@ class _NullTracer:
     spans: List[Span] = []
     dropped = 0
 
-    def span(self, name, cat="default", parent_id=None, args=None):
-        return _NULL_SPAN
+    def span(self, name, cat="default", parent_id=None, args=None,
+             kind=None, timed=False):
+        return _Stopwatch() if timed else _NULL_SPAN
 
     def current_span_id(self):
         return None
@@ -348,10 +466,40 @@ class _NullTracer:
 NULL_TRACER = _NullTracer()
 
 
+class StageClock:
+    """The stages of one unit of pipelined work (one scan batch on a
+    feeder thread), each timed at ONE site: ``stage(name)`` is the span
+    ``<prefix>.<name>`` under ``parent_id`` (the cause waits on another
+    thread, so the thread-local stack cannot supply it) and adds the
+    span's own seconds to ``seconds[name]``. The consumer folds
+    ``seconds`` into the operator's counters when the unit is handed
+    over, so a counter is the summed duration of its spans and is only
+    ever written on the consumer's thread. Under the null tracer a
+    stage is a bare stopwatch."""
+
+    __slots__ = ("_tracer", "_prefix", "_parent_id", "seconds")
+
+    def __init__(self, tracer=None, prefix: str = "stage",
+                 parent_id: Optional[str] = None):
+        self._tracer = tracer or NULL_TRACER
+        self._prefix = prefix
+        self._parent_id = parent_id
+        self.seconds: Dict[str, float] = {}
+
+    @contextlib.contextmanager
+    def stage(self, name: str, **args):
+        with self._tracer.span(f"{self._prefix}.{name}", cat=self._prefix,
+                               parent_id=self._parent_id, args=args,
+                               timed=True) as sp:
+            yield sp
+        self.seconds[name] = self.seconds.get(name, 0.0) + sp.dur
+
+
 def tracer_from_conf(conf, pid: int = 0, trace_id: Optional[str] = None):
-    """A live Tracer when ``spark.rapids.trace.dir`` is set, else the
-    shared null tracer."""
-    if not conf.get(TRACE_DIR):
+    """A live Tracer when ``spark.rapids.trace.dir`` is set or a JAX
+    profiler session is running now (its host plane is the second sink
+    of every span), else the shared null tracer."""
+    if not conf.get(TRACE_DIR) and not TraceAnnotation.is_enabled():
         return NULL_TRACER
     return Tracer(trace_id=trace_id, pid=pid,
                   max_spans=conf.get(TRACE_MAX_SPANS),

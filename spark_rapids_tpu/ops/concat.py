@@ -16,6 +16,7 @@ import numpy as np
 
 from ..columnar.batch import TpuBatch, bucket_bytes, bucket_rows, row_mask
 from ..columnar.column import TpuColumnVector
+from ..programs import named_jit
 
 __all__ = ["concat_batches", "concat_device", "device_concat_supported"]
 
@@ -169,7 +170,8 @@ def concat_batches_bounded(batches: List[TpuBatch]) -> TpuBatch:
            tuple(char_caps), id(batches[0].schema))
     fn = _concat_jit_cache.get(key)
     if fn is None:
-        fn = jax.jit(lambda bs: concat_device(bs, out_cap, char_caps))
+        fn = named_jit("concat_batches", lambda bs: concat_device(
+            bs, out_cap, char_caps))
         _concat_jit_cache[key] = fn
     return fn(batches)
 
@@ -214,7 +216,7 @@ def concat_batches(batches: List[TpuBatch]) -> TpuBatch:
            id(batches[0].schema))
     fn = _concat_jit_cache.get(key)
     if fn is None:
-        fn = jax.jit(lambda bs: concat_device(bs, out_cap,
-                                              char_caps))
+        fn = named_jit("concat_batches", lambda bs: concat_device(
+            bs, out_cap, char_caps))
         _concat_jit_cache[key] = fn
     return fn(batches)

@@ -97,7 +97,8 @@ def pipelined_map(fn: Callable[[T], R], items: Iterable[T],
                   threads: int = 1, window: int = 2,
                   weigher: Optional[Callable[[T], int]] = None,
                   max_weight: Optional[int] = None,
-                  token=None) -> Iterator[R]:
+                  token=None,
+                  thread_name: str = "pipelined-map") -> Iterator[R]:
     """Yield ``fn(item)`` for each item, in order, with up to ``window``
     results in flight across ``threads`` worker threads.
 
@@ -121,6 +122,9 @@ def pipelined_map(fn: Callable[[T], R], items: Iterable[T],
       window) and the consumer raises the classified QueryCancelled at
       its next ``next()``, early-draining in-flight work through the
       normal close path.
+    - ``thread_name`` prefixes the names of the worker threads and of
+      the source's feeder (``<prefix>_<n>``, ``<prefix>-src``): a trace
+      then says whose lines they are.
     """
     if threads <= 0 or window <= 0:
         for x in items:
@@ -135,7 +139,7 @@ def pipelined_map(fn: Callable[[T], R], items: Iterable[T],
                             token=token)
     stop = threading.Event()
     pool = concurrent.futures.ThreadPoolExecutor(
-        max_workers=threads, thread_name_prefix="pipelined-map")
+        max_workers=threads, thread_name_prefix=thread_name)
 
     def feeder():
         try:
@@ -154,7 +158,7 @@ def pipelined_map(fn: Callable[[T], R], items: Iterable[T],
             out.put((_ERR, e))
 
     th = threading.Thread(target=feeder, daemon=True,
-                          name="pipelined-map-feeder")
+                          name=thread_name + "-src")
     th.start()
     try:
         while True:

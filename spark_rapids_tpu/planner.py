@@ -120,6 +120,18 @@ class NodeMeta:
 
 
 
+def query_span(ctx: ExecCtx, root: TpuExec):
+    """The root span of a query (``spark:query`` on the profiler), for
+    every path that executes a plan locally: ``PhysicalPlan.collect``
+    and the ML bridge. Disabled, it is the shared no-op and the plan is
+    not fingerprinted."""
+    if not ctx.tracer.enabled:
+        return ctx.tracer.span("query")
+    from .tools.event_log import plan_fingerprint
+    return ctx.tracer.span("query", cat="query",
+                           args={"fingerprint": plan_fingerprint(root)})
+
+
 class PhysicalPlan:
     """Planner output: the rebuilt tree + placement + explain report."""
 
@@ -161,8 +173,22 @@ class PhysicalPlan:
 
     def collect(self, ctx: Optional[ExecCtx] = None,
                 qctx=None) -> pa.Table:
+        import contextlib
+        from .config import PROFILE_PATH
+        prof_dir = self.conf.get(PROFILE_PATH)
+        if prof_dir:
+            import jax
+            session = jax.profiler.trace(prof_dir)
+        else:
+            session = contextlib.nullcontext()
+        # the session before the ExecCtx: its tracer is live when a
+        # profiler session runs, and the query's spans land in the
+        # profile beside the device operations
+        with session:
+            return self._collect(ctx or ExecCtx(self.conf), qctx)
+
+    def _collect(self, ctx: ExecCtx, qctx) -> pa.Table:
         import time as _time
-        ctx = ctx or ExecCtx(self.conf)
         self.last_ctx = ctx
         # query lifecycle (lifecycle.py): default-on — every collect
         # gets a QueryContext (deadline/tenant/budget from conf) unless
@@ -181,31 +207,39 @@ class PhysicalPlan:
         # failed) — obs/attribution.py. None when the warehouse is off.
         from .obs.attribution import QueryAttribution
         attrib = QueryAttribution.begin(self.conf)
-        from .config import PROFILE_PATH
         from .columnar.arrow_bridge import arrow_schema
-        import contextlib
         _t0 = _time.perf_counter()
         schema = arrow_schema(self.root.output_schema)
-        prof_dir = self.conf.get(PROFILE_PATH)
-        if prof_dir:
-            import jax
-            tracer = jax.profiler.trace(prof_dir)
-        else:
-            tracer = contextlib.nullcontext()
-        from .tools.event_log import plan_fingerprint
-        qspan = ctx.tracer.span(
-            "query", cat="query",
-            args={"fingerprint": plan_fingerprint(self.root)}) \
-            if ctx.tracer.enabled else contextlib.nullcontext()
         try:
-            with tracer, qspan:
+            with query_span(ctx, self.root):
                 if self.root_on_device:
                     rbs = self._collect_device(ctx, qctx)
                 else:
                     # CPU-rooted plans can still contain device islands
                     # (under DeviceToHostExec): their cleanups and
-                    # deferred device checks must run here too
+                    # deferred device checks run below too
                     rbs = self._collect_cpu(ctx)
+                # from the last download to the return: one span, so
+                # that no second of a query lies under the query alone
+                with ctx.tracer.span("finish", cat="query"):
+                    ctx.run_cleanups()
+                    ctx.check_deferred()  # downloads were the sync point
+                    wall_s = _time.perf_counter() - _t0
+                    self.last_wall_s = wall_s
+                    # fold the deferred row counts in now — the
+                    # downloads above were the natural sync point, so
+                    # this readback is already satisfied
+                    ctx.opm.finalize()
+                    from .obs.metrics import QUERY_DURATION
+                    QUERY_DURATION.labels(self.source,
+                                          "local").observe(wall_s)
+                    # (the event's span rollup is taken here, before
+                    # the query and finish spans close: it holds every
+                    # other span)
+                    from .tools.event_log import log_query_event
+                    log_query_event(self, ctx, wall_s)
+                    self._write_profile(ctx, wall_s)
+                    self._emit_warehouse(attrib, ctx, qctx, wall_s)
         except QueryCancelled as e:
             self._report_cancel(ctx, e, _time.perf_counter() - _t0)
             self._emit_warehouse(attrib, ctx, qctx,
@@ -229,36 +263,32 @@ class PhysicalPlan:
                     ctx.tracer.write_chrome(self.conf.get(TRACE_DIR))
                 except OSError:
                     pass
-        wall_s = _time.perf_counter() - _t0
-        self.last_wall_s = wall_s
-        # fold the deferred row counts in now — the downloads above were
-        # the natural sync point, so this readback is already satisfied
-        ctx.opm.finalize()
-        from .obs.metrics import QUERY_DURATION
-        QUERY_DURATION.labels(self.source, "local").observe(wall_s)
-        from .tools.event_log import log_query_event
-        log_query_event(self, ctx, wall_s)
-        self._write_profile(ctx, wall_s)
-        self._emit_warehouse(attrib, ctx, qctx, wall_s)
         return pa.Table.from_batches(rbs, schema=schema)
 
     def _collect_device(self, ctx: ExecCtx, qctx) -> List:
         """Device-rooted execution under fair admission; the
         degradation ladder's terminal rung answers a
-        ladder-exhausted OOM with the classified CPU fallback."""
-        import time as _time
+        ladder-exhausted OOM with the classified CPU fallback. Query-end
+        cleanups and deferred checks of a run that succeeded are the
+        caller's (``_collect``: the ``finish`` span)."""
+        import contextlib
         from .columnar.arrow_bridge import device_to_arrow
         from .memory import TpuRetryOOM
         try:
-            _ts = _time.perf_counter()
-            with ctx.mm.task_slot(qctx):  # GpuSemaphore admission
-                # blocking happened at entry: charge the admission
-                # wait to the root operator (the semaphoreWaitTime
-                # analog)
-                ctx.metric(self.root, "ledgerWaitTime") \
-                    .value += _time.perf_counter() - _ts
-                rbs = [device_to_arrow(b)
-                       for b in self.root.execute(ctx)]
+            with contextlib.ExitStack() as slot:
+                # GpuSemaphore admission: the blocking happens at entry,
+                # and is charged to the root operator (the
+                # semaphoreWaitTime analog)
+                with ctx.tracer.span("admit", cat="query",
+                                     timed=True) as wait:
+                    slot.enter_context(ctx.mm.task_slot(qctx))
+                ctx.metric(self.root, "ledgerWaitTime").value += wait.dur
+                rbs = []
+                for b in self.root.execute(ctx):
+                    with ctx.tracer.span("download", cat="query") as down:
+                        rb = device_to_arrow(b)
+                        down.set(rows=rb.num_rows, bytes=rb.nbytes)
+                    rbs.append(rb)
         except TpuRetryOOM as oom:
             ctx.discard_deferred()  # dead attempt's flags
             ctx.opm.discard()
@@ -289,21 +319,16 @@ class PhysicalPlan:
             ctx.opm.discard()
             ctx.run_cleanups()
             raise
-        ctx.run_cleanups()
-        ctx.check_deferred()  # downloads were the sync point
         return rbs
 
     def _collect_cpu(self, ctx: ExecCtx) -> List:
         try:
-            rbs = list(self.root.execute_cpu(ctx))
+            return list(self.root.execute_cpu(ctx))
         except BaseException:
             ctx.discard_deferred()
             ctx.opm.discard()
             ctx.run_cleanups()
             raise
-        ctx.run_cleanups()
-        ctx.check_deferred()
-        return rbs
 
     def _report_cancel(self, ctx: ExecCtx, e, wall_s: float) -> None:
         """Classified-cancel evidence: one event-log line (type

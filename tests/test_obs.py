@@ -490,3 +490,260 @@ def test_cluster_trace_disabled_has_zero_surface(tmp_path):
         assert c.last_trace_path is None
         assert c.last_scheduler.tracer is NULL_TRACER \
             or not c.last_scheduler.tracer.enabled
+
+
+# --- one span, two sinks: the scan's spans on the profiler's clock ----------
+
+Q6_TEXT = """select sum(l_extendedprice * l_discount) as revenue
+from lineitem
+where l_shipdate >= date '1994-01-01' and l_shipdate < date '1995-01-01'
+  and l_discount between 0.05 and 0.07 and l_quantity < 24"""
+
+# one dispatch per row group, all from ONE feeder thread, so that the
+# second batch meets the arena its first decode still owns
+_SCAN_CONF = {"spark.sql.shuffle.partitions": "1",
+              "spark.rapids.sql.scan.coalesceTargetBytes": "0",
+              "spark.rapids.sql.scan.uploadThreads": "1"}
+
+_SPAN_TABLE = ("spark:query", "spark:admit", "spark:op", "spark:scan.read",
+               "spark:scan.wait", "spark:scan.assemble",
+               "spark:scan.arena_wait", "spark:scan.upload",
+               "spark:scan.dispatch", "spark:download", "spark:finish")
+
+
+def _lineitem_files(base, files=3, rows=1500, row_group=500, seed=7):
+    """A rehearsal-size lineitem: the four columns Q6 reads and a
+    string beside them, several row groups a file."""
+    import datetime
+
+    import numpy as np
+    import pyarrow.parquet as pq
+    rng = np.random.default_rng(seed)
+    day0 = datetime.date(1993, 6, 1)
+    paths = []
+    for i in range(files):
+        t = pa.table({
+            "l_quantity": rng.integers(1, 51, rows).astype("float64"),
+            "l_extendedprice": rng.uniform(900.0, 105000.0, rows),
+            "l_discount": rng.integers(0, 11, rows) / 100.0,
+            "l_shipdate": pa.array(
+                [day0 + datetime.timedelta(days=int(d))
+                 for d in rng.integers(0, 900, rows)], pa.date32()),
+            "l_comment": pa.array([f"c{j % 97}" for j in range(rows)]),
+        })
+        p = os.path.join(str(base), f"lineitem-{i:02d}.parquet")
+        pq.write_table(t, p, row_group_size=row_group)
+        paths.append(p)
+    return paths
+
+
+def _q6_plan(paths, conf=None):
+    from spark_rapids_tpu import TpuSession
+    from spark_rapids_tpu.planner import TpuOverrides
+    s = TpuSession(dict(_SCAN_CONF, **(conf or {})))
+    s.register_table("lineitem", s.read_parquet(paths))
+    return TpuOverrides(s.conf).apply(s.sql(Q6_TEXT)._node)
+
+
+def _scan_metrics(pp):
+    by_name = {}
+    for node_metrics in pp.last_ctx.metrics.values():
+        for k, m in node_metrics.items():
+            if k in ("scanTime", "assembleTime", "uploadTime",
+                     "uploadWaitTime", "arenaWaitTime"):
+                by_name[k] = by_name.get(k, 0.0) + m.value
+    return by_name
+
+
+def _host_spans(trace_dir):
+    """``(name, start_ns, end_ns, stats, line)`` of every ``spark:``
+    event of the profile written under ``trace_dir``."""
+    import glob
+
+    from jax.profiler import ProfileData
+    path, = glob.glob(os.path.join(trace_dir, "plugins", "profile", "*",
+                                   "*.xplane.pb"))
+    out = []
+    for pl in ProfileData.from_file(path).planes:
+        if pl.name != "/host:CPU":
+            continue
+        for li, ln in enumerate(pl.lines):
+            for e in ln.events:
+                if e.name.startswith("spark:"):
+                    out.append((e.name, e.start_ns,
+                                e.start_ns + e.duration_ns,
+                                dict(e.stats), (li, ln.name)))
+    return out
+
+
+def test_profiler_session_carries_every_span_of_a_parquet_query(
+        tmp_path, monkeypatch):
+    """A query under ``jax.profiler.trace`` and nothing else: the host
+    plane holds every span of the local query path, on the threads that
+    did the work, each with the query's id and a parent that leads to
+    ``spark:query``; no file is written; ``bytes`` of the uploads is
+    what ``jax.device_put`` was handed on the feeder threads."""
+    import jax
+    import numpy as np
+    put, real_put = [], jax.device_put
+
+    def spy(x, *a, **kw):
+        if isinstance(x, np.ndarray) and \
+                threading.current_thread().name.startswith("scan-upload"):
+            put.append(x.nbytes)
+        return real_put(x, *a, **kw)
+
+    monkeypatch.setattr(jax, "device_put", spy)
+    pp = _q6_plan(_lineitem_files(tmp_path))
+    prof = str(tmp_path / "prof")
+    with jax.profiler.trace(prof):
+        table = pp.collect()
+    assert table.num_rows == 1
+    tracer = pp.last_ctx.tracer
+    assert tracer.enabled and tracer is not NULL_TRACER
+    assert not [n for n in os.listdir(tmp_path) if n.startswith("trace-")]
+    spans = _host_spans(prof)
+    names = {s[0] for s in spans}
+    assert names == set(_SPAN_TABLE)
+    by_id = {s[3]["span"]: s for s in spans}
+    assert len(by_id) == len(spans) == len(tracer.spans)
+    root, = [s for s in spans if s[0] == "spark:query"]
+    assert "parent" not in root[3] and root[3]["fingerprint"]
+    for s in spans:
+        assert s[3]["query"] == "q" + tracer.trace_id
+        at = s
+        while at is not root:  # KeyError: a parent that is not there
+            at = by_id[at[3]["parent"]]
+    # whose line each span is on: reads on the pool, the feeder's stages
+    # on a scan-upload thread, the rest on the caller's
+    line_of = lambda n: {s[4] for s in spans if s[0] == n}  # noqa: E731
+    caller, = line_of("spark:query")
+    for n in ("spark:admit", "spark:op", "spark:download", "spark:finish"):
+        assert line_of(n) == {caller}
+    assert caller not in line_of("spark:scan.read")
+    assert any(name.startswith("scan-plan")
+               for _, name in line_of("spark:scan.read"))
+    feeder = line_of("spark:scan.dispatch")
+    assert len(feeder) == 1 and caller not in feeder
+    assert next(iter(feeder))[1].startswith("scan-upload")
+    for n in ("spark:scan.assemble", "spark:scan.arena_wait",
+              "spark:scan.upload"):
+        assert line_of(n) == feeder
+    waits = {s[3]["on"]: s[4] for s in spans if s[0] == "spark:scan.wait"}
+    assert waits["upload"] == caller and waits["read"] != caller
+    # what the spans carry: nine row groups read, nine programs
+    # dispatched under the name they compile under, the node label with
+    # its '#' replaced, the bytes handed to the device
+    reads = [s[3] for s in spans if s[0] == "spark:scan.read"]
+    assert len(reads) == 9 and all(r["chunks"] == 5 and r["bytes"] > 0
+                                   for r in reads)
+    assert {r["file"] for r in reads} == {f"lineitem-0{i}.parquet"
+                                          for i in range(3)}
+    programs = [s[3] for s in spans if s[0] == "spark:scan.dispatch"]
+    assert len(programs) == 9 and all(
+        p["program"] == "jit_scan_decode_chain" and p["fused"]
+        for p in programs)
+    ops = {s[3]["op"] for s in spans if s[0] == "spark:op"}
+    assert ops and all("#" not in o and ":op" in o for o in ops)
+    uploads = [s[3]["bytes"] for s in spans if s[0] == "spark:scan.upload"]
+    assert sum(uploads) == sum(put) and len(uploads) == len(put) == 9
+    down, = [s[3] for s in spans if s[0] == "spark:download"]
+    assert down["rows"] == 1 and down["bytes"] == table.nbytes
+
+
+def test_profile_path_session_carries_the_spans(tmp_path):
+    """``spark.rapids.profile.path`` and nothing else: ``collect`` starts
+    the profiler session BEFORE it makes the ``ExecCtx``, so the query's
+    tracer is live and the profile holds the program's spans."""
+    prof = str(tmp_path / "prof")
+    pp = _q6_plan(_lineitem_files(tmp_path, files=1),
+                  {"spark.rapids.profile.path": prof})
+    assert pp.collect().num_rows == 1
+    assert pp.last_ctx.tracer.enabled
+    names = {s[0] for s in _host_spans(prof)}
+    assert {"spark:query", "spark:scan.read", "spark:scan.dispatch",
+            "spark:download", "spark:finish"} <= names
+
+
+def test_untraced_query_records_no_span(tmp_path, monkeypatch):
+    """No profiler session and no trace directory: the ExecCtx holds the
+    shared no-op, and not one ``Span`` is made."""
+    from spark_rapids_tpu.obs import tracer as tracer_mod
+    made = []
+    real = tracer_mod.Span.__init__
+    monkeypatch.setattr(tracer_mod.Span, "__init__",
+                        lambda self, *a, **kw: (made.append(a),
+                                                real(self, *a, **kw))[1])
+    pp = _q6_plan(_lineitem_files(tmp_path))
+    assert pp.collect().num_rows == 1
+    assert pp.last_ctx.tracer is NULL_TRACER
+    assert made == []
+    # the counters still count: each stage is timed at its one site
+    m = _scan_metrics(pp)
+    assert m["scanTime"] > 0 and m["assembleTime"] > 0 \
+        and m["uploadTime"] > 0 and m["uploadWaitTime"] > 0
+
+
+def test_scan_counters_are_their_spans_and_chrome_nests_them(tmp_path):
+    """``spark.rapids.trace.dir`` alone: each scan counter is the summed
+    duration of its spans, and the Chrome JSON holds the scan's spans
+    under the query span."""
+    trace_dir = str(tmp_path / "traces")
+    pp = _q6_plan(_lineitem_files(tmp_path),
+                  {"spark.rapids.trace.dir": trace_dir})
+    assert pp.collect().num_rows == 1
+    name, = os.listdir(trace_dir)
+    spans = load_chrome_trace(os.path.join(trace_dir, name))
+    total = lambda keep: sum(s["dur"] for s in spans if keep(s))  # noqa
+    m = _scan_metrics(pp)
+    rel = 1e-5  # the JSON rounds a span to a thousandth of a microsecond
+    assert m["scanTime"] == pytest.approx(total(
+        lambda s: s["name"] == "scan.wait" and s["args"]["on"] == "read"),
+        rel=rel)
+    assert m["uploadWaitTime"] == pytest.approx(total(
+        lambda s: s["name"] == "scan.wait"
+        and s["args"]["on"] == "upload"), rel=rel)
+    assert m["assembleTime"] == pytest.approx(total(
+        lambda s: s["name"] == "scan.assemble"), rel=rel)
+    assert m["uploadTime"] == pytest.approx(total(
+        lambda s: s["name"] in ("scan.upload", "scan.dispatch")), rel=rel)
+    assert m["arenaWaitTime"] == pytest.approx(total(
+        lambda s: s["name"] == "scan.arena_wait"), rel=rel)
+    assert m["arenaWaitTime"] > 0
+    query, = [s for s in spans if s["name"] == "query"]
+    scan = [s for s in spans if s["cat"] == "scan"]
+    assert {s["name"] for s in scan} == {
+        "scan.read", "scan.wait", "scan.assemble", "scan.arena_wait",
+        "scan.upload", "scan.dispatch"}
+    assert all(s["parent_id"] == query["span_id"] for s in scan)
+    for n in ("admit", "download", "finish"):
+        s, = [s for s in spans if s["name"] == n]
+        assert s["parent_id"] == query["span_id"]
+        assert query["ts"] <= s["ts"] and \
+            s["ts"] + s["dur"] <= query["ts"] + query["dur"] + 1e-6
+
+
+def test_q6_compiles_only_programs_of_the_registry(tmp_path):
+    """Every program a rehearsal-size Q6 sends to the compiler carries a
+    name of ``programs.PROGRAM_NAMES``: the next closure, lambda or
+    partial handed to ``jax.jit`` on this path (``jit(build)``,
+    ``jit(composed)``, ``jit(<lambda>)``, ``jit(_unknown)``) fails here."""
+    import jax.monitoring
+
+    from spark_rapids_tpu.programs import PROGRAM_NAMES
+    seen = []
+
+    def listen(event, secs, **kw):
+        if event == "/jax/core/compile/backend_compile_duration":
+            seen.append(kw.get("fun_name"))
+
+    jax.monitoring.register_event_duration_secs_listener(listen)
+    try:
+        # sizes no other test uses: these programs are compiled here
+        pp = _q6_plan(_lineitem_files(tmp_path, rows=1311, row_group=437))
+        assert pp.collect().num_rows == 1
+    finally:
+        jax.monitoring.unregister_event_duration_listener(listen)
+    names = {n[len("jit("):-1] for n in seen}
+    assert {"scan_decode_chain", "concat_batches", "agg_final"} <= names
+    assert names <= PROGRAM_NAMES, names - PROGRAM_NAMES
