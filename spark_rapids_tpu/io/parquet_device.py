@@ -952,9 +952,18 @@ def merge_chunk_plans(plans: Sequence[ChunkPlan]) -> ChunkPlan:
 
 # --- device kernel ---------------------------------------------------------
 
-def _expand(words, tab, idx, delta: bool = False):
-    """Expand the run table at dense positions `idx`: uint64 raw bits +
-    (is_rle, is_dict, width) lanes for the caller's interpretation.
+def _run_ids(starts, cap: int, t_n: int):
+    """The run of a sorted run table that covers each dense position
+    0..cap-1: a prefix count of run-start flags, not a search per row
+    (``ops.gather.dense_run_counts``)."""
+    import jax.numpy as jnp
+    from ..ops.gather import dense_run_counts
+    return jnp.clip(dense_run_counts(starts, cap) - 1, 0, t_n - 1)
+
+
+def _expand(words, tab, cap: int, delta: bool = False):
+    """Expand the run table at the dense positions 0..cap-1: uint64 raw
+    bits + (is_rle, is_dict, width) lanes for the caller's interpretation.
     With ``delta`` (static), the expanded lanes are per-value DELTA
     contributions (bit-packed delta + the run's min_delta; a page's
     first value rides an RLE run) and the return value is the
@@ -962,9 +971,9 @@ def _expand(words, tab, idx, delta: bool = False):
     is its own delta stream."""
     import jax.numpy as jnp
     from jax import lax
+    idx = jnp.arange(cap, dtype=jnp.int64)
     starts = tab[:, 0]
-    rid = jnp.clip(jnp.searchsorted(starts, idx, side="right") - 1,
-                   0, tab.shape[0] - 1)
+    rid = _run_ids(starts, cap, tab.shape[0])
     meta = tab[rid, 1]
     width = (meta & 0xFF).astype(jnp.uint64)
     is_rle = (meta >> 8) & 1
@@ -1021,12 +1030,12 @@ def _decode_device(words, tab, dict_arr, def_words, def_tab, n_rows,
     import jax.numpy as jnp
     from jax import lax
     i = jnp.arange(cap, dtype=jnp.int64)
-    def_bits, _ = _expand(def_words, def_tab, i)
+    def_bits, _ = _expand(def_words, def_tab, cap)
     valid = (def_bits & jnp.uint64(1)) != 0
     valid = valid & (i < n_rows)
     # dense index of each valid row into the value stream
     didx = jnp.cumsum(valid.astype(jnp.int32)) - 1
-    bits, is_dict = _expand(words, tab, i, delta=delta)
+    bits, is_dict = _expand(words, tab, cap, delta=delta)
     lane = dict_arr.dtype
     if lane == jnp.bool_:
         vals = (bits & jnp.uint64(1)) != 0
@@ -1260,9 +1269,7 @@ def decode_row_group_device(plans: Dict[str, Tuple[ChunkPlan, dt.DataType]],
                             [jnp.zeros((1,), jnp.int32),
                              jnp.cumsum(ll).astype(jnp.int32)])
                         k = jnp.arange(char_cap, dtype=jnp.int32)
-                        row = jnp.clip(
-                            jnp.searchsorted(offsets, k, side="right") - 1,
-                            0, cap - 1)
+                        row = _run_ids(offsets, char_cap, cap)
                         src = d_offs[idx[row]] + (k - offsets[:-1][row])
                         word = b[jnp.clip(sc_off + (src >> 2), 0,
                                           b.shape[0] - 1)]

@@ -15,9 +15,10 @@ from ..columnar.batch import TpuBatch, row_mask
 from ..columnar.column import TpuColumnVector
 from .strings import gather_strings
 
-__all__ = ["compaction_indices", "exclusive_cumsum", "invert_permutation",
-           "gather_column", "gather_batch", "gather_columns",
-           "compact_batch", "ensure_compacted", "shrink_batch"]
+__all__ = ["compaction_indices", "dense_run_counts", "exclusive_cumsum",
+           "invert_permutation", "gather_column", "gather_batch",
+           "gather_columns", "compact_batch", "ensure_compacted",
+           "shrink_batch"]
 
 
 def inclusive_int_cumsum(x: jax.Array) -> jax.Array:
@@ -30,6 +31,36 @@ def inclusive_int_cumsum(x: jax.Array) -> jax.Array:
     2^31; a float64 prefix (a pair of float32 on the TPU, ~49 bits)
     would be exact to 2^49 but takes 331 s to compile."""
     return jnp.cumsum(x.astype(jnp.int32))
+
+
+_PREFIX_BLOCK = 1024
+
+
+def dense_run_counts(starts: jax.Array, n: int) -> jax.Array:
+    """out[i] = how many of the non-negative `starts` are <= i, for the
+    dense positions i = 0..n-1: what ``jnp.searchsorted(starts,
+    arange(n), side="right")`` gives for sorted starts, so ``out - 1``
+    is the run (row) that covers position i. The queries being every
+    position in order, that is a prefix count of start flags: ONE
+    scatter of len(starts) flags and ONE int32 prefix sum over n lanes,
+    where the search lowers to a `while` of log2(len(starts)) gathers
+    over n lanes (the eight loops that were 7.1 s of q6's 13.0
+    device-busy seconds; ledger, PR 26). `add`, not `set`: zero-length
+    runs share a start and each counts. A start >= n — a padding run at
+    int32.max among them — counts for no position below n.
+
+    The prefix is blocked: within rows of 1024 lanes, plus the prefix of
+    the row totals. On the v5e (PR 27, 2^20 lanes) it compiles in 0.4 s
+    where the 1-D ``jnp.cumsum`` takes 30 s, runs as fast alone (0.9
+    against 0.8 ms), and q6's decode program built on it runs 6 % faster
+    (5.78 against 6.13 s a query: XLA places more of the run tables in
+    fast memory beside it)."""
+    rows = -(-n // _PREFIX_BLOCK)
+    flags = jnp.zeros((rows * _PREFIX_BLOCK,), jnp.int32) \
+        .at[starts.astype(jnp.int32)].add(1, mode="drop")
+    inner = jnp.cumsum(flags.reshape(rows, _PREFIX_BLOCK), axis=1)
+    before = exclusive_cumsum(inner[:, -1])
+    return (inner + before[:, None]).reshape(-1)[:n]
 
 
 def exclusive_cumsum(x: jax.Array) -> jax.Array:

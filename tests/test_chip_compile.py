@@ -124,16 +124,20 @@ def test_q6_filter_project_partial_agg_chain_compiles(chip):
 def test_parquet_dictionary_rle_chunk_decode_compiles(chip):
     """One dictionary/RLE float32 column chunk of a 2^20-row group through
     the device decoder (run table -> funnel-shift unpack -> dictionary
-    gather -> null scatter), built from shapes alone."""
+    gather -> null scatter), built from shapes alone. Its run lookup is
+    a prefix count of run-start flags: a `while` in the compiled program
+    would be the per-row binary search back (2^20 lanes x log2(runs)
+    rounds; 7.1 s of q6's 13.0 device-busy seconds, ledger, PR 26)."""
     from spark_rapids_tpu.io.parquet_device import _decode_device
     cap = 1 << 20
-    jax.jit(_decode_device, static_argnums=(6,)).lower(
+    compiled = jax.jit(_decode_device, static_argnums=(6,)).lower(
         chip((cap // 2,), jnp.uint32),      # bit-packed index words
-        chip((256, 4), jnp.int64),          # run table
+        chip((2048, 4), jnp.int64),         # run table (q6's value tables)
         chip((4096,), jnp.float32),         # dictionary page
         chip((cap // 32 + 2,), jnp.uint32),  # definition-level words
-        chip((64, 4), jnp.int64),           # definition-level runs
+        chip((8, 4), jnp.int64),            # definition-level runs
         chip((), jnp.int64), cap).compile()
+    assert " while(" not in compiled.as_text()
 
 
 # --- sorts and scans at the engine's batch size ---------------------------------
@@ -171,6 +175,19 @@ def test_inclusive_int_cumsum_compiles_as_int32(chip):
                              jax.ShapeDtypeStruct((8,), dtype))
         assert out.dtype == jnp.int32
     jax.jit(inclusive_int_cumsum).lower(chip((ROWS,), jnp.int32)).compile()
+
+
+def test_dense_run_counts_compiles_as_int32_without_a_loop(chip):
+    """The decoder's run lookup at a 2^20-row capacity: int64 run starts
+    in, int32 flags and an int32 prefix (1024-blocked: half a second of
+    compilation where the 1-D cumsum takes 30 s), and no `while`."""
+    from spark_rapids_tpu.ops.gather import dense_run_counts
+    cap = 1 << 20
+    compiled = jax.jit(dense_run_counts, static_argnums=1).lower(
+        chip((2048,), jnp.int64), cap).compile()
+    text = compiled.as_text()
+    assert compiled.out_info.dtype == jnp.int32
+    assert " while(" not in text and "s64[%d]" % cap not in text
 
 
 # --- float64 on the chip ----------------------------------------------------------

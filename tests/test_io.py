@@ -154,6 +154,55 @@ def test_parquet_device_decode_matrix(tmp_path):
         assert _canon(got_dev) == _canon(got_host), codec
 
 
+def _run_table(starts, index_base=0):
+    """A run table as the planner lays it out — (row start, meta, raw,
+    bit position) int64 rows — with constant-RLE runs; a merged group's
+    index base rides in meta bits 16+."""
+    tab = np.zeros((len(starts), 4), np.int64)
+    tab[:, 0] = starts
+    tab[:, 1] = 1 | (1 << 8) | (index_base << 16)
+    return tab
+
+
+def _run_lookup_cases():
+    from spark_rapids_tpu.io.parquet_device import _pad_rows
+    n_rows, cap = 1000, 1024
+    two_groups = np.concatenate([
+        _run_table([0, 100, 100, 400]),
+        _run_table(np.array([0, 250, 499]) + 500, index_base=37)])
+    cases = {
+        "one-run": (_pad_rows(_run_table([0])), cap),
+        "2048-runs": (_run_table(np.arange(2048) * 3), 1 << 13),
+        "zero-length-runs-share-a-start":
+            (_run_table([0, 7, 7, 7, 512, 512, 900, 999]), cap),
+        "starts-at-and-past-n_rows":
+            (_run_table([0, 300, n_rows, n_rows + 11, cap - 1, cap, cap + 5,
+                         2 * cap]), cap),
+        "pad_rows-padding": (_pad_rows(_run_table(np.arange(11) * 90)), cap),
+        "merged-two-groups-with-index-bases": (_pad_rows(two_groups), cap),
+        "capacity-off-the-prefix-block":
+            (_pad_rows(_run_table([0, 5, 5, 1023, 1024, 1499, 1500])), 1500),
+    }
+    return [pytest.param(tab, cap, id=name)
+            for name, (tab, cap) in cases.items()]
+
+
+@pytest.mark.parametrize("tab, cap", _run_lookup_cases())
+def test_parquet_device_run_lookup_is_the_search(tab, cap):
+    """The decoder's run lookup (a prefix count of run-start flags) gives,
+    element for element, the run the per-row binary search it replaced
+    gave: the decode tests around this one prove nulls, delta and strings
+    bit-exact, this one the lookup itself on tables they do not reach."""
+    import jax.numpy as jnp
+    from spark_rapids_tpu.io.parquet_device import _run_ids
+    t_n = tab.shape[0]
+    want = np.clip(
+        np.searchsorted(tab[:, 0], np.arange(cap), "right") - 1, 0, t_n - 1)
+    got = np.asarray(_run_ids(jnp.asarray(tab)[:, 0], cap, t_n))
+    assert got.dtype == np.int32 and got.shape == (cap,)
+    np.testing.assert_array_equal(got, want)
+
+
 def _to_arrow(batch):
     from spark_rapids_tpu.columnar.arrow_bridge import device_to_arrow
     return device_to_arrow(batch)
