@@ -152,6 +152,25 @@ class TpuHashAggregateExec(UnaryExec):
     def expressions(self):
         return list(self.group_exprs) + list(self.aggs)
 
+    PRUNING_NOTE = ("requires the inputs of its grouping keys and "
+                    "aggregates; keeps its whole output")
+
+    def child_requirements(self, required):
+        from .pruning import refs
+        return [refs(self.expressions())]
+
+    def pruned(self, children, maps, required):
+        from ..expr.base import Alias
+        from .pruning import identity_map, remap
+        out = identity_map(len(self._schema.fields))
+        if children[0] is self.child:
+            return self, out
+        aggs = [Alias(remap(a, maps[0]), n)
+                for a, n in zip(self.aggs, self.agg_names)]
+        return TpuHashAggregateExec(
+            [remap(e, maps[0]) for e in self.group_exprs], aggs,
+            children[0]), out
+
     # --- device phases ----------------------------------------------------
 
     def _group_and_gather(self, key_cols, extra_cols, live):
@@ -241,7 +260,8 @@ class TpuHashAggregateExec(UnaryExec):
         from ..columnar.batch import bucket_rows
         from ..ops.gather import shrink_batch
         if self._jit_merge is None:
-            self._jit_merge = jax.jit(self._merge_only, static_argnums=1)
+            self._jit_merge = named_jit("agg_merge", self._merge_only,
+                                        static_argnums=1)
         window = max(1, ctx.mm.budget // 4)
         spill = ctx.metric(self, "spillTime")
         while len(partials) > 1:
@@ -393,7 +413,8 @@ class TpuHashAggregateExec(UnaryExec):
         budget/2: the one-pass path concats a second full copy of the
         input off-ledger."""
         if self._jit_single is None:
-            self._jit_single = jax.jit(self._single_pass, static_argnums=1)
+            self._jit_single = named_jit("agg_single", self._single_pass,
+                                         static_argnums=1)
         op_time = ctx.metric(self, "opTime")
         from ..columnar.arrow_bridge import device_to_arrow
         sbs, total = [], 0
@@ -479,7 +500,8 @@ class TpuHashAggregateExec(UnaryExec):
             yield from self._execute_single_pass(ctx)
             return
         if self._jit_partial is None:
-            self._jit_partial = jax.jit(self._partial, static_argnums=1)
+            self._jit_partial = named_jit("agg_partial", self._partial,
+                                          static_argnums=1)
             self._jit_final = named_jit("agg_final", self._final,
                                         static_argnums=1)
         op_time = ctx.metric(self, "opTime")

@@ -96,6 +96,10 @@ class TpuAQEShuffleReadExec(UnaryExec):
     def describe(self):
         return "AQEShuffleReadExec"
 
+    PRUNING_NOTE = UnaryExec.WRAPPER_PRUNING_NOTE
+    child_requirements = UnaryExec._parents_columns
+    pruned = UnaryExec._wrapper_pruned
+
     def execute(self, ctx: ExecCtx):
         from ..memory import split_batch
         from ..ops.concat import concat_batches_bounded
@@ -215,6 +219,10 @@ class TpuAQEJoinExec(UnaryExec):
 
     def describe(self):
         return "AQEJoinExec"
+
+    PRUNING_NOTE = UnaryExec.WRAPPER_PRUNING_NOTE
+    child_requirements = UnaryExec._parents_columns
+    pruned = UnaryExec._wrapper_pruned
 
     @property
     def output_schema(self):

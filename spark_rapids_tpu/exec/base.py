@@ -430,6 +430,46 @@ class TpuExec:
         clone.children = tuple(children)
         return clone
 
+    # --- required-column pushdown (exec/pruning.py) -----------------------
+    #: how the operator takes part in column pruning, rendered into
+    #: SUPPORTED_OPS.md beside the live ``child_requirements`` overrides
+    PRUNING_NOTE: str = ("requires every column of its children; "
+                         "pruning goes on below it")
+    #: False where the subtree below must stay the objects they are (a
+    #: cache replays what it materialized once)
+    PRUNE_BELOW: bool = True
+
+    def child_requirements(self, required) -> Optional[List[set]]:
+        """Top-down half of column pruning: the ordinals of each child
+        this operator reads when its parent reads the output ordinals
+        ``required`` — its own bound expressions plus what it passes
+        through. ``None`` (the default) states no requirement: the
+        operator requires everything and pruning stops at it."""
+        return None
+
+    def pruned(self, children: Sequence["TpuExec"], maps, required):
+        """Bottom-up half: ``(operator rebuilt over the narrowed
+        children, old -> new ordinal map of its output)``. ``maps`` holds
+        one old -> new ordinal map per child; every ``BoundReference``
+        is re-bound through it (``pruning.remap``). The output may keep
+        more than ``required``; the map must cover ``required``."""
+        raise NotImplementedError(type(self).__name__)
+
+    def __getstate__(self):
+        """What a plan carries when it is copied (``with_new_children``),
+        deep-copied or pickled (cluster tasks): everything but the
+        pruning pass's memo of the trees it built from this node."""
+        state = self.__dict__.copy()
+        state.pop("_pruned_memo", None)
+        return state
+
+    def _passthrough_requirements(self, required):
+        """``child_requirements`` of a unary operator whose output IS
+        its child's: what the parent reads plus what its own
+        expressions (``expr_bindings``) read."""
+        from .pruning import refs
+        return [set(required) | refs(e for e, _ in self.expr_bindings())]
+
     # --- execution --------------------------------------------------------
     def execute(self, ctx: ExecCtx) -> Iterator[TpuBatch]:
         raise NotImplementedError(type(self).__name__)
@@ -611,6 +651,22 @@ class UnaryExec(TpuExec):
     @property
     def output_schema(self) -> dt.Schema:
         return self.child.output_schema
+
+    def _parents_columns(self, required):
+        """``child_requirements`` of a unary operator that reads
+        nothing itself: what its parent reads."""
+        return [set(required)]
+
+    # column pruning of the planner-inserted wrappers (transitions, AQE
+    # reader and join switch): ``Class(child)`` over whatever the child
+    # became
+    WRAPPER_PRUNING_NOTE = ("planner-inserted wrapper: requires its "
+                            "parent's columns")
+
+    def _wrapper_pruned(self, children, maps, required):
+        if children[0] is self.child:
+            return self, maps[0]
+        return type(self)(children[0]), maps[0]
 
 
 class HostBatchSourceExec(LeafExec):

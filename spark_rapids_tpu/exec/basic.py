@@ -58,6 +58,25 @@ class TpuProjectExec(UnaryExec):
     def expressions(self):
         return self.exprs
 
+    PRUNING_NOTE = ("keeps the expressions its parent reads; requires "
+                    "their inputs")
+
+    def child_requirements(self, required):
+        from .pruning import refs
+        return [refs(self.exprs[i] for i in required)]
+
+    def pruned(self, children, maps, required):
+        from .pruning import remap
+        keep = sorted(required)
+        if len(keep) == len(self.exprs) and children[0] is self.child:
+            return self, {i: i for i in keep}
+        # an unnamed expression is named after its position: keep it
+        exprs = [e if hasattr(e, "name") else Alias(e, f.name)
+                 for e, f in ((remap(self.exprs[i], maps[0]),
+                               self._schema.fields[i]) for i in keep)]
+        node = TpuProjectExec(exprs, children[0])
+        return node, {o: i for i, o in enumerate(keep)}
+
     def _run(self, batch: TpuBatch, ectx) -> TpuBatch:
         cols = [e.eval_tpu(batch, ectx) for e in self.exprs]
         return TpuBatch(cols, self._schema, batch.row_count,
@@ -99,6 +118,18 @@ class TpuFilterExec(UnaryExec):
 
     def describe(self):
         return f"FilterExec [{self.condition!r}]"
+
+    PRUNING_NOTE = "requires its predicate's columns and its parent's"
+
+    def child_requirements(self, required):
+        return self._passthrough_requirements(required)
+
+    def pruned(self, children, maps, required):
+        from .pruning import remap
+        if children[0] is self.child:
+            return self, maps[0]
+        return TpuFilterExec(remap(self.condition, maps[0]),
+                             children[0]), maps[0]
 
     def expressions(self):
         return (self.condition,)

@@ -129,6 +129,24 @@ class TpuShuffleExchangeExec(UnaryExec):
         return (f"ShuffleExchangeExec [{type(self.partitioning).__name__} "
                 f"n={self.partitioning.num_partitions}]")
 
+    PRUNING_NOTE = ("requires its partition keys and its parent's "
+                    "columns; a fusable child is narrowed to them")
+
+    def child_requirements(self, required):
+        from .pruning import refs
+        return [set(required)
+                | refs(self.partitioning.key_expressions())]
+
+    def pruned(self, children, maps, required):
+        from .pruning import narrowed, remap
+        if children[0] is self.child:
+            return self, maps[0]
+        child, m = narrowed(children[0], maps[0],
+                            self.child_requirements(required)[0])
+        return TpuShuffleExchangeExec(
+            self.partitioning.map_expressions(lambda e: remap(e, m)),
+            child, transport=self.transport), m
+
     def tpu_supported(self):
         key_exprs = getattr(self.partitioning, "key_exprs", None) or \
             [o.child for o in getattr(self.partitioning, "orders", [])]
@@ -470,6 +488,19 @@ class TpuBroadcastExchangeExec(UnaryExec):
                 return str(e)
         return None
 
+    PRUNING_NOTE = ("requires its parent's columns; a fusable child "
+                    "is narrowed to them")
+
+    child_requirements = UnaryExec._parents_columns
+
+    def pruned(self, children, maps, required):
+        from .pruning import narrowed
+        if children[0] is self.child:
+            return self, maps[0]
+        child, m = narrowed(children[0], maps[0], required)
+        return TpuBroadcastExchangeExec(child, mesh=self.mesh,
+                                        axis=self.axis), m
+
     def spillable(self, ctx: ExecCtx):
         """The catalog handle for the broadcast payload (None if the
         child is empty). Join build sides reuse this handle instead of
@@ -524,6 +555,19 @@ class TpuCoalesceBatchesExec(UnaryExec):
 
     def describe(self):
         return f"CoalesceBatchesExec [target={self.target_rows}]"
+
+    PRUNING_NOTE = ("requires its parent's columns; a fusable child "
+                    "is narrowed to them")
+
+    child_requirements = UnaryExec._parents_columns
+
+    def pruned(self, children, maps, required):
+        from .pruning import narrowed
+        if children[0] is self.child:
+            return self, maps[0]
+        child, m = narrowed(children[0], maps[0], required)
+        return TpuCoalesceBatchesExec(child,
+                                      target_rows=self.target_rows), m
 
     def tpu_supported(self):
         from ..ops.concat import device_concat_supported

@@ -33,6 +33,7 @@ from ..ops.join import (JOIN_TYPES, join_counts, join_gather, join_indices,
                         join_output_bytes, join_total, probe_unique,
                         unique_build_analysis, unique_build_probe,
                         unique_union_lookup)
+from ..programs import named_jit
 from .base import ExecCtx, OpContract, TpuExec
 from .basic import bind_all
 
@@ -78,7 +79,9 @@ class _BaseJoinExec(TpuExec):
                  right_keys: Sequence[Expression], join_type: str,
                  left: TpuExec, right: TpuExec,
                  condition: Optional[Expression] = None,
-                 build_unique_hint: bool = False):
+                 build_unique_hint: bool = False,
+                 left_out: Optional[Sequence[int]] = None,
+                 right_out: Optional[Sequence[int]] = None):
         super().__init__()
         if join_type not in JOIN_TYPES:
             raise ValueError(f"unknown join type {join_type}")
@@ -97,11 +100,19 @@ class _BaseJoinExec(TpuExec):
                 raise TypeError(
                     f"join key type mismatch: {lk.dtype.simple_string()} "
                     f"vs {rk.dtype.simple_string()}")
-        self._schema = _join_output_schema(left.output_schema,
-                                           right.output_schema, join_type)
-        # conditions see both sides even when the output is left-only
-        self._cond_schema = dt.Schema(list(left.output_schema.fields)
-                                      + list(right.output_schema.fields))
+        # the PAYLOAD: the ordinals of each child that are gathered
+        # into the output (all of them unless column pruning narrowed
+        # the join: a column that is only a key is not gathered)
+        self.left_out = list(range(len(left.output_schema.fields))) \
+            if left_out is None else list(left_out)
+        self.right_out = list(range(len(right.output_schema.fields))) \
+            if right_out is None else list(right_out)
+        lpay, rpay = self._pay_schemas = self._payload_schemas()
+        self._schema = _join_output_schema(lpay, rpay, join_type)
+        # conditions see both sides' payload even when the output is
+        # left-only
+        self._cond_schema = dt.Schema(list(lpay.fields)
+                                      + list(rpay.fields))
         self.condition = bind_expr(condition, self._cond_schema) \
             if condition is not None else None
         self._jit_a = None
@@ -124,6 +135,98 @@ class _BaseJoinExec(TpuExec):
     def output_schema(self):
         return self._schema
 
+    def _payload_schemas(self):
+        """(left, right) schemas of the gathered columns, from the
+        CURRENT children."""
+        lf = self.left.output_schema.fields
+        rf = self.right.output_schema.fields
+        return (dt.Schema([lf[i] for i in self.left_out]),
+                dt.Schema([rf[i] for i in self.right_out]))
+
+    def _cut(self, batch: TpuBatch, side: int) -> TpuBatch:
+        """One side's batch cut to its gathered columns (the same
+        buffers; keys are evaluated over the whole batch)."""
+        out = self.right_out if side else self.left_out
+        if len(out) == len(batch.columns):
+            return batch
+        return batch.with_columns([batch.columns[i] for i in out],
+                                  schema=self._pay_schemas[side])
+
+    def _payload(self, lbatch: TpuBatch, rbatch: TpuBatch):
+        return self._cut(lbatch, 0), self._cut(rbatch, 1)
+
+    # --- column pruning (exec/pruning.py) ---------------------------------
+    PRUNING_NOTE = ("requires both key lists, its condition's inputs and "
+                    "the payload its parent reads from each side; a "
+                    "column that is only a key is not gathered")
+
+    def _split_required(self, required):
+        """Output ordinals the parent reads -> (left child ordinals,
+        right child ordinals) of the payload."""
+        nl = len(self.left_out)
+        semi = self.join_type in ("left_semi", "left_anti")
+        lreq = {self.left_out[o] for o in required if o < nl}
+        rreq = set() if semi else \
+            {self.right_out[o - nl] for o in required if o >= nl}
+        if self.condition is not None:
+            from .pruning import refs
+            for o in refs([self.condition]):
+                if o < nl:
+                    lreq.add(self.left_out[o])
+                else:
+                    rreq.add(self.right_out[o - nl])
+        return lreq, rreq
+
+    def child_requirements(self, required):
+        from .pruning import refs
+        lreq, rreq = self._split_required(required)
+        return [lreq | refs(self.left_keys), rreq | refs(self.right_keys)]
+
+    def _rebuilt(self, left, right, left_keys, right_keys, condition,
+                 left_out, right_out):
+        """This join's class over new children, keys and payload."""
+        return type(self)(left_keys, right_keys, self.join_type, left,
+                          right, condition,
+                          build_unique_hint=self.build_unique_hint,
+                          left_out=left_out, right_out=right_out)
+
+    def pruned(self, children, maps, required):
+        from .pruning import narrowed, remap
+        from .pruning import refs
+        lreq, rreq = self._split_required(required)
+        need = [lreq | refs(self.left_keys), rreq | refs(self.right_keys)]
+        left, lm = narrowed(children[0], maps[0], need[0])
+        right, rm = narrowed(children[1], maps[1], need[1])
+        # (the pass never asks for no column at all: a row count keeps
+        # the cheapest one, so one side's payload may be empty, not both)
+        lkeep, rkeep = sorted(lreq), sorted(rreq)
+        semi = self.join_type in ("left_semi", "left_anti")
+        # old output ordinal -> new one, and the condition's old ordinal
+        # space (old payload) -> the new payload's
+        nl_old = len(self.left_out)
+        lpos = {c: i for i, c in enumerate(lkeep)}
+        rpos = {c: i for i, c in enumerate(rkeep)}
+        out_map = {}
+        for o, c in enumerate(self.left_out):
+            if c in lpos:
+                out_map[o] = lpos[c]
+        for o, c in enumerate(self.right_out):
+            if c in rpos:
+                out_map[nl_old + o] = len(lkeep) + rpos[c]
+        new_lout, new_rout = [lm[c] for c in lkeep], [rm[c] for c in rkeep]
+        if left is self.left and right is self.right \
+                and new_lout == self.left_out \
+                and new_rout == self.right_out:
+            return self, out_map
+        node = self._rebuilt(
+            left, right,
+            [remap(k, lm) for k in self.left_keys],
+            [remap(k, rm) for k in self.right_keys],
+            remap(self.condition, out_map), new_lout, new_rout)
+        if semi:
+            out_map = {o: n for o, n in out_map.items() if o < nl_old}
+        return node, out_map
+
     def tpu_supported(self):
         if self.condition is not None and \
                 self.join_type not in ("inner", "cross"):
@@ -145,9 +248,8 @@ class _BaseJoinExec(TpuExec):
         return out
 
     def expected_output_schema(self):
-        return _join_output_schema(self.left.output_schema,
-                                   self.right.output_schema,
-                                   self.join_type)
+        lpay, rpay = self._payload_schemas()
+        return _join_output_schema(lpay, rpay, self.join_type)
 
     def expr_bindings(self):
         # left keys bind against the left child, right keys against the
@@ -157,16 +259,24 @@ class _BaseJoinExec(TpuExec):
         if self.condition is not None:
             # rebuilt from the CURRENT children (not the cached
             # _cond_schema): the check must see what the tree is now
-            cond = dt.Schema(list(self.left.output_schema.fields)
-                             + list(self.right.output_schema.fields))
+            lpay, rpay = self._payload_schemas()
+            cond = dt.Schema(list(lpay.fields) + list(rpay.fields))
             out.append((self.condition, cond))
         return out
+
+    def payload_cols(self) -> int:
+        """Columns gathered into the join's output or its condition."""
+        return len(self._cond_schema.fields)
 
     def describe(self):
         c = f" cond={self.condition!r}" if self.condition is not None \
             else ""
+        narrowed = len(self.left_out) + len(self.right_out) < \
+            len(self.left.output_schema.fields) \
+            + len(self.right.output_schema.fields)
+        p = f" payload={self._cond_schema.names}" if narrowed else ""
         return (f"{self.pretty_name()} [{self.join_type}] "
-                f"keys={list(zip(self.left_keys, self.right_keys))}{c}")
+                f"keys={list(zip(self.left_keys, self.right_keys))}{c}{p}")
 
     # --- staged device execution -----------------------------------------
 
@@ -181,8 +291,9 @@ class _BaseJoinExec(TpuExec):
         rkeys = [k.eval_tpu(rbatch, ectx) for k in self.right_keys]
         plan = join_counts(lkeys, rkeys, lbatch.live_mask(),
                            rbatch.live_mask(), cross=self._cross())
+        lpay, rpay = self._payload(lbatch, rbatch)
         return plan, join_total(plan, jt), \
-            join_output_bytes(plan, lbatch, rbatch, jt)
+            join_output_bytes(plan, lpay, rpay, jt)
 
     def _stage_b(self, jt: str, out_cap: int, plan):
         return join_indices(plan, jt, out_cap)
@@ -221,13 +332,14 @@ class _BaseJoinExec(TpuExec):
         char_caps). One source of truth for the sizing protocol shared
         by the hash-join and nested-loop paths."""
         if self._jit_a is None:
-            self._jit_a = jax.jit(self._stage_a, static_argnums=(2, 3))
+            self._jit_a = named_jit("join_count", self._stage_a,
+                                    static_argnums=(2, 3))
         plan, total_dev, bytes_dev = self._jit_a(lbatch, rbatch,
                                                  ctx.eval_ctx, jt)
         total, nbytes = jax.device_get((total_dev, bytes_dev))
         out_cap = bucket_rows(int(total))
-        char_caps = self._char_caps([int(v) for v in nbytes], lbatch,
-                                    rbatch, jt)
+        char_caps = self._char_caps([int(v) for v in nbytes],
+                                    *self._payload(lbatch, rbatch), jt)
         return plan, out_cap, char_caps
 
     def _stage_ab(self, lbatch: TpuBatch, rbatch: TpuBatch, ctx: ExecCtx,
@@ -240,7 +352,8 @@ class _BaseJoinExec(TpuExec):
         bkey = (jt, out_cap)
         bfn = self._jit_b.get(bkey)
         if bfn is None:
-            bfn = jax.jit(partial(self._stage_b, jt, out_cap))
+            bfn = named_jit("join_indices",
+                            partial(self._stage_b, jt, out_cap))
             self._jit_b[bkey] = bfn
         lidx, ridx, lvalid, rvalid, total_d = bfn(plan)
         return plan, out_cap, lidx, ridx, lvalid, rvalid, total_d, \
@@ -259,9 +372,10 @@ class _BaseJoinExec(TpuExec):
         ckey = (jt, out_cap, char_caps)
         cfn = self._jit_c.get(ckey)
         if cfn is None:
-            cfn = jax.jit(partial(self._stage_bc, jt, out_cap, char_caps))
+            cfn = named_jit("join_gather", partial(
+                self._stage_bc, jt, out_cap, char_caps))
             self._jit_c[ckey] = cfn
-        out = cfn(plan, lbatch, rbatch)
+        out = cfn(plan, *self._payload(lbatch, rbatch))
         if self.condition is not None:
             ectx = ctx.eval_ctx
             pred = self.condition.eval_tpu(out, ectx)
@@ -288,19 +402,21 @@ class _BaseJoinExec(TpuExec):
         if rbatch.capacity == 0:
             return None
         semi = jt in ("left_semi", "left_anti")
-        has_strings = not semi and any(c.is_string_like
-                                       for c in rbatch.columns)
+        has_strings = not semi and any(
+            rbatch.columns[i].is_string_like for i in self.right_out)
         from ..config import JOIN_VERIFY_UNIQUE_HINT
         verify = ctx.conf.get(JOIN_VERIFY_UNIQUE_HINT)
         maxlens: List[int] = []
         analyzed = False
         if not (self.build_unique_hint and not has_strings):
             if self._jit_analysis is None:
-                self._jit_analysis = jax.jit(
+                self._jit_analysis = named_jit(
+                    "join_build_analysis",
                     lambda rb, ectx: unique_build_analysis(
                         [k.eval_tpu(rb, ectx) for k in self.right_keys],
                         rb.live_mask(),
-                        [] if semi else list(rb.columns)),
+                        [] if semi else [rb.columns[i]
+                                         for i in self.right_out]),
                     static_argnums=1)
             facts = [int(v) for v in jax.device_get(
                 self._jit_analysis(rbatch, ctx.eval_ctx))]
@@ -332,7 +448,8 @@ class _BaseJoinExec(TpuExec):
                 and not dt.is_nested(kd) \
                 and not isinstance(kd, dt.NullType):
             if self._jit_probe is None:
-                self._jit_probe = jax.jit(
+                self._jit_probe = named_jit(
+                    "join_build_probe",
                     lambda rb, ectx: unique_build_probe(
                         self.right_keys[0].eval_tpu(rb, ectx),
                         rb.live_mask()),
@@ -347,7 +464,8 @@ class _BaseJoinExec(TpuExec):
             if dup_flag is None:
                 from ..ops.join import build_dup_flag
                 if self._jit_dup is None:
-                    self._jit_dup = jax.jit(
+                    self._jit_dup = named_jit(
+                        "join_build_dup",
                         lambda rb, ectx: build_dup_flag(
                             [k.eval_tpu(rb, ectx)
                              for k in self.right_keys],
@@ -386,17 +504,18 @@ class _BaseJoinExec(TpuExec):
                 eligible_r = eligible_r & k.validity
             ridx, matched = unique_union_lookup(
                 lkeys, rkeys, live_l, live_r, eligible_l, eligible_r)
+        lpay, rpay = self._payload(lbatch, rbatch)
         if jt == "left_semi":
-            return TpuBatch(lbatch.columns, self._schema,
+            return TpuBatch(lpay.columns, self._schema,
                             lbatch.row_count,
                             selection=_and_sel(lbatch, matched))
         if jt == "left_anti":
-            return TpuBatch(lbatch.columns, self._schema,
+            return TpuBatch(lpay.columns, self._schema,
                             lbatch.row_count,
                             selection=_and_sel(lbatch, live_l & ~matched))
-        rcols = gather_columns(rbatch.columns, ridx, matched,
+        rcols = gather_columns(rpay.columns, ridx, matched,
                                list(char_caps))
-        out_cols = list(lbatch.columns) + rcols
+        out_cols = list(lpay.columns) + rcols
         if jt == "inner":
             sel = matched
             if has_cond:
@@ -419,7 +538,7 @@ class _BaseJoinExec(TpuExec):
         char_caps: List[int] = []
         if jt not in ("left_semi", "left_anti"):
             mi = 0
-            for c in rbatch.columns:
+            for c in self._cut(rbatch, 1).columns:
                 if c.is_string_like:
                     need = lbatch.capacity * max(info["maxlens"][mi], 1)
                     if need > _FAST_MAX_CHAR_CAP:
@@ -432,9 +551,9 @@ class _BaseJoinExec(TpuExec):
                self.condition is not None, info["probe"] is not None)
         fn = self._jit_fast.get(key)
         if fn is None:
-            fn = jax.jit(partial(self._fast_kernel, jt, tuple(char_caps),
-                                 self.condition is not None),
-                         static_argnums=3)
+            fn = named_jit("join_probe", partial(
+                self._fast_kernel, jt, tuple(char_caps),
+                self.condition is not None), static_argnums=3)
             self._jit_fast[key] = fn
         return fn(lbatch, rbatch, info["probe"], ctx.eval_ctx)
 
@@ -498,8 +617,16 @@ class _BaseJoinExec(TpuExec):
             # oracle (execute_cpu) handles these correctly.
             raise NotImplementedError(self.tpu_supported())
         op_time = ctx.metric(self, "opTime")
+        label = self.node_label()
+
+        def span(phase):  # spark:op, with the width the join gathers
+            return ctx.tracer.span(label, cat="op", kind="op", args={
+                "op": label, "phase": phase,
+                "payload_cols": self.payload_cols()})
+
         t0 = time.perf_counter()
-        rsb, owned = self._acquire_build(ctx)
+        with span("build"):
+            rsb, owned = self._acquire_build(ctx)
         if rsb is None:
             return
         op_time.value += time.perf_counter() - t0
@@ -508,18 +635,20 @@ class _BaseJoinExec(TpuExec):
                 yield from self._execute_outer_build(rsb, ctx, op_time)
                 return
             t0 = time.perf_counter()
-            fast = self._fast_build_info(rsb.get(), ctx)
+            with span("build"):
+                fast = self._fast_build_info(rsb.get(), ctx)
             op_time.value += time.perf_counter() - t0
             for lbatch in self.left.execute(ctx):
                 t0 = time.perf_counter()
                 out = None
-                if fast is not None:
-                    out = self._fast_join_batch(lbatch, rsb.get(), ctx,
-                                                fast)
-                if out is None:
-                    out = self._join_batch(lbatch, rsb.get(), ctx)
-                if ctx.sync_metrics:
-                    out.block_until_ready()
+                with span("probe"):
+                    if fast is not None:
+                        out = self._fast_join_batch(lbatch, rsb.get(),
+                                                    ctx, fast)
+                    if out is None:
+                        out = self._join_batch(lbatch, rsb.get(), ctx)
+                    if ctx.sync_metrics:
+                        out.block_until_ready()
                 op_time.value += time.perf_counter() - t0
                 yield out
         finally:
@@ -565,8 +694,10 @@ class _BaseJoinExec(TpuExec):
     def execute_cpu(self, ctx: ExecCtx):
         lt = [rb for rb in self.left.execute_cpu(ctx)]
         rt = [rb for rb in self.right.execute_cpu(ctx)]
-        lrows, lkeys = self._cpu_rows(lt, self.left_keys, ctx)
-        rrows, rkeys = self._cpu_rows(rt, self.right_keys, ctx)
+        lrows, lkeys = self._cpu_rows(lt, self.left_keys, ctx,
+                                      self.left_out)
+        rrows, rkeys = self._cpu_rows(rt, self.right_keys, ctx,
+                                      self.right_out)
         jt = self.join_type
         cross = self._cross()
 
@@ -599,19 +730,21 @@ class _BaseJoinExec(TpuExec):
                 matched_right.add(j)
                 emitted = True
             if not emitted and jt in ("left_outer", "full_outer"):
-                out.append(lrows[i] + (None,) * len(self.right.output_schema))
+                out.append(lrows[i] + (None,) * len(self.right_out))
         if jt in ("right_outer", "full_outer"):
-            nl = len(self.left.output_schema)
+            nl = len(self.left_out)
             for j, row in enumerate(rrows):
                 if j not in matched_right:
                     out.append((None,) * nl + row)
         yield self._rows_to_batch(out)
 
-    def _cpu_rows(self, rbs, key_exprs, ctx):
+    def _cpu_rows(self, rbs, key_exprs, ctx, out):
+        """(payload rows, key tuples) of one side: the rows hold the
+        ``out`` columns only, the keys are read from the whole batch."""
         rows: List[tuple] = []
         keys: List[object] = []
         for rb in rbs:
-            cols = [rb.column(i).to_pylist() for i in range(rb.num_columns)]
+            cols = [rb.column(i).to_pylist() for i in out]
             kcols = [k.eval_cpu(rb, ctx.eval_ctx).to_pylist()
                      for k in key_exprs]
             for r in range(rb.num_rows):
@@ -671,8 +804,16 @@ class TpuBroadcastHashJoinExec(_BaseJoinExec):
 
 class TpuCartesianProductExec(_BaseJoinExec):
     def __init__(self, left: TpuExec, right: TpuExec,
-                 condition: Optional[Expression] = None):
-        super().__init__([], [], "cross", left, right, condition)
+                 condition: Optional[Expression] = None,
+                 left_out=None, right_out=None):
+        super().__init__([], [], "cross", left, right, condition,
+                         left_out=left_out, right_out=right_out)
+
+    def _rebuilt(self, left, right, left_keys, right_keys, condition,
+                 left_out, right_out):
+        return TpuCartesianProductExec(left, right, condition,
+                                       left_out=left_out,
+                                       right_out=right_out)
 
 
 class TpuBroadcastNestedLoopJoinExec(_BaseJoinExec):
@@ -688,8 +829,16 @@ class TpuBroadcastNestedLoopJoinExec(_BaseJoinExec):
     outer path."""
 
     def __init__(self, join_type: str, left: TpuExec, right: TpuExec,
-                 condition: Optional[Expression] = None):
-        super().__init__([], [], join_type, left, right, condition)
+                 condition: Optional[Expression] = None,
+                 left_out=None, right_out=None):
+        super().__init__([], [], join_type, left, right, condition,
+                         left_out=left_out, right_out=right_out)
+
+    def _rebuilt(self, left, right, left_keys, right_keys, condition,
+                 left_out, right_out):
+        return TpuBroadcastNestedLoopJoinExec(
+            self.join_type, left, right, condition, left_out=left_out,
+            right_out=right_out)
 
     def tpu_supported(self):
         # condition allowed for every join type here; nested columns
@@ -719,8 +868,8 @@ class TpuBroadcastNestedLoopJoinExec(_BaseJoinExec):
         if cfn is None:
             def build(caps, ectx, lb, rb, li, ri, lv, rv, tot):
                 from ..ops.join import join_gather
-                pair = join_gather(lb, rb, li, ri, lv, rv, tot,
-                                   self._cond_schema, caps)
+                pair = join_gather(*self._payload(lb, rb), li, ri, lv,
+                                   rv, tot, self._cond_schema, caps)
                 pred = self.condition.eval_tpu(pair, ectx)
                 ok = pred.data & pred.validity & pair.live_mask()
                 okl = ok.astype(jnp.int32)
@@ -733,7 +882,8 @@ class TpuBroadcastNestedLoopJoinExec(_BaseJoinExec):
                     num_segments=nr) > 0 if need_mr else None
                 return (pair if need_pair else None, ok, matched_l,
                         matched_r)
-            cfn = jax.jit(partial(build, char_caps, ctx.eval_ctx))
+            cfn = named_jit("join_pairs",
+                            partial(build, char_caps, ctx.eval_ctx))
             self._jit_c[ckey] = cfn
         return cfn(lbatch, rbatch, lidx, ridx, lvalid, rvalid, total_d)
 
@@ -743,9 +893,9 @@ class TpuBroadcastNestedLoopJoinExec(_BaseJoinExec):
         other side, in the output schema."""
         from ..columnar.column import TpuColumnVector
         from ..ops.gather import compact_batch
+        lpay, rpay = self._pay_schemas
         kept = compact_batch(batch, keep)
-        other = self.right.output_schema if left_side \
-            else self.left.output_schema
+        other = rpay if left_side else lpay
         nulls = [TpuColumnVector.nulls(f.dtype, kept.capacity)
                  for f in other.fields]
         cols = (list(kept.columns) + nulls) if left_side \
@@ -784,15 +934,16 @@ class TpuBroadcastNestedLoopJoinExec(_BaseJoinExec):
                     op_time.value += time.perf_counter() - t0
                     yield out
                     t0 = time.perf_counter()
+                lpay = self._cut(lbatch, 0)
                 if jt in ("left_outer", "full_outer"):
                     unmatched = lbatch.live_mask() & ~matched_l
-                    yield self._null_side_batch(lbatch, unmatched, True,
+                    yield self._null_side_batch(lpay, unmatched, True,
                                                 ctx)
                 elif jt == "left_semi":
-                    yield compact_batch(lbatch, matched_l
+                    yield compact_batch(lpay, matched_l
                                         & lbatch.live_mask())
                 elif jt == "left_anti":
-                    yield compact_batch(lbatch, ~matched_l
+                    yield compact_batch(lpay, ~matched_l
                                         & lbatch.live_mask())
                 op_time.value += time.perf_counter() - t0
             if jt in ("right_outer", "full_outer"):
@@ -801,7 +952,8 @@ class TpuBroadcastNestedLoopJoinExec(_BaseJoinExec):
                     unmatched = rbatch.live_mask()
                 else:
                     unmatched = rbatch.live_mask() & ~any_matched_r
-                yield self._null_side_batch(rbatch, unmatched, False, ctx)
+                yield self._null_side_batch(self._cut(rbatch, 1),
+                                            unmatched, False, ctx)
         finally:
             rsb.unpin()
             if owned:

@@ -72,6 +72,21 @@ class TpuUnionExec(TpuExec):
                     for c in self.children))
             for i, f in enumerate(first.fields)])
 
+    PRUNING_NOTE = ("requires the same ordinals of every child; "
+                    "each child is projected to that one layout")
+
+    def child_requirements(self, required):
+        return [set(required) for _ in self.children]
+
+    def pruned(self, children, maps, required):
+        from .pruning import narrowed
+        keep = sorted(required)
+        if all(a is b for a, b in zip(children, self.children)):
+            return self, {i: i for i in keep}
+        kids = [narrowed(c, m, keep, force=True)[0]
+                for c, m in zip(children, maps)]
+        return TpuUnionExec(kids), {o: i for i, o in enumerate(keep)}
+
     def execute(self, ctx: ExecCtx):
         for c in self.children:
             yield from c.execute(ctx)
@@ -123,6 +138,22 @@ class TpuExpandExec(UnaryExec):
 
     def describe(self):
         return f"ExpandExec [{len(self.projections)} projections]"
+
+    PRUNING_NOTE = ("requires the inputs of every projection list; "
+                    "keeps its whole output")
+
+    def child_requirements(self, required):
+        from .pruning import refs
+        return [refs(self.expressions())]
+
+    def pruned(self, children, maps, required):
+        from .pruning import identity_map, remap
+        out = identity_map(len(self._schema.fields))
+        if children[0] is self.child:
+            return self, out
+        return TpuExpandExec(
+            [[remap(e, maps[0]) for e in p] for p in self.projections],
+            self._schema.names, children[0]), out
 
     def expressions(self):
         return [e for p in self.projections for e in p]
@@ -210,6 +241,16 @@ class TpuSampleExec(UnaryExec):
 
     def describe(self):
         return f"SampleExec [fraction={self.fraction} seed={self.seed}]"
+
+    PRUNING_NOTE = "requires its parent's columns"
+
+    child_requirements = UnaryExec._parents_columns
+
+    def pruned(self, children, maps, required):
+        if children[0] is self.child:
+            return self, maps[0]
+        return TpuSampleExec(self.fraction, self.seed,
+                             children[0]), maps[0]
 
     def _keep_mask(self, pos, xp):
         """ONE hash/threshold body for both paths (the dual-run contract

@@ -23,6 +23,7 @@ from ..expr.base import Expression, bind_expr
 from ..ops.concat import concat_batches
 from ..ops.gather import gather_batch
 from ..ops.sort_keys import SortSpec, sort_permutation
+from ..programs import named_jit
 from .base import ExecCtx, OpContract, TpuExec, UnaryExec, fused_batches
 
 __all__ = ["SortOrder", "TpuSortExec", "TpuLocalLimitExec",
@@ -155,9 +156,26 @@ class TpuSortExec(UnaryExec):
     def expressions(self):
         return [o.child for o in self.orders]
 
+    PRUNING_NOTE = ("requires its order keys and its parent's columns; "
+                    "a fusable child is narrowed to them")
+
+    def child_requirements(self, required):
+        return self._passthrough_requirements(required)
+
+    def pruned(self, children, maps, required):
+        from .pruning import narrowed, remap_order
+        if children[0] is self.child:
+            return self, maps[0]
+        child, m = narrowed(children[0], maps[0],
+                            self.child_requirements(required)[0])
+        return TpuSortExec([remap_order(o, m) for o in self.orders],
+                           child, global_sort=self.global_sort), m
+
     def execute(self, ctx: ExecCtx):
         if self._jitted is None:
-            self._jitted = jax.jit(sort_batch_by, static_argnums=(1, 2))
+            self._jitted = named_jit(
+                "sort_batch", lambda b, orders, ectx: sort_batch_by(
+                    b, orders, ectx), static_argnums=(1, 2))
         op_time = ctx.metric(self, "opTime")
         orders = tuple(self.orders)
         if self.global_sort:
@@ -274,7 +292,7 @@ class TpuSortExec(UnaryExec):
                                      .astype(jnp.int32))
                 return out, total, safe_count
 
-            jit_round = jax.jit(merge_round)
+            jit_round = named_jit("sort_merge", merge_round)
 
             while any(cursors[i] < rows[i] for i in range(k)) \
                     or carry is not None:
@@ -372,6 +390,15 @@ class TpuLocalLimitExec(UnaryExec):
 
     def describe(self):
         return f"LocalLimitExec [{self.limit}]"
+
+    PRUNING_NOTE = "requires its parent's columns"
+
+    child_requirements = UnaryExec._parents_columns
+
+    def pruned(self, children, maps, required):
+        if children[0] is self.child:
+            return self, maps[0]
+        return type(self)(self.limit, children[0]), maps[0]
 
     def execute(self, ctx: ExecCtx):
         """Sync-free truncation: a device-resident cumulative row count
@@ -504,6 +531,33 @@ class TpuTopNExec(UnaryExec):
         if self._ctor_project is not None:
             out.extend(self._out.exprs)
         return out
+
+    PRUNING_NOTE = ("requires its order keys and its projection's "
+                    "inputs, or its parent's columns where it has no "
+                    "projection")
+
+    def child_requirements(self, required):
+        from .pruning import refs
+        need = refs(self.expressions())
+        if self._ctor_project is None:
+            need |= set(required)
+        return [need]
+
+    def pruned(self, children, maps, required):
+        from .pruning import identity_map, narrowed, remap, remap_order
+        has_project = self._ctor_project is not None
+        if children[0] is self.child:
+            return self, identity_map(len(self.output_schema.fields)) \
+                if has_project else maps[0]
+        child, m = narrowed(children[0], maps[0],
+                            self.child_requirements(required)[0])
+        project = [remap(e, m) for e in self._out.exprs] \
+            if has_project else None
+        node = TpuTopNExec(
+            self.limit, [remap_order(o, m) for o in self._sort.orders],
+            child, project=project)
+        return node, identity_map(len(node.output_schema.fields)) \
+            if has_project else m
 
     def with_new_children(self, children):
         if children[0] is self.child:

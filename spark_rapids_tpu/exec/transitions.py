@@ -29,6 +29,10 @@ class DeviceToHostExec(UnaryExec):
     FUSION_NOTE = ("barrier: device->host boundary — batches leave the "
                    "device here, there is no device map to fuse")
 
+    PRUNING_NOTE = UnaryExec.WRAPPER_PRUNING_NOTE
+    child_requirements = UnaryExec._parents_columns
+    pruned = UnaryExec._wrapper_pruned
+
     def execute(self, ctx: ExecCtx):
         # the planner places this node under CPU parents only; a device
         # parent calling execute() means the tree was mis-planned — fail
@@ -57,6 +61,10 @@ class HostToDeviceExec(UnaryExec):
     FUSION_NOTE = ("chain root: uploads a CPU island's Arrow batches — "
                    "fusable chains begin above it (its input is host "
                    "data, not a device batch)")
+
+    PRUNING_NOTE = UnaryExec.WRAPPER_PRUNING_NOTE
+    child_requirements = UnaryExec._parents_columns
+    pruned = UnaryExec._wrapper_pruned
 
     def execute(self, ctx: ExecCtx):
         t = ctx.metric(self, "uploadTime")

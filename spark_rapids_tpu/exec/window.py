@@ -212,6 +212,30 @@ class TpuWindowExec(UnaryExec):
     def expressions(self):
         return list(self.win_exprs)
 
+    PRUNING_NOTE = ("requires the inputs of its window expressions and "
+                    "the child columns its parent reads; a fusable child "
+                    "is narrowed to them")
+
+    def child_requirements(self, required):
+        from .pruning import refs
+        n = len(self.child.output_schema.fields)
+        return [{o for o in required if o < n} | refs(self.win_exprs)]
+
+    def pruned(self, children, maps, required):
+        from .pruning import identity_map, narrowed, remap
+        if children[0] is self.child:
+            return self, identity_map(len(self._schema.fields))
+        child, m = narrowed(children[0], maps[0],
+                            self.child_requirements(required)[0])
+        node = TpuWindowExec(
+            [Alias(remap(we, m), name)
+             for we, name in zip(self.win_exprs, self.win_names)], child)
+        n, width = len(self.child.output_schema.fields), \
+            len(child.output_schema.fields)
+        out = dict(m)
+        out.update({n + i: width + i for i in range(len(self.win_exprs))})
+        return node, out
+
     # --- device path ------------------------------------------------------
 
     def _window_batch(self, batch: TpuBatch, ectx) -> TpuBatch:

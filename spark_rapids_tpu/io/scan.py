@@ -303,19 +303,25 @@ def _decode_split(split: FileSplit, fmt: str, columns, batch_rows: int,
     if fmt == "hivetext":
         return _decode_hive_text(split.path, columns, batch_rows,
                                  schema)
+    # (a column the file lacks is left to ``_align``: nulls, as when no
+    # columns are named)
     if fmt == "orc":
         from pyarrow import orc
-        table = orc.ORCFile(split.path).read(columns=columns)
+        f = orc.ORCFile(split.path)
+        table = f.read(columns=None if columns is None else
+                       [c for c in columns if c in f.schema.names])
     elif fmt == "csv":
         from pyarrow import csv
         table = csv.read_csv(split.path)
-        if columns:
-            table = table.select(columns)
+        if columns is not None:
+            table = table.select([c for c in columns
+                                  if c in table.column_names])
     elif fmt == "json":
         from pyarrow import json as pj
         table = pj.read_json(split.path)
-        if columns:
-            table = table.select(columns)
+        if columns is not None:
+            table = table.select([c for c in columns
+                                  if c in table.column_names])
     else:
         raise ValueError(f"unknown scan format {fmt!r}")
     return [rb for rb in table.combine_chunks().to_batches(
@@ -479,6 +485,9 @@ class TpuFileScanExec(LeafExec):
             if kept is not None and not kept:
                 self._part_values = {}
         self._schema = schema
+        # header-less text is split by position: the decoder needs the
+        # files' whole schema whatever columns the plan keeps
+        self._file_schema = schema
 
     def _infer_schema(self) -> dt.Schema:
         if not self.paths:
@@ -513,9 +522,59 @@ class TpuFileScanExec(LeafExec):
             return None
 
     def describe(self):
+        read = ""
+        if len(self._schema.fields) < len(self._file_schema.fields):
+            read = (f" ReadSchema=[{', '.join(self._schema.names)}] "
+                    f"({len(self._schema.fields)} of "
+                    f"{len(self._file_schema.fields)} columns)")
         return (f"FileScanExec [{self.fmt} x{len(self.paths)}"
                 + (f" pushdown={self._conjuncts}" if self._conjuncts else "")
-                + "]")
+                + read + "]")
+
+    # --- column pruning (exec/pruning.py) ---------------------------------
+    PRUNING_NOTE = ("plans, reads, stages and decodes only the required "
+                    "columns, in file order; a row count alone keeps the "
+                    "cheapest column")
+
+    def child_requirements(self, required):
+        return []
+
+    def pruned(self, children, maps, required):
+        keep = sorted(required)
+        mapping = {o: i for i, o in enumerate(keep)}
+        if len(keep) == len(self._schema.fields):
+            return self, mapping
+        return self.reading([self._schema.fields[i].name
+                             for i in keep]), mapping
+
+    def reading(self, names: Sequence[str]) -> "TpuFileScanExec":
+        """This scan cut to the columns ``names`` (file order kept):
+        only their chunks are planned, read, staged and decoded, and
+        the host path hands pyarrow the same list. Row-group pruning by
+        footer statistics goes by the pushed-down predicate's column
+        NAMES in the footer, so it needs no kept column."""
+        import copy
+        wanted = set(names)
+        clone = copy.copy(self)
+        clone.__dict__.pop("_chain_jit_cache", None)
+        clone._schema = dt.Schema([f for f in self._schema.fields
+                                   if f.name in wanted])
+        parts = [f for f in self._part_schema.fields
+                 if f.name in wanted] \
+            if self._part_schema is not None else []
+        clone._part_schema = dt.Schema(parts) if parts else None
+        part_names = {f.name for f in parts}
+        clone.columns = [f.name for f in clone._schema.fields
+                         if f.name not in part_names]
+        return clone
+
+    def registered_as(self, names: Sequence[str]) -> "TpuFileScanExec":
+        """``reading(names)`` as the table's own width: what a user's
+        explicit projection registers (``read_parquet(columns=)``)."""
+        clone = self.reading(names)
+        if self.fmt != "hivetext":  # split by position: keeps the files'
+            clone._file_schema = clone._schema
+        return clone
 
     def pretty_name(self):
         return "FileScanExec"
@@ -544,7 +603,7 @@ class TpuFileScanExec(LeafExec):
     def _decode_with_parts(self, split: FileSplit,
                            batch_rows: int) -> List[pa.RecordBatch]:
         rbs = _decode_split(split, self.fmt, self.columns, batch_rows,
-                            self._conjuncts, schema=self._schema)
+                            self._conjuncts, schema=self._file_schema)
         if self._part_schema is None:
             return rbs
         return _attach_partition_columns(
@@ -662,6 +721,7 @@ class TpuFileScanExec(LeafExec):
                       for i in range(md.num_columns)}
         part_fields = {f.name for f in self._part_schema.fields} \
             if self._part_schema is not None else set()
+        arrow_names = set(pf.schema_arrow.names)
         plans: Dict[str, object] = {}
         host_cols: List[str] = []
         fb_reasons: List[str] = []
@@ -671,6 +731,11 @@ class TpuFileScanExec(LeafExec):
                     continue
                 ci = name_to_ci.get(fld.name)
                 if ci is None:
+                    if fld.name in arrow_names:
+                        # a nested column: its leaves go by other
+                        # names; pyarrow assembles it on the host
+                        host_cols.append(fld.name)
+                        fb_reasons.append("nested")
                     continue  # schema evolution: nulls at assembly
                 try:
                     plans[fld.name] = plan_chunk(
@@ -977,13 +1042,20 @@ class TpuFileScanExec(LeafExec):
         pool = concurrent.futures.ThreadPoolExecutor(
             nthreads, thread_name_prefix="scan-plan")
 
+        widths: List[Tuple[int, int]] = []  # per row group: read, file's
+
         def read(path, g):
             with tracer.span("scan.read", cat="scan", parent_id=parent,
                              args={"file": os.path.basename(path),
                                    "rg": g}) as sp:
                 item = self._plan_row_group(path, g)
+                cols = len(item[1]) + (item[2].num_columns
+                                       if item[2] is not None else 0)
+                file_cols = self._thread_pf(path).metadata.num_columns
+                widths.append((cols, file_cols))
                 sp.set(chunks=len(item[1]), bytes=sum(
-                    plan.encoded_bytes for plan in item[1].values()))
+                    plan.encoded_bytes for plan in item[1].values()),
+                    columns=cols, file_columns=file_cols)
             return item
 
         def planned():
@@ -1101,6 +1173,11 @@ class TpuFileScanExec(LeafExec):
         finally:
             gen.close()
             pool.shutdown(wait=False, cancel_futures=True)
+            # column chunks read and left unread, summed over row groups
+            ctx.metric(self, "columnsRead").value += sum(
+                c for c, _ in widths)
+            ctx.metric(self, "columnsPruned").value += sum(
+                f - c for c, f in widths)
             # early exit: release every ledger charge the consumer never
             # took delivery of (stragglers see closed[0] and release
             # their own)

@@ -13,6 +13,7 @@ import jax.numpy as jnp
 
 from ..columnar.batch import TpuBatch, row_mask
 from ..columnar.column import TpuColumnVector
+from ..programs import named_jit
 from .strings import gather_strings
 
 __all__ = ["compaction_indices", "dense_run_counts", "exclusive_cumsum",
@@ -259,9 +260,11 @@ def compact_batch(batch: TpuBatch, keep: jax.Array) -> TpuBatch:
     return gather_batch(batch, indices, count)  # prefix layout, no selection
 
 
-@jax.jit
 def _compact_selection(batch: TpuBatch) -> TpuBatch:
     return compact_batch(batch, batch.live_mask())
+
+
+_compact_selection = named_jit("compact_selection", _compact_selection)
 
 
 def _shrink_col(c: TpuColumnVector, new_cap: int) -> TpuColumnVector:

@@ -524,7 +524,10 @@ class TpuOverrides:
 
     def apply(self, plan: TpuExec) -> PhysicalPlan:
         self._seen_exchanges = set()
-        meta = self._wrap(plan)
+        # required-column pushdown over the tree as the frontend built
+        # it (exec/pruning.py): always, for every frontend
+        from .exec.pruning import prune_plan
+        meta = self._wrap(prune_plan(plan))
         self._tag(meta)
         root = self._convert(meta)
         self._verify(root)

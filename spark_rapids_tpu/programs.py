@@ -18,7 +18,7 @@ compiles a program whose name is not in the registry.
 """
 from __future__ import annotations
 
-__all__ = ["PROGRAM_NAMES", "named_jit", "module_name"]
+__all__ = ["PROGRAM_NAMES", "EAGER_OPS", "named_jit", "module_name"]
 
 PROGRAM_NAMES = frozenset((
     "scan_decode",        # io/parquet_device.py: fused decode of a row group
@@ -27,6 +27,34 @@ PROGRAM_NAMES = frozenset((
     "fused_stage",        # exec/base.py: one fused operator chain per batch
     "agg_final",          # exec/aggregate.py: the final aggregation
     "concat_batches",     # ops/concat.py: exact and capacity-bounded concat
+    "compact_selection",  # ops/gather.py: a lazy selection made a prefix
+    # exec/joins.py, per build side: key duplication + string lengths,
+    # the sorted build keys of the unique-build probe, the duplicate flag
+    "join_build_analysis",
+    "join_build_probe",
+    "join_build_dup",
+    "join_probe",         # ... per stream batch, unique build: one program
+    "join_count",         # ... staged path: matches, total, string bytes
+    "join_indices",       # ... nested loop: output indices of the pairs
+    "join_gather",        # ... staged path: indices + gather
+    "join_pairs",         # ... nested loop: pair gather + condition
+    "agg_partial",        # exec/aggregate.py: per-batch partial (unfused)
+    "agg_merge",          # ... merge of partials past the batch size
+    "agg_single",         # ... single-pass (collect_*, exact percentile)
+    "sort_batch",         # exec/sort.py: sort (and truncate) one batch
+    "sort_merge",         # ... one round of the out-of-core run merge
+))
+
+#: single-primitive programs JAX compiles for EAGER ``jnp`` calls on the
+#: host path of a join / exchange / limit query (a lazy batch's live-row
+#: count for the operator metrics, the limit's clamp, the exchange
+#: view's partition mask): each well under a millisecond on the chip,
+#: none the engine's own ``jax.jit`` site. Listed so that the registry
+#: test can tell them from an unnamed closure; folding them into named
+#: programs is ROADMAP S0's.
+EAGER_OPS = frozenset((
+    "iota", "less", "bitwise_and", "convert_element_type", "_reduce_sum",
+    "add", "subtract", "clip", "broadcast_in_dim", "equal",
 ))
 
 

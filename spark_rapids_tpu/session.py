@@ -36,6 +36,11 @@ class TpuCacheExec(UnaryExec):
         schema_preserving=True,
         notes="materializes once into the spill catalog and replays")
 
+    PRUNING_NOTE = ("requires every column of its child and keeps the "
+                    "subtree below it as built: it replays what it "
+                    "materialized once, whatever the next query reads")
+    PRUNE_BELOW = False
+
     def __init__(self, child: TpuExec):
         super().__init__(child)
         self._entries = None   # List[SpillableBatch]
@@ -481,16 +486,26 @@ class TpuSession:
         return DataFrame(HostBatchSourceExec(
             rbs, schema=engine_schema(schema)), self)
 
-    def _read(self, paths, fmt: str, schema=None) -> DataFrame:
+    def _read(self, paths, fmt: str, schema=None,
+              columns=None) -> DataFrame:
         from .io import TpuFileScanExec
         if isinstance(paths, str):
             paths = [paths]
-        return DataFrame(
-            TpuFileScanExec(paths, fmt=fmt, schema=schema,
-                            conf=self.conf), self)
+        scan = TpuFileScanExec(paths, fmt=fmt, schema=schema,
+                               conf=self.conf)
+        if columns is not None:
+            missing = [c for c in columns
+                       if c not in scan.output_schema.names]
+            if missing:
+                raise KeyError(f"columns {missing} not found in "
+                               f"{scan.output_schema.names}")
+            scan = scan.registered_as(columns)
+        return DataFrame(scan, self)
 
-    def read_parquet(self, paths, schema=None) -> DataFrame:
-        return self._read(paths, "parquet", schema)
+    def read_parquet(self, paths, schema=None, columns=None) -> DataFrame:
+        """``columns``: the user's own projection, kept in FILE order;
+        the planner's column pruning then narrows within it."""
+        return self._read(paths, "parquet", schema, columns)
 
     def read_csv(self, paths, schema=None) -> DataFrame:
         return self._read(paths, "csv", schema)

@@ -37,6 +37,15 @@ class Partitioning:
     def bind(self, schema: dt.Schema) -> "Partitioning":
         return self
 
+    def key_expressions(self) -> list:
+        """The bound expressions the partition id is computed from."""
+        return []
+
+    def map_expressions(self, fn) -> "Partitioning":
+        """This partitioning with ``fn`` applied to each key expression
+        (column pruning re-binds them to a narrowed child)."""
+        return self
+
     def partition_ids_device(self, batch: TpuBatch, ectx) -> jax.Array:
         raise NotImplementedError
 
@@ -80,6 +89,13 @@ class HashPartitioning(Partitioning):
                              self.num_partitions)
         return p
 
+    def key_expressions(self):
+        return list(self.key_exprs)
+
+    def map_expressions(self, fn):
+        return HashPartitioning([fn(e) for e in self.key_exprs],
+                                self.num_partitions)
+
     def partition_ids_device(self, batch, ectx):
         cols = [e.eval_tpu(batch, ectx) for e in self.key_exprs]
         h = hash_columns_device(cols)
@@ -107,6 +123,17 @@ class RangePartitioning(Partitioning):
         from ..expr.base import bind_expr
         p = RangePartitioning(
             [dataclasses.replace(o, child=bind_expr(o.child, schema))
+             for o in self.orders], self.num_partitions)
+        p.bounds = self.bounds
+        return p
+
+    def key_expressions(self):
+        return [o.child for o in self.orders]
+
+    def map_expressions(self, fn):
+        import dataclasses
+        p = RangePartitioning(
+            [dataclasses.replace(o, child=fn(o.child))
              for o in self.orders], self.num_partitions)
         p.bounds = self.bounds
         return p

@@ -107,7 +107,7 @@ def generate_supported_ops() -> str:
               "--check-docs`.", "",
               "| Operator | Fusion |", "|---|---|"]
     from ..exec.base import (DeviceBatchSourceExec, HostBatchSourceExec,
-                             TpuExec as _TpuExec)
+                             LeafExec as _LeafExec, TpuExec as _TpuExec)
     from ..exec.transitions import DeviceToHostExec, HostToDeviceExec
     # the audit covers the non-Tpu-prefixed participants too: the
     # planner-inserted transitions and the source leaves all carry
@@ -124,6 +124,34 @@ def generate_supported_ops() -> str:
         else:
             cell = cls.FUSION_NOTE
         lines.append(f"| {cls.__name__} | {cell} |")
+    lines += ["", "## Column pruning", "",
+              "Required-column pushdown (`spark_rapids_tpu/exec/"
+              "pruning.py`; Spark's `ColumnPruning`) runs inside "
+              "`TpuOverrides.apply` on every plan, with no switch: "
+              "top-down each operator states which columns of each "
+              "child it reads (`child_requirements`), bottom-up it is "
+              "rebuilt over its narrowed children with every "
+              "`BoundReference` re-bound (`pruned`), and the static "
+              "verifier checks the result. An operator that states no "
+              "requirement requires every column of its children. This "
+              "table is generated from the live `child_requirements` "
+              "overrides and each class's `PRUNING_NOTE`.", "",
+              "| Operator | States a requirement | Pruning |",
+              "|---|---|---|"]
+    from ..session import TpuCacheExec
+    from ..exec.aqe import TpuAQEJoinExec, TpuAQEShuffleReadExec
+    for cls in sorted(audit_classes + [TpuCacheExec, TpuAQEJoinExec,
+                                       TpuAQEShuffleReadExec],
+                      key=lambda c: c.__name__):
+        states = cls.child_requirements is not _TpuExec.child_requirements
+        note = cls.PRUNING_NOTE
+        if not states and issubclass(cls, _LeafExec):
+            note = "leaf: emits its whole schema"
+        if not cls.PRUNE_BELOW:
+            states_cell = "no (pruning stops here)"
+        else:
+            states_cell = "yes" if states else "no (requires everything)"
+        lines.append(f"| {cls.__name__} | {states_cell} | {note} |")
     lines += [
         "", "## Format notes", "",
         "- Parquet device decode "

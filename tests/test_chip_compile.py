@@ -140,6 +140,94 @@ def test_parquet_dictionary_rle_chunk_decode_compiles(chip):
     assert " while(" not in compiled.as_text()
 
 
+# --- the scan chains of the pruned plans (column pruning, exec/pruning.py) ------
+
+def _shape_batch(chip, schema, cap):
+    """A batch of ``schema`` on the described chip, from shapes alone
+    (a string column: offsets and 32 characters a row)."""
+    cols = []
+    for f in schema.fields:
+        if isinstance(f.dtype, dt.StringType):
+            cols.append(TpuColumnVector(
+                f.dtype, validity=chip((cap,), jnp.bool_),
+                offsets=chip((cap + 1,), jnp.int32),
+                chars=chip((cap * 32,), jnp.uint8)))
+        else:
+            cols.append(TpuColumnVector(
+                f.dtype, data=chip((cap,), f.dtype.np_dtype),
+                validity=chip((cap,), jnp.bool_)))
+    return TpuBatch(cols, schema, chip((), jnp.int32))
+
+
+@pytest.mark.parametrize("config,query,widths", [
+    ("tpch-sf1", "tpch/q6", {"lineitem": (4, 1 << 20)}),
+    ("tpcds-sf1-store", "tpcds/q3", {"date_dim": (3, 1 << 17),
+                                     "item": (4, 1 << 15),
+                                     "store_sales": (3, 1 << 19)}),
+])
+def test_pruned_scan_chains_compile(chip, tmp_path, config, query, widths):
+    """The benchmark's two query texts planned over tables registered at
+    full width (16, and 23 + 28 + 22 columns): every scan comes out of
+    the planner cut to the columns the text names, and the chain above
+    it (filter, the narrowing projection, Q6's partial aggregate), bound
+    against that NARROWED schema, compiles for the chip at the capacity
+    of the cell's row groups — the epilogue ``fused_scan_execute``
+    splices into ``scan_decode_chain``."""
+    import sys
+    root = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+    bench = os.path.join(root, "benchmark")
+    sys.path.insert(0, bench)
+    try:
+        import datagen
+        import run
+    finally:
+        sys.path.remove(bench)
+    from spark_rapids_tpu.exec.aggregate import TpuHashAggregateExec
+    from spark_rapids_tpu.exec.base import ExecCtx, UnaryExec
+    from spark_rapids_tpu.io import TpuFileScanExec
+    from spark_rapids_tpu.session import TpuSession
+    config_file = os.path.join(bench, "configs", config + ".json")
+    paths, _, _ = datagen.make_tables(config_file, str(tmp_path), 5, 256)
+    session = TpuSession(dict(run.load_json(config_file)["session_conf"]))
+    for table, files in paths.items():
+        session.register_table(table, session.read_parquet(files))
+    pp = run.plan_of(session, run.read_query(query))
+
+    found = {}
+
+    def visit(node, above):
+        if isinstance(node, TpuFileScanExec):
+            table = next(t for t, fs in paths.items()
+                         if fs[0] in node.paths)
+            fns = []
+            for up in above:  # nearest parent first
+                if isinstance(up, UnaryExec) and up.device_fn() is not None:
+                    fns.append(up.device_fn())
+                    continue
+                if isinstance(up, TpuHashAggregateExec):
+                    fns.append(up._partial)
+                break
+            found[table] = (node, fns)
+        for c in node.children:
+            visit(c, [node] + above)
+
+    visit(pp.root, [])
+    assert {t: len(n.output_schema.fields) for t, (n, _) in found.items()} \
+        == {t: w for t, (w, _) in widths.items()}
+    ectx = ExecCtx().eval_ctx
+    for table, (scan, fns) in found.items():
+        if not fns:
+            continue  # store_sales: the bare decode, no chain
+
+        def composed(b, e, fns=tuple(fns)):
+            for f in fns:
+                b = f(b, e)
+            return b
+        jax.jit(composed, static_argnums=1).lower(
+            _shape_batch(chip, scan.output_schema, widths[table][1]),
+            ectx).compile()
+
+
 # --- sorts and scans at the engine's batch size ---------------------------------
 
 def test_compaction_sort_compiles(chip):

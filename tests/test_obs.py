@@ -635,8 +635,10 @@ def test_profiler_session_carries_every_span_of_a_parquet_query(
     # dispatched under the name they compile under, the node label with
     # its '#' replaced, the bytes handed to the device
     reads = [s[3] for s in spans if s[0] == "spark:scan.read"]
-    assert len(reads) == 9 and all(r["chunks"] == 5 and r["bytes"] > 0
-                                   for r in reads)
+    # (column pruning: Q6 names four of the files' five columns)
+    assert len(reads) == 9 and all(
+        r["chunks"] == r["columns"] == 4 and r["file_columns"] == 5
+        and r["bytes"] > 0 for r in reads)
     assert {r["file"] for r in reads} == {f"lineitem-0{i}.parquet"
                                           for i in range(3)}
     programs = [s[3] for s in spans if s[0] == "spark:scan.dispatch"]
@@ -747,3 +749,77 @@ def test_q6_compiles_only_programs_of_the_registry(tmp_path):
     names = {n[len("jit("):-1] for n in seen}
     assert {"scan_decode_chain", "concat_batches", "agg_final"} <= names
     assert names <= PROGRAM_NAMES, names - PROGRAM_NAMES
+
+
+def _q3_star_files(base, seed=11):
+    """A rehearsal-size store-channel star: the ten columns q3 names and
+    one beside them in each table."""
+    import numpy as np
+    import pyarrow.parquet as pq
+    rng = np.random.default_rng(seed)
+    nd, ni, ns = 211, 157, 1733  # sizes no other test uses
+    brand = rng.integers(1, 30, ni).astype("int32")
+    tables = {
+        "date_dim": pa.table({
+            "d_date_sk": np.arange(1, nd + 1, dtype="int32"),
+            "d_year": rng.integers(1998, 2003, nd).astype("int32"),
+            "d_moy": rng.choice([11, 11, 4], nd).astype("int32"),
+            "d_day_name": pa.array([f"day{j % 7}" for j in range(nd)])}),
+        "item": pa.table({
+            "i_item_sk": np.arange(1, ni + 1, dtype="int32"),
+            "i_brand_id": brand,
+            "i_brand": pa.array([f"brand #{b}" for b in brand]),
+            "i_manufact_id": rng.choice([128, 128, 5], ni).astype("int32"),
+            "i_item_desc": pa.array([f"desc {j}" for j in range(ni)])}),
+        "store_sales": pa.table({
+            "ss_sold_date_sk": rng.integers(1, nd + 1, ns).astype("int32"),
+            "ss_item_sk": rng.integers(1, ni + 1, ns).astype("int32"),
+            "ss_ext_sales_price": np.round(rng.uniform(0, 2e4, ns), 2),
+            "ss_ticket_number": rng.integers(1, 1 << 40, ns)})}
+    paths = {}
+    for name, t in tables.items():
+        paths[name] = [os.path.join(str(base), name + ".parquet")]
+        pq.write_table(t, paths[name][0])
+    return paths
+
+
+def test_q3_compiles_only_named_programs(tmp_path):
+    """Every program a rehearsal-size TPC-DS q3 (two joins, a
+    string-keyed group-by, order-by, limit) sends to the compiler is one
+    of the engine's ``named_jit`` sites, by a name of
+    ``programs.PROGRAM_NAMES``, or a single primitive JAX dispatches for
+    an eager ``jnp`` call (``programs.EAGER_OPS``): a join that reached
+    the trace as ``jit__unknown`` / ``jit__lambda_`` (ROADMAP S0) fails
+    here."""
+    import jax.monitoring
+
+    from spark_rapids_tpu import TpuSession
+    from spark_rapids_tpu.planner import TpuOverrides
+    from spark_rapids_tpu.programs import EAGER_OPS, PROGRAM_NAMES
+    bench = os.path.join(os.path.dirname(os.path.dirname(
+        os.path.abspath(__file__))), "benchmark")
+    with open(os.path.join(bench, "queries", "tpcds", "q3.sql")) as f:
+        text = "\n".join(ln for ln in f.read().splitlines()
+                         if not ln.lstrip().startswith("--"))
+    s = TpuSession(dict(_SCAN_CONF,
+                        **{"spark.sql.shuffle.partitions": "1"}))
+    for name, paths in _q3_star_files(tmp_path).items():
+        s.register_table(name, s.read_parquet(paths))
+    seen = []
+
+    def listen(event, secs, **kw):
+        if event == "/jax/core/compile/backend_compile_duration":
+            seen.append(kw.get("fun_name"))
+
+    jax.monitoring.register_event_duration_secs_listener(listen)
+    try:
+        pp = TpuOverrides(s.conf).apply(s.sql(text)._node)
+        assert pp.collect().num_rows > 10
+    finally:
+        jax.monitoring.unregister_event_duration_listener(listen)
+    names = {n[len("jit("):-1] for n in seen}
+    assert {"scan_decode_chain", "join_build_probe", "join_probe",
+            "join_count", "join_gather", "agg_final",
+            "sort_batch"} <= names, names
+    assert names <= PROGRAM_NAMES | EAGER_OPS, \
+        names - PROGRAM_NAMES - EAGER_OPS
