@@ -17,6 +17,7 @@ from ..programs import named_jit
 from .strings import gather_strings
 
 __all__ = ["compaction_indices", "dense_run_counts", "exclusive_cumsum",
+           "varlen_gather_plan",
            "invert_permutation", "gather_column", "gather_batch",
            "gather_columns", "compact_batch", "ensure_compacted",
            "shrink_batch"]
@@ -94,23 +95,49 @@ def compaction_indices(keep: jax.Array):
     return indices, count
 
 
-def gather_list(col: TpuColumnVector, indices: jax.Array,
-                out_live: jax.Array) -> TpuColumnVector:
-    """Reorder an array/map column by row indices: new offsets are the
-    prefix sum of gathered lengths; each output ELEMENT position finds
-    its row by searchsorted, and the element columns gather recursively
-    by the resulting source-element indices (strings work the same way
-    one level down — gather_strings is this kernel with uint8 chars).
-    The element capacity stays the child's static capacity (each source
-    element appears at most once per gathered row set; duplicates from
-    repeated indices are bounded by the caller's semantics)."""
+def varlen_gather_plan(offsets: jax.Array, indices: jax.Array, out_live,
+                       capacity: int):
+    """The variable-length gather, shared by strings (uint8 chars) and
+    arrays/maps (element columns): (new_offsets, src, live) where
+    new_offsets is the int32 prefix sum of the gathered row lengths and,
+    for each of the `capacity` output payload positions, src is the
+    source payload position and live whether it lies under the total.
+
+    Each output position needs the row that owns it; the positions being
+    every lane in order, that is a prefix count of row-END flags
+    (`dense_run_counts`), never a search per position:
+    `jnp.searchsorted` is a `while` of log2(rows)+1 gathers over every
+    payload lane on the TPU (six of them were 3.4 of q3's 7.1 device-busy
+    seconds; ledger, PR 29). Every row's end counts, dead and zero-length
+    rows included: they share an end with their predecessor and still
+    advance the row id. Source minus output position is one number per
+    row, so the payload lanes gather ONCE by row (36 ms a 2^22-lane
+    gather on the v5e). out_live (if given; any mask, not only a prefix)
+    zeroes the lengths of dead output rows so padding can't inflate the
+    offsets. src is not clipped: past the total it is arbitrary."""
     n = indices.shape[0]
-    lens = col.offsets[1:] - col.offsets[:-1]
-    new_lens = lens[indices]
+    new_lens = (offsets[1:] - offsets[:-1])[indices]
     if out_live is not None:
         new_lens = jnp.where(out_live, new_lens, 0)
     new_offsets = jnp.concatenate(
         [jnp.zeros((1,), jnp.int32), inclusive_int_cumsum(new_lens)])
+    delta = offsets[:-1][indices] - new_offsets[:-1]
+    pos = jnp.arange(capacity, dtype=jnp.int32)
+    row = dense_run_counts(new_offsets[1:], capacity)
+    src = pos + delta[jnp.clip(row, 0, n - 1)]
+    return new_offsets, src, pos < new_offsets[-1]
+
+
+def gather_list(col: TpuColumnVector, indices: jax.Array,
+                out_live: jax.Array) -> TpuColumnVector:
+    """Reorder an array/map column by row indices (varlen_gather_plan):
+    the element columns gather recursively by the resulting
+    source-element indices (strings work the same way one level down —
+    gather_strings is this kernel with uint8 chars).
+    The element capacity stays the child's static capacity (each source
+    element appears at most once per gathered row set; duplicates from
+    repeated indices are bounded by the caller's semantics)."""
+    n = indices.shape[0]
     validity = col.validity[indices]
     if out_live is not None:
         validity = validity & out_live
@@ -118,13 +145,9 @@ def gather_list(col: TpuColumnVector, indices: jax.Array,
     if ecap == 0:
         return col.with_arrays(validity=validity,
                                offsets=jnp.zeros((n + 1,), jnp.int32))
-    src_starts = col.offsets[:-1][indices]
-    e = jnp.arange(ecap, dtype=jnp.int32)
-    row = jnp.searchsorted(new_offsets[1:], e, side="right")
-    row = jnp.clip(row, 0, n - 1).astype(jnp.int32)
-    within = e - new_offsets[row]
-    src = jnp.clip(src_starts[row] + within, 0, ecap - 1)
-    elem_live = e < new_offsets[-1]
+    new_offsets, src, elem_live = varlen_gather_plan(
+        col.offsets, indices, out_live, ecap)
+    src = jnp.clip(src, 0, ecap - 1)
     children = [gather_column(ch, src, elem_live) for ch in col.children]
     return col.with_arrays(validity=validity, offsets=new_offsets,
                            children=children)

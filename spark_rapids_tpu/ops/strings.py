@@ -81,32 +81,15 @@ def string_compare_tpu(a: TpuColumnVector, b: TpuColumnVector) -> jax.Array:
 
 def gather_strings(col: TpuColumnVector, indices: jax.Array,
                    char_capacity: int, out_live=None) -> TpuColumnVector:
-    """Reorder a string column by row indices, all gathers (no scatter —
-    arbitrary scatters serialize on TPU, gathers don't).
-
-    Output offsets = cumulative gathered lengths (log-depth int32
-    associative_scan: serial int cumsum and 24-bit-exact f64-as-f32
-    cumsum both lose on TPU). For each output char position, the
-    owning row comes from one searchsorted over the offsets, then the byte
-    is a single gather from the source. out_live (if given) zeroes the
-    lengths of dead output rows so padding can't inflate the offsets."""
-    n = indices.shape[0]
-    lens = string_lengths(col)
-    new_lens = lens[indices]
-    if out_live is not None:
-        new_lens = jnp.where(out_live, new_lens, 0)
-    from .gather import inclusive_int_cumsum
-    new_offsets = jnp.concatenate(
-        [jnp.zeros((1,), jnp.int32), inclusive_int_cumsum(new_lens)])
-    src_starts = col.offsets[:-1][indices]
-
-    c = jnp.arange(char_capacity, dtype=jnp.int32)
-    row = jnp.searchsorted(new_offsets[1:], c, side="right")
-    row = jnp.clip(row, 0, n - 1).astype(jnp.int32)
-    within = c - new_offsets[row]
-    src = src_starts[row] + within
-    total = new_offsets[-1]
-    valid_pos = c < total
+    """Reorder a string column by row indices: the variable-length gather
+    of `ops.gather.varlen_gather_plan` (offsets by prefix sum, each char
+    position's source by a prefix count of row ends), then the byte is
+    a single gather from the source. out_live (if given; any mask)
+    zeroes the lengths of dead output rows so padding can't inflate the
+    offsets."""
+    from .gather import varlen_gather_plan
+    new_offsets, src, valid_pos = varlen_gather_plan(
+        col.offsets, indices, out_live, char_capacity)
     if col.chars.shape[0]:
         limit = col.chars.shape[0] - 1
         out = jnp.where(valid_pos,

@@ -278,6 +278,28 @@ def test_dense_run_counts_compiles_as_int32_without_a_loop(chip):
     assert " while(" not in text and "s64[%d]" % cap not in text
 
 
+def test_gather_strings_compiles_without_a_loop(chip):
+    """The variable-length gather at the shape q3's second join traces it
+    with (the item side's 2^15 rows of `i_brand` gathered into 2^18 stream
+    rows over a 2^22-lane char buffer; the aggregate's and the sort's
+    calls are 2^18 x 2^22 on both sides): the owner-row lookup is a
+    scatter of row-end flags and a blocked int32 prefix — no `while` of
+    per-character gathers (six were 3.4 s of q3's 7.1 busy seconds;
+    ledger, PR 29) and no int64 lane of the char capacity."""
+    from spark_rapids_tpu.ops.strings import gather_strings
+    src_rows, src_chars, rows, cap = 1 << 15, 1 << 18, 1 << 18, 1 << 22
+    col = TpuColumnVector(dt.STRING, validity=chip((src_rows,), jnp.bool_),
+                          offsets=chip((src_rows + 1,), jnp.int32),
+                          chars=chip((src_chars,), jnp.uint8))
+    compiled = jax.jit(
+        lambda c, idx, live: gather_strings(c, idx, cap, out_live=live)) \
+        .lower(col, chip((rows,), jnp.int32), chip((rows,), jnp.bool_)) \
+        .compile()
+    text = compiled.as_text()
+    assert " while(" not in text and "s64[%d]" % cap not in text
+    assert text.count(" scatter(") == 1
+
+
 # --- float64 on the chip ----------------------------------------------------------
 
 def test_f64_to_s64_bitcast_is_refused_and_s64_to_f64_is_not(chip):
