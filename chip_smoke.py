@@ -1,17 +1,21 @@
 """The quickest proof that the engine still starts on the chip.
 
-    python chip_smoke.py            # one TPU chip: types, q6, nds
+    python chip_smoke.py            # one TPU chip: types, nds
     python chip_smoke.py --chips 4  # four chips: the ICI exchange only
 
 ONE process drives the engine's normal query path — ``TpuSession`` ->
 ``session.sql`` -> ``TpuOverrides`` -> ``TpuFileScanExec`` device decode
 -> fused stage -> join / aggregate / sort / exchange -> Arrow download —
-at the sizes the repo calls its own, checks every result against an
-independent reference, and fails (non-zero exit, no result line) when
-any phase fails or when JAX finds no TPU. Data is generated from fixed
-seeds into the git-ignored ``.bench_cache/``; nothing is read that a
-clean checkout does not hold. It sets no ``JAX_PLATFORMS``, no
-``XLA_FLAGS`` and starts no child process.
+over shapes no cell of the benchmark runs (a types round trip, the NDS
+star's queries from SQL text, the four-chip ICI exchange), checks every
+result against an independent reference, and fails (non-zero exit, no
+result line) when any phase fails or when JAX finds no TPU. Speed is
+``python3 benchmark/run.py --workload <cell>`` and the ledger; the
+instruments here (compile meter, scan counters, the HBM peak, the device
+requirement) ARE the benchmark's, loaded from ``benchmark/``. Data is
+generated from fixed seeds into the git-ignored ``.bench_cache/``;
+nothing is read that a clean checkout does not hold. It sets no
+``JAX_PLATFORMS``, no ``XLA_FLAGS`` and starts no child process.
 
 Every one-chip query runs twice in the same process (cold, then warm
 after the first result was downloaded) with the XLA compile requests,
@@ -24,6 +28,7 @@ so tests/test_chip_smoke.py can drive the same control flow on the CPU
 mesh at a few thousand rows.
 """
 import argparse
+import collections
 import json
 import os
 import sys
@@ -32,18 +37,17 @@ import time
 import numpy as np
 import pyarrow as pa
 
-import bench
 from spark_rapids_tpu.compile_cache import CHECKOUT, enable_compile_cache
 
+BENCHMARK_DIR = os.path.join(CHECKOUT, "benchmark")
+if BENCHMARK_DIR not in sys.path:  # no package: found as run.py finds them
+    sys.path.insert(0, BENCHMARK_DIR)
+import run as benchmark_run  # noqa: E402  (benchmark/run.py)
+from compile_meter import CompileMeter  # noqa: E402
+
+scan_counters = benchmark_run.scan_counters
 DATA_DIR = os.path.join(CHECKOUT, ".bench_cache")
 
-Q6_SQL = """
-SELECT SUM(l_extendedprice * l_discount) AS revenue
-FROM lineitem
-WHERE l_shipdate >= DATE '1994-01-01' AND l_shipdate < DATE '1995-01-01'
-  AND l_discount BETWEEN CAST(0.05 AS FLOAT) AND CAST(0.07 AS FLOAT)
-  AND l_quantity < CAST(24 AS FLOAT)
-"""
 #: the NDS queries the chip run drives (scan + 2 joins + group-by +
 #: order-by + limit; scan + sort + limit). q55, q96 and q_customer_age
 #: were cut for their cold compile cost (CHANGES.md, PR 21); phase_nds
@@ -53,53 +57,23 @@ NDS_QUERIES = ("q3", "q_topn")
 
 # --- measuring ---------------------------------------------------------------
 
-class CompileMeter:
-    """Counts what reaches the XLA compiler, from ``jax.monitoring``.
-
-    Every jit-cache miss is one compile REQUEST (the backend-compile
-    event, which wraps the persistent-cache lookup); ``hits`` of them were
-    served from the persistent cache; ``seconds`` is what the requests
-    took; ``saved`` is what the hits would have cost cold (the compile
-    time JAX stored with each entry), so ``seconds + saved`` is what the
-    same run costs with an empty cache."""
-
-    FIELDS = ("requests", "hits", "seconds", "saved")
-
-    def __init__(self):
-        import jax.monitoring
-        self.requests = self.hits = 0
-        self.seconds = self.saved = 0.0
-        jax.monitoring.register_event_duration_secs_listener(self._dur)
-        jax.monitoring.register_event_listener(self._event)
-
-    def _dur(self, event, secs, **_):
-        if event == "/jax/core/compile/backend_compile_duration":
-            self.requests += 1
-            self.seconds += secs
-        elif event == "/jax/compilation_cache/compile_time_saved_sec":
-            self.saved += max(0.0, secs)
-
-    def _event(self, event, **_):
-        if event == "/jax/compilation_cache/cache_hits":
-            self.hits += 1
-
-    def snapshot(self):
-        return tuple(getattr(self, f) for f in self.FIELDS)
-
-    def since(self, before) -> str:
-        r, h, s, v = (a - b for a, b in zip(self.snapshot(), before))
-        return (f"compile_requests={r} persistent_cache_hits={h} "
-                f"compile_s={s:.2f} cold_compile_s={s + v:.2f}")
-
-
 _METER = None
 
 
 def meter() -> CompileMeter:
+    """The benchmark's compile meter, listening for the whole process."""
     global _METER
     if _METER is None:
-        _METER = CompileMeter()
+        _METER = CompileMeter().__enter__()
     return _METER
+
+
+def compile_traffic(before) -> str:
+    d = meter().since(before)
+    return (f"compile_requests={d['requests']} "
+            f"persistent_cache_hits={d['hits']} "
+            f"compile_s={d['seconds']:.2f} "
+            f"cold_compile_s={d['seconds'] + d['saved']:.2f}")
 
 
 def cold_warm(name, once, labels=("cold", "warm")):
@@ -115,8 +89,8 @@ def cold_warm(name, once, labels=("cold", "warm")):
         t0 = time.perf_counter()
         out.append(once())
         wall = time.perf_counter() - t0
-        print(f"  {name} {label}: wall_s={wall:.3f} {m.since(before)}",
-              flush=True)
+        print(f"  {name} {label}: wall_s={wall:.3f} "
+              f"{compile_traffic(before)}", flush=True)
     return out
 
 
@@ -128,19 +102,36 @@ def phase(name, fn, *args, **kw):
     before = meter().snapshot()
     out = fn(*args, **kw)
     print(f"== phase {name} ok: elapsed_s={time.perf_counter() - t0:.1f} "
-          f"{meter().since(before)}", flush=True)
+          f"{compile_traffic(before)}", flush=True)
     return out
 
 
 def smoke_conf(extra=None):
-    """One shuffle partition (single-chip tuning, as bench.py) and a
-    warehouse directory, so each collect leaves the telemetry row whose
-    ``device_kind`` the smoke checks."""
+    """One shuffle partition (single-chip tuning) and a warehouse
+    directory, so each collect leaves the telemetry row whose
+    ``device_kind`` the NDS phase checks."""
     conf = {"spark.sql.shuffle.partitions": "1",
             "spark.rapids.warehouse.dir":
                 os.path.join(DATA_DIR, "smoke_warehouse")}
     conf.update(extra or {})
     return conf
+
+
+def assert_matches_oracle(name, got, want):
+    """Engine result (an Arrow table) vs the pandas oracle's frame: row
+    count, then per column exact for integers/strings and rtol 1e-5 for
+    floats."""
+    got = got.to_pandas()
+    want = want.reset_index(drop=True)
+    assert len(got) == len(want), (name, len(got), len(want))
+    for ci, c in enumerate(want.columns):
+        w = want[c].to_numpy()
+        g = got.iloc[:, ci].to_numpy()
+        if np.issubdtype(w.dtype, np.floating):
+            assert np.allclose(g.astype(float), w, rtol=1e-5, atol=1e-5), \
+                (name, c, g[:5], w[:5])
+        else:
+            assert (g == w).all(), (name, c, g[:5], w[:5])
 
 
 def planned(df, session):
@@ -154,80 +145,16 @@ def planned(df, session):
     return pp
 
 
-def scan_counters(pp):
-    """Scan coverage summed over the last collect's operators."""
-    tot = {"deviceChunks": 0, "fallbackChunks": 0, "scanPrograms": 0,
-           "fusedDispatches": 0}
-    for node_metrics in pp.last_ctx.metrics.values():
-        for k in tot:
-            if k in node_metrics:
-                tot[k] += int(node_metrics[k].value)
-    return tot
-
-
-# --- phase q6 ----------------------------------------------------------------
-
-def phase_q6(n_rows, n_files, row_group_rows):
-    """BASELINE config 1: TPC-H q6 from snappy Parquet files, SQL text
-    in, Arrow out, the download inside each run."""
-    import jax
-
-    from spark_rapids_tpu import datatypes as dt
-    from spark_rapids_tpu.obs.warehouse import read_rows
-    from spark_rapids_tpu.session import TpuSession
-    cols = bench.gen_lineitem(n_rows)
-    paths = bench.ensure_parquet(
-        cols, n_rows, n_files,
-        cache_dir=os.path.join(
-            DATA_DIR, f"smoke_lineitem_n{n_rows}_f{n_files}"
-                      f"_g{row_group_rows}"),
-        row_group_size=row_group_rows)
-    schema = dt.Schema([
-        dt.StructField("l_quantity", dt.FLOAT32, False),
-        dt.StructField("l_extendedprice", dt.FLOAT32, False),
-        dt.StructField("l_discount", dt.FLOAT32, False),
-        dt.StructField("l_shipdate", dt.DATE, False)])
-    s = TpuSession(conf=smoke_conf())
-    s.register_table("lineitem", s.read_parquet(paths, schema=schema))
-    pp = planned(s.sql(Q6_SQL), s)
-    t_first = time.time()
-    tables = cold_warm("q6", pp.collect)
-
-    # reference: the engine multiplies in float32 and sums in float64
-    mask = ((cols["l_shipdate"] >= 8766) & (cols["l_shipdate"] < 9131)
-            & (cols["l_discount"] >= np.float32(0.05))
-            & (cols["l_discount"] <= np.float32(0.07))
-            & (cols["l_quantity"] < np.float32(24.0)))
-    want = float((cols["l_extendedprice"][mask] * cols["l_discount"][mask])
-                 .astype(np.float64).sum())
-    for t in tables:
-        got = t.column("revenue")[0].as_py()
-        rel = abs(got - want) / max(1.0, abs(want))
-        assert rel < 1e-6, (got, want, rel)
-    c = scan_counters(pp)
-    print(f"  q6 rows={n_rows} revenue={got!r} reference={want!r} "
-          f"rel_err={rel:.2e} deviceChunks={c['deviceChunks']} "
-          f"fallbackChunks={c['fallbackChunks']} "
-          f"scanPrograms={c['scanPrograms']} "
-          f"fusedDispatches={c['fusedDispatches']}")
-    assert c["fallbackChunks"] == 0 and c["deviceChunks"] > 0, c
-
-    # the telemetry row each collect left names the device it ran on
-    rows = [r for r in read_rows(s.conf.get("spark.rapids.warehouse.dir"))
-            if r["ts"] >= t_first]
-    kind = jax.devices()[0].device_kind
-    assert len(rows) == 2 and all(r["device_kind"] == kind for r in rows), \
-        ([r["device_kind"] for r in rows], kind)
-    print(f"  q6 warehouse rows name device_kind={kind!r}")
-
-
 # --- phase nds ---------------------------------------------------------------
 
 def phase_nds(n_sales, row_group_rows, queries=NDS_QUERIES):
     """The NDS-shaped star from Parquet files, each query from SQL TEXT
-    through ``session.sql``, checked against the pandas oracle."""
+    through ``session.sql``, checked against the pandas oracle; each
+    ``collect()`` leaves a warehouse row naming the device it ran on."""
+    import jax
     import pyarrow.parquet as pq
 
+    from spark_rapids_tpu.obs.warehouse import read_rows
     from spark_rapids_tpu.session import TpuSession
     from spark_rapids_tpu.tools.nds import (build_query_sql, gen_tables,
                                             pandas_frames, pandas_oracle,
@@ -248,36 +175,29 @@ def phase_nds(n_sales, row_group_rows, queries=NDS_QUERIES):
     s._nds_frames = (tables, frames)  # build_query_sql reuses these scans
     register_frames(s, frames)
     oracle_frames = pandas_frames(tables)
-    totals = {"deviceChunks": 0, "fallbackChunks": 0, "scanPrograms": 0,
-              "fusedDispatches": 0}
+    totals = collections.Counter()
+    t_first = time.time()
     for name in queries:
         pp = planned(build_query_sql(name, s, tables), s)
         want = pandas_oracle(name, tables, pdt=oracle_frames)
         for got in cold_warm(name, pp.collect):
-            bench.assert_matches_oracle(name, got, want)
+            assert_matches_oracle(name, got, want)
         c = scan_counters(pp)
         assert c["fallbackChunks"] == 0 and c["deviceChunks"] > 0, (name, c)
-        for k in totals:
-            totals[k] += c[k]
+        totals.update(c)
         print(f"  {name} rows_out={len(want)} matches the pandas oracle "
               f"(exact ints, rtol 1e-5 floats)")
     print(f"  nds n_sales={n_sales} queries={len(queries)} "
           + " ".join(f"{k}={v}" for k, v in totals.items()))
 
-
-# --- phase join --------------------------------------------------------------
-
-def phase_join(n_li, n_ord):
-    """bench.py's q97/q72-shaped join + group-by over device-resident
-    batches, checked by its own ``finish_check`` (which downloads)."""
-    run, host_run, finish_check, _ = bench.setup_join_groupby(n_li, n_ord)
-    host_out, _ = host_run()
-
-    def once():
-        finish_check(run(), host_out)
-    cold_warm("join_groupby", once)
-    print(f"  join_groupby {n_li} x {n_ord} rows matches numpy "
-          f"(rtol 2e-3, float32 products)")
+    # the telemetry row each collect left names the device it ran on
+    rows = [r for r in read_rows(s.conf.get("spark.rapids.warehouse.dir"))
+            if r["ts"] >= t_first]
+    kind = jax.devices()[0].device_kind
+    assert len(rows) == 2 * len(queries) \
+        and all(r["device_kind"] == kind for r in rows), \
+        ([r["device_kind"] for r in rows], kind)
+    print(f"  nds warehouse rows={len(rows)} name device_kind={kind!r}")
 
 
 # --- phase types -------------------------------------------------------------
@@ -546,6 +466,13 @@ def phase_ici(devices, n_fact, n_dim, map_batches_per_chip=2):
 
 # --- main --------------------------------------------------------------------
 
+def hbm_peak_gbs(device_kind: str) -> int:
+    """The benchmark's peak for this device (``benchmark/peaks.json``); a
+    device that is not in the table is a ``KeyError``, never a default."""
+    return benchmark_run.load_json(BENCHMARK_DIR,
+                                   "peaks.json")[device_kind]["hbm_gbs"]
+
+
 def report_device(devices):
     """Device facts the records need, and the budget the engine derived
     from them (an unknown TPU kind or a missing bytes_limit is an
@@ -558,7 +485,7 @@ def report_device(devices):
     budget = resolve_device_budget(conf)
     print(f"device platform={d.platform} device_kind={d.device_kind!r} "
           f"count={len(devices)} hbm_peak_gbs="
-          f"{bench.hbm_peak_gbs(d.device_kind)}")
+          f"{hbm_peak_gbs(d.device_kind)}")
     print(f"memory_stats keys={sorted(stats)}")
     print(f"memory bytes_limit={stats.get('bytes_limit')} "
           f"engine_budget={budget} "
@@ -571,15 +498,12 @@ def single_chip_phases():
     """(name, function, arguments) in run order. The list is STATIC —
     what runs never depends on the clock or on what the compile cache
     holds — and sized so that a run with an EMPTY cache fits the 1200 s
-    the driver allows: about 1000 s on the v5e host, nearly all of it
-    compilation (PERF.md sections 5 and 6). ``phase_join`` (bench.py's
-    join + group-by at 2^23 x 2^17 rows, 290 s cold) does not fit beside
-    the two NDS queries and is not in the list; it ran on the chip in
-    PR 21's calls 1, 2 and 7 (CHANGES.md) and runs on the CPU mesh in
-    tests/test_chip_smoke.py."""
+    the driver allows, nearly all of it compilation (PERF.md section 6,
+    PR 21). Q6 from files and a join + group-by are cells of the
+    benchmark (``tpch-sf1.q6.files``, ``tpcds-sf1-store.q3.files``), at
+    the sources' shapes and tighter limits, and are not repeated here."""
     return [
         ("types", phase_types, ()),
-        ("q6", phase_q6, (bench.SF_ROWS, 8, 1 << 20)),
         ("nds", phase_nds, (1 << 21, 1 << 19)),
     ]
 
@@ -596,7 +520,10 @@ def main(argv=None):
     ap.add_argument("--chips", type=int, choices=(1, 4), default=1)
     args = ap.parse_args(argv)
     t_start = time.perf_counter()
-    devices = bench.require_tpu(args.chips)
+    try:
+        devices = benchmark_run.require_devices(args.chips, None)
+    except benchmark_run.Refused as e:  # no TPU, no result line
+        sys.exit(str(e))
     cache_dir = enable_compile_cache()
     import spark_rapids_tpu  # noqa: F401  (x64 on before any array)
     before = meter().snapshot()
@@ -610,7 +537,7 @@ def main(argv=None):
         phase(name, fn, *fn_args)
     print(f"total: phases={','.join(name for name, _, _ in phases)} "
           f"elapsed_s={time.perf_counter() - t_start:.1f} "
-          f"{meter().since(before)}")
+          f"{compile_traffic(before)}")
     for d in devices:
         stats = d.memory_stats() or {}
         print(f"memory device={d.id} bytes_limit={stats.get('bytes_limit')} "

@@ -6,8 +6,8 @@ shipped (see CHANGES.md: the window.py f-string SyntaxError,
 satellites patched by hand) or from the invariants its threaded
 runtime depends on. The engine is `ast`-exact — no regex over source
 text — and reports file:line findings with a machine-readable JSON
-form (`tools/tpu_lint.py --json`, ``schema: 2``); CI gates on zero
-unallowlisted, unbaselined violations (ci_smoke.sh steps 8 and 12).
+form (`tools/tpu_lint.py --json`, ``schema: 2``); tier-1 gates on zero
+unallowlisted, unbaselined violations (tests/test_lint.py).
 
 Statement rules (this module)
 -----------------------------

@@ -4,7 +4,7 @@ Static analysis (analysis/locks.py) proposes the package lock
 hierarchy (:data:`~spark_rapids_tpu.analysis.locks.LOCK_HIERARCHY`);
 this watchdog verifies it against *reality*: in watchdog-enabled runs
 (``RAPIDS_TPU_LOCKWATCH=1`` — tier-1 via tests/conftest.py, cluster
-workers via ``cluster._main``, CI smoke step 12) every
+workers via ``cluster._main``) every
 ``threading.Lock`` / ``RLock`` / ``Condition`` the process creates is
 replaced by a recording proxy. Each *blocking* acquisition checks the
 calling thread's shadow stack: holding a lock of level N while
@@ -36,7 +36,7 @@ zero when not installed. Design points:
 - Inversions are recorded, not raised: a watchdog must never change
   the program it observes. ``report()`` / ``write_report()`` expose
   them; conftest fails the session on a non-empty list, and
-  ``check_obs_output.py --lockwatch`` gates CI.
+  ``check_obs_output.py --lockwatch`` validates a written report.
 
 Crash caveat: a worker that dies via ``os._exit`` (chaos) loses its
 report — the driver-side run still covers the shared-memory paths.

@@ -40,7 +40,7 @@ the event log, and CI match on them):
 The report is machine-readable (`VerifyReport.to_dict`) and the module
 is runnable: ``python -m spark_rapids_tpu.analysis.plan_verifier
 --smoke`` verifies the whole NDS corpus clean and asserts one seeded
-defect is rejected (CI step 8).
+defect is rejected (tier-1 holds both: tests/test_plan_verifier.py).
 """
 from __future__ import annotations
 
@@ -366,11 +366,11 @@ def report_rejection(conf: RapidsConf, report: VerifyReport, root,
     log_plan_rejected(conf, report, root, query_id=query_id)
 
 
-# --- CI smoke -----------------------------------------------------------------
+# --- command-line smoke ------------------------------------------------------
 
 def _smoke() -> int:
     """Verify the whole NDS corpus clean, then seed one broken plan and
-    require its rejection — the gate ci_smoke.sh step 8 runs."""
+    require its rejection."""
     import json
 
     from ..session import TpuSession

@@ -12,8 +12,8 @@ pid or a timestamp — and it is placed from OUTSIDE when the caller asks:
   overrides it);
 - otherwise: the fixed, git-ignored ``<checkout>/.bench_cache/xla``.
 
-``bench.py`` and ``chip_smoke.py`` call :func:`enable_compile_cache`;
-nothing else in the repo sets a cache.
+``chip_smoke.py`` calls :func:`enable_compile_cache`; the benchmark
+places its own cache at the same path (``benchmark/run.py``).
 """
 from __future__ import annotations
 

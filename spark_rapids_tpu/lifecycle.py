@@ -31,8 +31,7 @@ query-service sidecar (ROADMAP item 2) will drive:
   ``rapids_query_degraded_total{rung}`` and the flight recorder.
 
 Everything is default-on behind ``spark.rapids.lifecycle.enabled``
-(the bench A/B kill switch: ``lifecycle_overhead_frac``, audited <= 5%
-like ``obs_overhead_frac``).
+(a kill switch for an A/B no chip run has taken yet: ROADMAP D8).
 """
 from __future__ import annotations
 

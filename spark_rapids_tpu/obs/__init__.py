@@ -34,8 +34,8 @@ a session the caller started); with neither it is near-zero overhead
 (the null tracer's ``span()`` is a shared no-op context manager, and a
 stage whose seconds feed a counter is timed by a bare stopwatch;
 registry updates are plain attribute arithmetic); the flight
-recorder is ON by default — its records are bounded deque appends,
-audited by bench.py's ``obs_overhead_frac``.
+recorder is ON by default — its records are bounded deque appends.
+What any of it costs on the chip has not been measured (ROADMAP D8).
 """
 from .tracer import (NULL_TRACER, Span, Tracer, TRACE_DIR, TRACE_MAX_FILES,
                      TRACE_MAX_SPANS, tracer_from_conf)

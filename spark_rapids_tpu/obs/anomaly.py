@@ -23,7 +23,7 @@ every process (incl. dead worker incarnations), the merged HBM memory
 timeline, a metrics snapshot, plan fallback reasons (the planner taps
 the ring), the non-default conf delta, and per-stage attempt/straggler
 attribution. ``tools/profiling.py triage`` renders it for humans;
-``tools/check_obs_output.py --flight`` schema-checks it in CI.
+``tools/check_obs_output.py --flight`` schema-checks it.
 """
 from __future__ import annotations
 

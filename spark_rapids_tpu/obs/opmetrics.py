@@ -62,8 +62,7 @@ OP_METRICS_ENABLED = register(
     "EXPLAIN ANALYZE, query profiles, and the event log's top-sink "
     "embedding. Recording is two integer adds per batch plus one fused "
     "device readback at the query's natural sync point; disable only "
-    "to rule it out while measuring (bench.py audits the overhead "
-    "A/B under obs_overhead_frac).")
+    "to rule it out while measuring.")
 HISTORY_ENABLED = register(
     "spark.rapids.history.enabled", True,
     "Write one query-profile JSON per executed query (plan with stable "

@@ -561,8 +561,8 @@ class TpuOverrides:
         if mode in ("ALL", "NOT_ON_GPU"):
             text = pp.explain(mode)
             if text:
-                # stderr, never stdout: driver scripts (bench.py) speak a
-                # machine-readable JSON-line protocol on stdout
+                # stderr, never stdout: driver scripts (chip_smoke.py,
+                # benchmark/run.py) end stdout with one JSON result line
                 print(text, file=sys.stderr)
         return pp
 

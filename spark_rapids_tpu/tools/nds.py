@@ -12,8 +12,8 @@ also the HOST BASELINE the driver-facing geomean compares against
 (pandas merge/groupby is the strongest commonly-available single-node
 host engine for these shapes).
 
-Used by tests (dual-run correctness, tests/test_nds.py) and bench.py
-(`nds_subset_geomean_vs_host`).
+Used by tests (dual-run correctness, tests/test_nds.py) and by
+chip_smoke.py's NDS phase.
 """
 from __future__ import annotations
 
@@ -861,8 +861,8 @@ QUERIES = {
 # Every query re-expressed as REAL NDS-style SQL text (comma FROM
 # lists, WHERE-clause join predicates, /*+ UNIQUE(...) */ hints where
 # the hand-built plan passes build_unique=True). tests/test_sql_nds.py
-# dual-runs each against its hand-built plan row-for-row; bench.py
-# drives the corpus from these texts by default.
+# dual-runs each against its hand-built plan row-for-row; chip_smoke.py
+# drives its queries from these texts.
 
 SQL_QUERIES = {
     "q3": """
@@ -1110,15 +1110,14 @@ def build_query(name: str, session, tables):
 def build_query_sql(name: str, session, tables):
     """The SQL-text route to the same query: registers the corpus
     frames as temp views and compiles SQL_QUERIES[name] through
-    ``session.sql`` — the path bench.py drives by default."""
+    ``session.sql`` — the path chip_smoke.py drives."""
     _frames(session, tables)
     return session.sql(SQL_QUERIES[name])
 
 
 def pandas_frames(tables):
-    """One-time arrow->pandas conversion (bench harnesses hoist this
-    out of timed regions: the device side's cached frames paid their
-    upload once too)."""
+    """One-time arrow->pandas conversion (callers that run several
+    queries hoist this out of their loops)."""
     return {k: v.to_pandas() for k, v in tables.items()}
 
 

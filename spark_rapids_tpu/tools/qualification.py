@@ -81,8 +81,9 @@ def qualify(plan: TpuExec,
 # speedup per query with Amdahl over per-operator acceleration factors
 # measured on this engine's own benchmarks.
 
-# conservative per-op speedup factors (device vs host) from bench.py /
-# NDS measurements; unknown ops use DEFAULT_FACTOR
+# per-op speedup factors (device vs host) that NO chip run produced
+# (ROADMAP D14: derive them from ledger cells or stop printing a
+# speed-up); unknown ops use DEFAULT_FACTOR
 _OP_FACTORS = {
     "HashAggregateExec": 40.0, "ShuffledHashJoinExec": 80.0,
     "BroadcastHashJoinExec": 80.0, "SortExec": 25.0,

@@ -8,7 +8,10 @@ Plan-level helpers are added with the session API.
 """
 from __future__ import annotations
 
+import functools
+import importlib.util
 import math
+import os
 
 import numpy as np
 import pyarrow as pa
@@ -18,6 +21,20 @@ from spark_rapids_tpu.columnar import arrow_to_device
 from spark_rapids_tpu.columnar.arrow_bridge import device_column_to_arrow
 from spark_rapids_tpu.expr.base import EvalCtx, bind_expr
 from spark_rapids_tpu.columnar.arrow_bridge import engine_schema
+
+
+@functools.lru_cache(maxsize=None)
+def obs_checker():
+    """``tools/check_obs_output.py`` as a module (it keeps no state, so
+    one per process): the operator's schema checker is the oracle for
+    every trace, Prometheus dump, incident bundle, query profile, lint
+    and lock-order report a test makes the engine write."""
+    path = os.path.join(os.path.dirname(os.path.abspath(__file__)),
+                        os.pardir, "tools", "check_obs_output.py")
+    spec = importlib.util.spec_from_file_location("check_obs_output", path)
+    mod = importlib.util.module_from_spec(spec)
+    spec.loader.exec_module(mod)
+    return mod
 
 
 def _norm_nested(v):

@@ -25,7 +25,6 @@ from spark_rapids_tpu.columnar.batch import TpuBatch
 from spark_rapids_tpu.columnar.column import TpuColumnVector
 
 ROWS = 1 << 21          # the engine's default batch capacity at bench sizes
-KERNEL_ROWS = 8 * 2048 * 128   # bench.py's Pallas A/B shape (8 chunks)
 
 
 @pytest.fixture(scope="module")
@@ -74,38 +73,39 @@ def _batch(chip, fields, n=ROWS):
                     chip((), jnp.int32))
 
 
-# --- the Pallas kernels that are kept ------------------------------------------
-
-def test_masked_product_sum_pallas_compiles(chip):
-    from spark_rapids_tpu.ops.pallas_kernels import masked_product_sum_pallas
-    f32 = chip((KERNEL_ROWS,), jnp.float32)
-    compiled = masked_product_sum_pallas.lower(
-        f32, f32, f32, chip((KERNEL_ROWS,), jnp.int32), False).compile()
-    assert "tpu_custom_call" in compiled.as_text()
-
-
-def test_fused_filter_agg_pallas_compiles(chip):
-    from spark_rapids_tpu.ops.pallas_kernels import fused_filter_agg_pallas
-    f32 = chip((KERNEL_ROWS,), jnp.float32)
-    i32 = chip((KERNEL_ROWS,), jnp.int32)
-    compiled = fused_filter_agg_pallas.lower(
-        i32, f32, f32, f32, i32, False).compile()
-    assert "tpu_custom_call" in compiled.as_text()
-
-
 # --- the q6 stage: decode, then filter -> project -> partial aggregate --------
+
+def _q6_stage(src):
+    """Q6's filter -> project -> ungrouped sum over float32 columns."""
+    from spark_rapids_tpu.exec.aggregate import TpuHashAggregateExec
+    from spark_rapids_tpu.exec.basic import TpuFilterExec, TpuProjectExec
+    from spark_rapids_tpu.expr import (Alias, And, GreaterThanOrEqual,
+                                       LessThan, LessThanOrEqual, Literal,
+                                       Multiply, UnresolvedColumn as col)
+    from spark_rapids_tpu.expr.aggregates import Sum
+    f32 = lambda v: Literal(np.float32(v), dt.FLOAT32)  # noqa: E731
+    cond = And(
+        And(GreaterThanOrEqual(col("l_shipdate"), Literal(8766, dt.DATE)),
+            LessThan(col("l_shipdate"), Literal(9131, dt.DATE))),
+        And(And(GreaterThanOrEqual(col("l_discount"), f32(0.05)),
+                LessThanOrEqual(col("l_discount"), f32(0.07))),
+            LessThan(col("l_quantity"), f32(24.0))))
+    proj = TpuProjectExec(
+        [Alias(Multiply(col("l_extendedprice"), col("l_discount")), "rev")],
+        TpuFilterExec(cond, src))
+    return TpuHashAggregateExec([], [Alias(Sum(col("rev")), "revenue")], proj)
+
 
 def test_q6_filter_project_partial_agg_chain_compiles(chip):
     """The epilogue the fused scan program splices after decode, composed
     exactly as ``exec.base.fused_batches`` composes it, at a 2^20-row
     capacity."""
-    import bench
     from spark_rapids_tpu.exec.base import (DeviceBatchSourceExec, ExecCtx,
                                             UnaryExec)
     fields = [("l_quantity", dt.FLOAT32), ("l_extendedprice", dt.FLOAT32),
               ("l_discount", dt.FLOAT32), ("l_shipdate", dt.DATE)]
     batch = _batch(chip, fields, 1 << 20)
-    agg, _ = bench.build_q6(DeviceBatchSourceExec([], batch.schema))
+    agg = _q6_stage(DeviceBatchSourceExec([], batch.schema))
     fns, node = [], agg.children[0]
     while isinstance(node, UnaryExec) and node.device_fn() is not None:
         fns.insert(0, node.device_fn())

@@ -24,15 +24,11 @@ def data_under_tmp(tmp_path, monkeypatch):
 
 @pytest.mark.parametrize("phase,sizes", [
     ("phase_types", ()),
-    ("phase_q6", (20_000, 3, 4096)),
-    ("phase_join", (1 << 14, 1 << 8)),
 ])
 def test_single_chip_phase_runs_and_checks_itself(phase, sizes, capsys):
     getattr(chip_smoke, phase)(*sizes)
     out = capsys.readouterr().out
-    assert "warm: wall_s=" in out or phase == "phase_types"
-    if phase == "phase_q6":
-        assert "fallbackChunks=0" in out and "device_kind='cpu'" in out
+    assert "bit-exact round trip" in out
 
 
 @pytest.mark.parametrize("query", chip_smoke.NDS_QUERIES
@@ -60,21 +56,46 @@ def test_ici_phase_spreads_over_four_virtual_devices(capsys):
     assert all(f"[{i}]" in landed for i in ids), landed
 
 
+def test_nds_phase_leaves_a_warehouse_row_per_collect_naming_the_device(
+        capsys):
+    """Two queries, a cold and a warm collect each: four rows, each
+    naming the device the query ran on."""
+    from spark_rapids_tpu.obs.warehouse import read_rows
+    chip_smoke.phase_nds(1 << 12, 1 << 10, queries=("q_topn", "q96"))
+    rows = read_rows(chip_smoke.smoke_conf()["spark.rapids.warehouse.dir"])
+    assert len(rows) == 4
+    assert {r["device_kind"] for r in rows} == {"cpu"}
+    assert "nds warehouse rows=4 name device_kind='cpu'" \
+        in capsys.readouterr().out
+
+
 def test_single_chip_phase_list_is_static_and_holds_the_minimum():
     """What the driver's run covers never depends on the clock or on the
     compile cache: one fixed list, with q3 and q_topn among the NDS
-    queries."""
+    queries. Q6 and the join are cells of the benchmark, not phases."""
     names = [name for name, _, _ in chip_smoke.single_chip_phases()]
-    assert names == ["types", "q6", "nds"]
+    assert names == ["types", "nds"]
     assert {"q3", "q_topn"} <= set(chip_smoke.NDS_QUERIES)
 
 
 def test_phase_failure_is_not_swallowed(monkeypatch):
     """A wrong answer ends the run: the phases have no try/except."""
-    monkeypatch.setattr(chip_smoke, "Q6_SQL",
-                        chip_smoke.Q6_SQL.replace("0.07", "0.06"))
+    from spark_rapids_tpu.tools import nds
+    monkeypatch.setitem(nds.SQL_QUERIES, "q96", nds.SQL_QUERIES["q96"]
+                        .replace("BETWEEN 40 AND 60", "BETWEEN 40 AND 59"))
     with pytest.raises(AssertionError):
-        chip_smoke.phase_q6(20_000, 3, 4096)
+        chip_smoke.phase_nds(1 << 12, 1 << 10, queries=("q96",))
+
+
+def test_instruments_are_the_benchmarks_own():
+    """One compile meter, one scan-counter reader, one device rule: the
+    yardstick's (the harness tests import ``run`` the same way)."""
+    import compile_meter
+    import run
+    assert run.__file__ == os.path.join(chip_smoke.BENCHMARK_DIR, "run.py")
+    assert chip_smoke.CompileMeter is compile_meter.CompileMeter
+    assert chip_smoke.scan_counters is run.scan_counters
+    assert isinstance(chip_smoke.meter(), compile_meter.CompileMeter)
 
 
 @pytest.mark.parametrize("argv", [[], ["--chips", "4"]])
@@ -87,18 +108,11 @@ def test_main_on_a_cpu_backend_exits_nonzero_naming_the_platform(
     assert capsys.readouterr().out == ""  # no result line, nothing else
 
 
-def test_bench_main_on_a_cpu_backend_exits_nonzero():
-    import bench
-    with pytest.raises(SystemExit) as ei:
-        bench.main()
-    assert "platform='cpu'" in str(ei.value.code)
-
-
 def test_unknown_device_kind_has_no_default_peak():
-    import bench
-    assert bench.hbm_peak_gbs("TPU v5 lite") == 819
+    """The peak is the benchmark's table's (``benchmark/peaks.json``)."""
+    assert chip_smoke.hbm_peak_gbs("TPU v5 lite") == 819
     with pytest.raises(KeyError, match="TPU v9"):
-        bench.hbm_peak_gbs("TPU v9")
+        chip_smoke.hbm_peak_gbs("TPU v9")
 
 
 # --- the compile-cache helper -------------------------------------------------

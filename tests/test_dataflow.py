@@ -470,7 +470,7 @@ def test_package_lock_registry_matches_known_locks(package_project):
 
 @pytest.mark.skipif(not lockwatch.env_enabled(),
                     reason="needs RAPIDS_TPU_LOCKWATCH=1 (conftest "
-                           "bootstrap) — CI step 12 runs it")
+                           "bootstrap)")
 def test_import_time_singleton_locks_are_watched():
     """The conftest bootstrap installs the watchdog BEFORE the package
     imports, so module-level singleton locks created at import time
@@ -594,6 +594,40 @@ def test_watchdog_condition_machinery_stays_healthy(watchdog):
                               window=2, weigher=lambda x: 1,
                               max_weight=2)) == [0, 2, 4, 6, 8, 10,
                                                  12, 14]
+
+
+def test_watchdog_report_of_a_scan_passes_the_checker_until_an_inversion(
+        watchdog, tmp_path):
+    """A parquet query through the scan's threads, the upload tunnel
+    and the memory manager, run under the watchdog: the written report
+    is one ``check_obs_output.py --lockwatch`` accepts (installed,
+    acquisitions checked, zero inversions); a seeded inversion is one
+    it refuses by name."""
+    import pyarrow as pa
+    import pyarrow.parquet as pq
+    from asserts import obs_checker
+    from spark_rapids_tpu.session import TpuSession
+    data = str(tmp_path / "t.parquet")
+    pq.write_table(pa.table({"k": [i % 7 for i in range(4096)],
+                             "v": list(range(4096))}),
+                   data, row_group_size=512)
+    s = TpuSession()
+    s.register_table("t", s.read_parquet(data))
+    base = len(watchdog.report()["inversions"])
+    got = s.sql("SELECT k, SUM(v) AS sv FROM t GROUP BY k").collect()
+    assert got.num_rows == 7
+    assert len(watchdog.report()["inversions"]) == base
+    path = watchdog.write_report(str(tmp_path / "lw.json"))
+    if base == 0:
+        assert obs_checker().check_lockwatch(path) == []
+    mgr, sb = _mem_pair()
+    with mgr._lock:
+        with sb._state_lock:
+            pass
+    errors = obs_checker().check_lockwatch(
+        watchdog.write_report(str(tmp_path / "lw2.json")))
+    assert any("INVERSION" in e and "SpillableBatch._state_lock" in e
+               for e in errors), errors
 
 
 def test_watchdog_report_and_assert_clean(watchdog, tmp_path):

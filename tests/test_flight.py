@@ -7,7 +7,6 @@ one incident bundle containing the dead worker's preceding ring events,
 a memory timeline with a nonzero high-water mark, and straggler/attempt
 attribution naming the failed attempt, and ``profiling triage`` renders
 it without error."""
-import importlib.util
 import json
 import os
 import threading
@@ -17,6 +16,7 @@ import urllib.request
 import pyarrow as pa
 import pytest
 
+from asserts import obs_checker as _load_checker
 from data_gen import IntegerGen, LongGen, gen_table
 
 from spark_rapids_tpu.config import RapidsConf
@@ -30,15 +30,6 @@ from spark_rapids_tpu.obs.recorder import (RECORDER, FlightRecorder,
                                            read_flight_dumps,
                                            read_worker_rings)
 from spark_rapids_tpu.tools.profiling import triage_report
-
-
-def _load_checker():
-    path = os.path.join(os.path.dirname(__file__), "..", "tools",
-                        "check_obs_output.py")
-    spec = importlib.util.spec_from_file_location("check_obs_fl", path)
-    mod = importlib.util.module_from_spec(spec)
-    spec.loader.exec_module(mod)
-    return mod
 
 
 # --- ring buffer ------------------------------------------------------------

@@ -415,11 +415,13 @@ def test_parquet_device_decode_plain_strings_matrix(tmp_path):
             assert fb == 0 and dev > 0, (ver, target, dev, fb)
 
 
-def test_parquet_device_decode_delta_matrix(tmp_path):
+@pytest.mark.parametrize("page_version", ["1.0", "2.0"])
+def test_parquet_device_decode_delta_matrix(tmp_path, page_version):
     """DELTA_BINARY_PACKED int32/int64 (negative deltas, nulls,
     multi-page chunks — the device prefix sum restarts per page) and
-    DELTA_LENGTH_BYTE_ARRAY strings (nulls, empties): bit-exact vs the
-    CPU oracle across per-group and coalesced dispatch, zero
+    DELTA_LENGTH_BYTE_ARRAY strings (nulls, empties), in v1 pages and
+    in DATA_PAGE_V2 pages beside a PLAIN string column: bit-exact vs
+    the CPU oracle across per-group and coalesced dispatch, zero
     fallbacks."""
     rng = np.random.default_rng(29)
     n = 16_000
@@ -432,14 +434,17 @@ def test_parquet_device_decode_delta_matrix(tmp_path):
         "dls": pa.array([None if i % 11 == 0 else
                          ["", f"dl-{i % 53}", "長い" * (i % 4)][i % 3]
                         for i in range(n)]),
+        "ps": pa.array([None if i % 13 == 0 else f"plain-{i % 97}"
+                        for i in range(n)]),
     }
     p = os.path.join(str(tmp_path), "delta.parquet")
     pq.write_table(pa.table(arrays), p, use_dictionary=False,
                    compression="snappy", row_group_size=4000,
-                   data_page_size=4 << 10,
+                   data_page_size=4 << 10, data_page_version=page_version,
                    column_encoding={"d32": "DELTA_BINARY_PACKED",
                                     "d64": "DELTA_BINARY_PACKED",
-                                    "dls": "DELTA_LENGTH_BYTE_ARRAY"})
+                                    "dls": "DELTA_LENGTH_BYTE_ARRAY",
+                                    "ps": "PLAIN"})
     want = pa.Table.from_batches(
         list(TpuFileScanExec([p]).execute_cpu(ExecCtx())))
     for target in ("0", "1g"):

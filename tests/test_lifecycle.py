@@ -3,9 +3,8 @@ cancellation (user / deadline / budget / admission), fair per-tenant
 admission, the cancel-aware upload pipeline, the memory-pressure
 degradation ladder, the query-scoped chaos modes — and the
 process-cluster cancel paths, asserting zero ledger/slot leakage after
-every cancel. The cluster tests run in CI step 12's
-lockwatch-enabled file set, so every path here is also a lock-order
-witness."""
+every cancel. Run with ``RAPIDS_TPU_LOCKWATCH=1`` every path here is
+also a lock-order witness (tests/conftest.py)."""
 import json
 import os
 import threading
@@ -416,15 +415,17 @@ def test_cluster_user_cancel_midstage_no_leaks(tmp_path):
 
 def test_cluster_deadline_cancel_with_incident(tmp_path):
     """Deadline-exceeded under hang_query: classified deadline cancel,
-    exactly one query_cancelled event-log line, and an incident
-    bundle."""
+    exactly one query_cancelled event-log line, and exactly one
+    incident bundle, which the schema checker accepts."""
+    from asserts import obs_checker
     from spark_rapids_tpu.cluster import TpuProcessCluster
     log_dir = str(tmp_path / "events")
+    flight_dir = str(tmp_path / "flight")
     conf = RapidsConf({
         "spark.rapids.query.deadline": "2.0",
         "spark.rapids.tpu.test.injectFaults": "hang_query:q1r*:*:60",
         "spark.rapids.eventLog.dir": log_dir,
-        "spark.rapids.flight.dir": str(tmp_path / "flight"),
+        "spark.rapids.flight.dir": flight_dir,
     })
     plan = _cluster_plan()
     with TpuProcessCluster(n_workers=2, conf=conf) as c:
@@ -437,6 +438,12 @@ def test_cluster_deadline_cancel_with_incident(tmp_path):
             bundle = json.load(f)
         assert any(a["kind"] == "query_cancelled"
                    for a in bundle["anomalies"])
+        assert os.listdir(flight_dir) == \
+            [os.path.basename(c.last_incident_path)]
+        # under load the deadline can beat the map stage's first
+        # allocation: then the bundle has no memory event, and no more
+        assert set(obs_checker().check_flight(c.last_incident_path)) \
+            <= {"memory timeline empty"}
     evs = [json.loads(line)
            for n in os.listdir(log_dir)
            for line in open(os.path.join(log_dir, n))]

@@ -324,6 +324,11 @@ def test_cli_json_schema_and_exit_codes(tmp_path):
                                  "host-sync-in-jit"}
     for f in doc["findings"]:
         assert f["fingerprint"]
+    # ... and it is the report the operator's validator accepts
+    from asserts import obs_checker
+    report = tmp_path / "lint.json"
+    report.write_text(r.stdout)
+    assert obs_checker().check_lint_report(str(report)) == []
     bad = tmp_path / "cluster.py"
     bad.write_text("import time\n"
                    "def f(th):\n"

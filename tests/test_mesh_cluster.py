@@ -14,6 +14,8 @@ import numpy as np
 import pyarrow as pa
 import pytest
 
+from asserts import obs_checker
+
 from spark_rapids_tpu.cluster import (TpuProcessCluster,
                                       _mesh_ineligible,
                                       _slice_for_member)
@@ -103,16 +105,42 @@ def _assert_gang_ran(cluster, gen=0):
 
 # --- the gang path ---------------------------------------------------------
 
-@pytest.mark.slow  # covered in tier 1 by the SQL-text variant below,
-# which runs the same gang join+agg shape to the same oracle
-def test_mesh_gang_join_agg_matches_oracle(mesh_cluster):
+def test_mesh_gang_join_agg_matches_oracle(mesh_cluster, tmp_path):
     """Join + regroup + agg as ONE SPMD program over a mesh spanning
-    two worker processes; every exchange is a cross-process collective,
-    result identical to the in-process CPU oracle."""
+    two worker processes, result identical to the in-process CPU
+    oracle — and the STRUCTURAL evidence that every exchange was a
+    cross-process collective: both processes joined one distributed
+    mesh, each ran collective epochs, bytes crossed the boundary, and
+    the stitched trace carries spans of both."""
+    import json
+
+    from spark_rapids_tpu.distributed.runtime import read_mesh_markers
+    from spark_rapids_tpu.obs.metrics import read_worker_metrics
     plan = _join_agg_plan()
-    got = mesh_cluster.run_query(plan)
+    got = mesh_cluster.run_query(plan, conf=RapidsConf({
+        **MESH_CONF, "spark.rapids.metrics.enabled": "true",
+        "spark.rapids.trace.dir": str(tmp_path)}))
     _assert_gang_ran(mesh_cluster)
     assert _rows(got) == _rows(_oracle(plan))
+    markers = read_mesh_markers(mesh_cluster.root, 2, 0)
+    assert len(markers) == 2 and all(
+        m["ok"] and m["distributed"] and int(m["num_processes"]) == 2
+        and m["device_kind"] for m in markers), markers
+    epochs, nbytes = {}, {}
+    for tag, families in read_worker_metrics(mesh_cluster.root):
+        for name, acc in (("rapids_mesh_collective_epochs_total", epochs),
+                          ("rapids_mesh_collective_bytes_total", nbytes)):
+            for v in (families.get(name) or {"samples": {}})["samples"] \
+                    .values():
+                w = tag.split(".")[0]
+                acc[w] = max(acc.get(w, 0), int(v))
+    assert len(epochs) == 2 and min(epochs.values()) >= 1, epochs
+    assert sum(nbytes.values()) > 0, nbytes
+    with open(mesh_cluster.last_trace_path) as f:
+        pids = {ev.get("pid") for ev in json.load(f)["traceEvents"]
+                if ev.get("ph") == "X"}
+    assert {1, 2} <= pids, pids
+    assert obs_checker().check_trace(mesh_cluster.last_trace_path) == []
 
 
 def test_mesh_sql_join_explain_analyze(mesh_cluster):

@@ -4,7 +4,6 @@ guarantees — plus the ISSUE acceptance test: a process-cluster query
 with an injected worker crash produces ONE stitched Chrome trace with
 driver query/stage spans, both task attempts (failed + retried) under
 the right parents, and worker-side operator spans."""
-import importlib.util
 import json
 import os
 import threading
@@ -13,6 +12,7 @@ import urllib.request
 import pyarrow as pa
 import pytest
 
+from asserts import obs_checker as _load_checker
 from data_gen import IntegerGen, LongGen, gen_table
 
 from spark_rapids_tpu.config import RapidsConf
@@ -24,17 +24,6 @@ from spark_rapids_tpu.obs.tracer import (NULL_TRACER, Tracer,
 from spark_rapids_tpu.tools.profiling import (critical_path,
                                               format_critical_path,
                                               profile_trace)
-
-
-def _load_checker():
-    """The CI schema checker doubles as the test oracle for emitted
-    observability artifacts."""
-    path = os.path.join(os.path.dirname(__file__), "..", "tools",
-                        "check_obs_output.py")
-    spec = importlib.util.spec_from_file_location("check_obs", path)
-    mod = importlib.util.module_from_spec(spec)
-    spec.loader.exec_module(mod)
-    return mod
 
 
 # --- tracer -----------------------------------------------------------------
@@ -723,6 +712,24 @@ def test_scan_counters_are_their_spans_and_chrome_nests_them(tmp_path):
         assert s["parent_id"] == query["span_id"]
         assert query["ts"] <= s["ts"] and \
             s["ts"] + s["dur"] <= query["ts"] + query["dur"] + 1e-6
+
+
+def test_local_query_files_pass_the_schema_checker(tmp_path):
+    """What ONE in-process parquet query leaves for an operator: a Chrome
+    trace the checker accepts, and the scan's assemble / upload
+    histograms in a Prometheus dump the checker accepts."""
+    trace_dir = str(tmp_path / "traces")
+    pp = _q6_plan(_lineitem_files(tmp_path, files=1),
+                  {"spark.rapids.trace.dir": trace_dir})
+    assert pp.collect().num_rows == 1
+    name, = os.listdir(trace_dir)
+    checker = _load_checker()
+    assert checker.check_trace(os.path.join(trace_dir, name)) == []
+    prom = dump_prometheus()
+    assert checker.check_prometheus(prom) == []
+    for family in ("rapids_scan_assemble_seconds",
+                   "rapids_scan_upload_seconds"):
+        assert family + "_count" in prom, family
 
 
 def test_q6_compiles_only_programs_of_the_registry(tmp_path):

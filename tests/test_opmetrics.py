@@ -18,6 +18,8 @@ import re
 import pyarrow as pa
 import pytest
 
+from asserts import obs_checker
+
 from spark_rapids_tpu.cluster import TpuProcessCluster
 from spark_rapids_tpu.config import RapidsConf
 from spark_rapids_tpu.exec.aggregate import TpuHashAggregateExec
@@ -174,6 +176,26 @@ def test_cluster_join_totals_match_oracle_rows():
     agg_st = _ops_by_name(folded, "HashAggregateExec")[0]
     assert agg_st["tasks"] == 2, agg_st
     assert agg_st["skew"] >= 1.0
+
+
+def test_cluster_runs_persist_valid_profiles_that_compare(tmp_path):
+    """Each cluster run of a plan leaves its own query profile under
+    ``spark.rapids.history.dir``; the schema checker accepts both and
+    ``profiling compare`` renders the pair."""
+    from spark_rapids_tpu.tools.profiling import compare_report
+    root = _join_agg_df(_session())._plan().root
+    conf = RapidsConf({"spark.rapids.history.dir": str(tmp_path)})
+    paths = []
+    with TpuProcessCluster(n_workers=2, conf=conf) as c:
+        for _ in range(2):
+            c.run_query(root)
+            paths.append(c.last_profile_path)
+    first, second = paths
+    assert first != second
+    for path in paths:
+        assert obs_checker().check_profile(path) == []
+    assert json.load(open(first))["cluster"] == "process"
+    assert "per-operator opTime" in compare_report(first, second)
 
 
 def test_cluster_worker_crash_partial_snapshots_harvested():

@@ -285,6 +285,44 @@ def _walk(node):
 
 # --- spill-tier durability under chaos (PR 12) -----------------------------
 
+def _spilling_sort(tmp_path, faults):
+    """A reduce-side global sort under budgets tiny enough that it goes
+    out-of-core: its runs walk device -> host -> sealed disk files and
+    are read back (verified) during the k-way merge. Returns the plan
+    and a conf with every evidence directory under ``tmp_path``."""
+    from spark_rapids_tpu.exec.sort import SortOrder, TpuSortExec
+    conf = RapidsConf({
+        "spark.rapids.tpu.test.injectFaults": faults,
+        "spark.rapids.memory.device.budgetBytes": 1 << 14,
+        "spark.rapids.memory.host.spillStorageSize": 1 << 12,
+        "spark.rapids.memory.spillDir": str(tmp_path / "spill"),
+        "spark.rapids.eventLog.dir": str(tmp_path / "events"),
+        "spark.rapids.flight.dir": str(tmp_path / "incidents"),
+        "spark.rapids.warehouse.dir": str(tmp_path / "warehouse"),
+        "spark.rapids.metrics.enabled": "true",  # workers flush deltas
+    })
+    rng = np.random.default_rng(7)
+    rbs = [pa.record_batch({
+        "k": pa.array(rng.integers(0, 1 << 30, 1200).astype(np.int64)),
+        "v": pa.array(rng.integers(0, 1000, 1200).astype(np.int64)),
+    }) for _ in range(4)]
+    plan = TpuSortExec(
+        [SortOrder(col("k"))],
+        TpuShuffleExchangeExec(HashPartitioning([col("v")], 1),
+                               HostBatchSourceExec(rbs)))
+    return plan, conf
+
+
+def _leaked_spill_files(tmp_path):
+    spill_dir = str(tmp_path / "spill")
+    leftovers = []
+    if os.path.isdir(spill_dir):
+        for ns in os.listdir(spill_dir):
+            leftovers += [f for f in os.listdir(os.path.join(
+                spill_dir, ns)) if f.endswith(".arrow")]
+    return leftovers
+
+
 @pytest.mark.parametrize("mode,kind", [("spill_corrupt", "corrupt"),
                                        ("spill_torn", "torn")])
 def test_chaos_spill_damage_classified_retry_no_blacklist(
@@ -296,32 +334,11 @@ def test_chaos_spill_damage_classified_retry_no_blacklist(
     (bit rot is not a process fault; re-execution regenerates the
     data). The retry (no injection at attempt 1) goes green, the query
     matches the oracle, the incident bundle carries the
-    spill_read_failed anomaly, and no live incarnation spill dir
+    spill_read_failed anomaly, the query's ONE warehouse row says
+    completed with its spill bytes, and no live incarnation spill dir
     leaks files."""
-    from spark_rapids_tpu.exec.sort import SortOrder, TpuSortExec
-    log_dir = str(tmp_path / "events")
-    flight_dir = str(tmp_path / "incidents")
-    spill_dir = str(tmp_path / "spill")
-    conf = RapidsConf({
-        "spark.rapids.tpu.test.injectFaults": f"{mode}:q1r*:0",
-        # budgets tiny enough that the reduce task's global sort goes
-        # out-of-core: its runs walk device -> host -> sealed disk
-        # files and are read back (verified) during the k-way merge
-        "spark.rapids.memory.device.budgetBytes": 1 << 14,
-        "spark.rapids.memory.host.spillStorageSize": 1 << 12,
-        "spark.rapids.memory.spillDir": spill_dir,
-        "spark.rapids.eventLog.dir": log_dir,
-        "spark.rapids.flight.dir": flight_dir,
-    })
-    rng = np.random.default_rng(7)
-    rbs = [pa.record_batch({
-        "k": pa.array(rng.integers(0, 1 << 30, 1200).astype(np.int64)),
-        "v": pa.array(rng.integers(0, 1000, 1200).astype(np.int64)),
-    }) for _ in range(4)]
-    plan = TpuSortExec(
-        [SortOrder(col("k"))],
-        TpuShuffleExchangeExec(HashPartitioning([col("v")], 1),
-                               HostBatchSourceExec(rbs)))
+    from spark_rapids_tpu.obs.warehouse import read_rows
+    plan, conf = _spilling_sort(tmp_path, f"{mode}:q1r*:0")
     with TpuProcessCluster(n_workers=2, conf=conf) as c:
         got = c.run_query(plan)
         sched = c.last_scheduler
@@ -342,10 +359,45 @@ def test_chaos_spill_damage_classified_retry_no_blacklist(
     assert bundle and os.path.exists(bundle)
     kinds = {a["kind"] for a in json.load(open(bundle))["anomalies"]}
     assert "spill_read_failed" in kinds, kinds
-    # no orphan spill files survive in any live incarnation namespace
-    leftovers = []
-    if os.path.isdir(spill_dir):
-        for ns in os.listdir(spill_dir):
-            leftovers += [f for f in os.listdir(os.path.join(
-                spill_dir, ns)) if f.endswith(".arrow")]
-    assert leftovers == [], f"leaked spill files: {leftovers}"
+    (row,) = read_rows(str(tmp_path / "warehouse"))
+    assert row["outcome"] == "completed"
+    assert sum(int(v or 0) for v in row["spill"].values()) > 0, row["spill"]
+    assert _leaked_spill_files(tmp_path) == []
+
+
+def test_chaos_disk_full_completes_green_with_classified_pressure(
+        tmp_path):
+    """Every disk-spill write of the reduce task hits injected ENOSPC
+    (chaos ``disk_full``): refused writes leave batches host-resident,
+    so the query completes and NO task fails; the event log carries
+    ``disk_pressure`` (kind enospc), exactly one incident bundle names
+    the anomaly, the cluster's boot-time sweep reclaims a planted dead
+    incarnation's spill namespace, and no live namespace leaks."""
+    import subprocess
+
+    from asserts import obs_checker
+    from spark_rapids_tpu.memory import _hostname
+    from spark_rapids_tpu.tools.event_log import read_event_logs
+    plan, conf = _spilling_sort(tmp_path, "disk_full:q1r*:*:99")
+    p = subprocess.Popen(["true"])
+    p.wait()  # reaped: the pid is provably dead
+    orphan = tmp_path / "spill" / f"{_hostname()}-{p.pid}-{'0' * 8}"
+    orphan.mkdir(parents=True)
+    (orphan / "spill-stale.arrow").touch()
+    with TpuProcessCluster(n_workers=2, conf=conf) as c:
+        assert not orphan.exists()
+        got = c.run_query(plan)
+        sched = c.last_scheduler
+        bundle = c.last_incident_path
+    assert got.column("k").to_pylist() == sorted(
+        _oracle(plan).column("k").to_pylist())
+    assert not _events(sched, "task_failed")
+    pressure = [e for e in read_event_logs(str(tmp_path / "events"))
+                if e.get("type") == "disk_pressure"]
+    assert pressure and pressure[0]["kind"] == "enospc", pressure
+    assert bundle and os.listdir(tmp_path / "incidents") == \
+        [os.path.basename(bundle)]
+    assert obs_checker().check_flight(bundle) == []
+    kinds = {a["kind"] for a in json.load(open(bundle))["anomalies"]}
+    assert "disk_pressure" in kinds, kinds
+    assert _leaked_spill_files(tmp_path) == []

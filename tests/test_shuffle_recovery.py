@@ -11,6 +11,7 @@ import os
 import pyarrow as pa
 import pytest
 
+from asserts import obs_checker
 from data_gen import IntegerGen, LongGen, gen_table
 
 from spark_rapids_tpu.cluster import TpuProcessCluster
@@ -98,6 +99,7 @@ def test_corrupt_committed_output_recovers_via_stage_rerun(tmp_path):
         bundle = json.load(f)
     kinds = {a["kind"] for a in bundle["anomalies"]}
     assert {"fetch_failed", "stage_rerun"} <= kinds
+    assert obs_checker().check_flight(incident) == []
     # ... and the persisted event log carries the recovery timeline
     evs = [json.loads(line) for p in os.listdir(log_dir)
            for line in open(os.path.join(log_dir, p))]

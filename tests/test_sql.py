@@ -275,6 +275,8 @@ def test_sql_errors_logged_to_event_log(tmp_path):
             events += [json.loads(ln) for ln in f if ln.strip()]
     kinds = sorted(e["type"] for e in events)
     assert kinds == ["sql_analysis_error", "sql_parse_error"]
+    par = next(e for e in events if e["type"] == "sql_parse_error")
+    assert par["line"] == 1
     ana = next(e for e in events if e["type"] == "sql_analysis_error")
     assert ana["detail"] == "unknown_column"
     assert ana["line"] == 1 and ana["col"] > 0

@@ -58,6 +58,21 @@ def test_sql_corpus_plans_fully_on_device(name):
         f"{name}: {pp.explain('NOT_ON_GPU')}"
 
 
+def test_sql_q3_on_the_process_cluster_equals_the_local_run():
+    """A SQL-built plan crosses to OS worker processes whole: q3 (two
+    joins, group-by, order-by, limit) on a 2-worker cluster returns the
+    rows of the in-process run, which the dual runs hold to the
+    oracle. One shuffle partition keeps the sort + limit global."""
+    from spark_rapids_tpu.cluster import TpuProcessCluster
+    s = TpuSession(conf={"spark.sql.shuffle.partitions": "1"})
+    df = build_query_sql("q3", s, TABLES)
+    with TpuProcessCluster(n_workers=2) as c:
+        got = c.run_query(df._node).to_pandas()
+    _assert_frames_equal(got.reset_index(drop=True),
+                         df.collect().to_pandas().reset_index(drop=True),
+                         "q3")
+
+
 def test_sql_corpus_explains():
     # EXPLAIN over a corpus text returns plan text without executing
     s = TpuSession()

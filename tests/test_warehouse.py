@@ -495,8 +495,8 @@ def test_cluster_failed_query_row_partial_attribution(wh_cluster):
 
 def test_cluster_status_doc_shape(wh_cluster):
     """The cluster's /status provider: worker census, mesh health, and
-    the warehouse tail (in_flight is exercised end-to-end by CI step
-    17's hang_query probe)."""
+    the warehouse tail (a query in flight:
+    ``test_cluster_cancelled_query_row_and_live_status``)."""
     c, d = wh_cluster
     doc = c._status_doc()
     json.loads(json.dumps(doc))  # serializable as served
@@ -507,3 +507,61 @@ def test_cluster_status_doc_shape(wh_cluster):
     assert tail and set(tail[0]) == {"query_id", "tenant", "outcome",
                                      "wall_s", "device_kind",
                                      "fingerprint"}
+
+
+def test_cluster_cancelled_query_row_and_live_status(wh_cluster):
+    """A query stalled by chaos ``hang_query`` shows in the status
+    document WHILE it runs (query id, phase, the warehouse tail of the
+    queries before it); the user's cancel then leaves exactly ONE row,
+    classified cancelled / user."""
+    import threading
+    import time
+    c, d = wh_cluster
+    plan, _ = _join_plan()
+    c.run_query(plan)  # a finished query: the tail is not empty
+    before = len(read_rows(d))
+    seen = {}
+
+    def watch_then_cancel():
+        deadline = time.time() + 45
+        while time.time() < deadline and not seen:
+            doc = c._status_doc()
+            if doc["in_flight"]:
+                seen.update(json.loads(json.dumps(doc)))
+            time.sleep(0.05)
+        while not c.cancel_running() and time.time() < deadline:
+            time.sleep(0.05)
+
+    watcher = threading.Thread(target=watch_then_cancel, daemon=True)
+    watcher.start()
+    with pytest.raises(QueryCancelled) as ei:
+        c.run_query(plan, conf=RapidsConf({
+            **dict(c.conf.items()),
+            "spark.rapids.tpu.test.injectFaults": "hang_query:q*r*:*:60"}))
+    watcher.join(timeout=60)
+    assert ei.value.reason == "user"
+    (live,) = seen["in_flight"]
+    assert live["query_id"].startswith("q") and "phase" in live
+    assert seen["warehouse_tail"]
+    rows = read_rows(d)
+    assert len(rows) == before + 1
+    assert rows[-1]["query_id"] == live["query_id"]
+    assert rows[-1]["outcome"] == "cancelled"
+    assert rows[-1]["cancel"]["reason"] == "user"
+
+
+def test_cluster_repeat_run_lands_under_one_fingerprint_drift_clean(
+        wh_cluster):
+    """The same plan run twice on the cluster: two completed rows under
+    ONE fingerprint and device kind, and the drift sentinel, which
+    compares runs of a fingerprint, stays silent (rc 0)."""
+    c, d = wh_cluster
+    plan, _ = _join_plan()
+    c.run_query(plan)
+    c.run_query(plan)
+    first, second = read_rows(d)[-2:]
+    assert first["outcome"] == second["outcome"] == "completed"
+    assert first["fingerprint"] == second["fingerprint"]
+    assert first["device_kind"] == second["device_kind"]
+    rep, rc = drift_report(d)
+    assert rc == 0, rep
