@@ -17,10 +17,13 @@ encoded representation instead:
       bytes stay opaque) into a run table,
   device side (one XLA program per shape bucket):
     - expand runs: value v_i = two uint32 gathers + funnel shift + mask
-      (bit-packed), or the run's literal (RLE),
+      (bit-packed), or the run's literal (RLE); what a run holds is
+      expanded over its positions by a scatter + prefix sum, never
+      gathered per row,
     - dictionary gather for dict-encoded pages, bitcast for PLAIN,
-    - definition-level expansion for nullable columns (same run
-      machinery at width 1) + dense→row scatter via a cumsum gather.
+    - for a chunk that holds a null: definition-level expansion (same
+      run machinery at width 1) + dense→row gather via a prefix sum; a
+      chunk without nulls runs neither.
 
 PLAIN-only non-null chunks skip the kernel entirely (the bytes ARE the
 column). The envelope covers v1 AND v2 data pages of flat columns in
@@ -288,6 +291,9 @@ _PHYS_LANE = {"INT32": np.dtype(np.int32), "INT64": np.dtype(np.int64),
               "BOOLEAN": np.dtype(np.bool_)}
 _SUPPORTED_CODECS = {"UNCOMPRESSED", "SNAPPY", "ZSTD", "GZIP", "BROTLI"}
 _MAX_DICT_WIDTH = 24  # funnel-shift window bound: shift(<=31) + width <= 55
+# the decoder's bit positions are int32 lanes: a chunk's packed stream
+# stays under 2^31 bits (256 MiB), guard words included
+_MAX_PACKED_WORDS = 1 << 26
 
 
 class ChunkPlan:
@@ -302,17 +308,22 @@ class ChunkPlan:
     either way. `is_delta` marks DELTA_BINARY_PACKED numeric chunks
     whose values the device reconstructs by prefix sum; `str_bound` is
     the chunk's worst-case decoded character count (the string output
-    buffer currency — merge sums it)."""
+    buffer currency — merge sums it). `has_nulls` says whether the
+    device runs the definition-level pass: the chunk holds a null
+    (`n_valid < n_rows`, whatever the schema's nullability says), or
+    the scan met one in an earlier row group of the column and keeps
+    the column on one program (`io/scan.py`); `chunks` counts the column
+    chunks a merged plan was made from."""
 
     __slots__ = ("n_rows", "lane", "dictionary", "packed", "runs",
                  "def_packed", "def_runs", "n_valid", "has_nulls",
                  "encoded_bytes", "str_dict", "str_char_cap",
-                 "str_max_len", "is_delta", "str_bound")
+                 "str_max_len", "is_delta", "str_bound", "chunks")
 
     def __init__(self, n_rows, lane, dictionary, packed, runs, def_packed,
                  def_runs, n_valid, encoded_bytes, str_dict=None,
                  str_char_cap=0, str_max_len=0, is_delta=False,
-                 str_bound=0):
+                 str_bound=0, chunks=1):
         self.n_rows = n_rows
         self.lane = lane
         self.dictionary = dictionary
@@ -328,6 +339,7 @@ class ChunkPlan:
         self.str_max_len = str_max_len  # longest store string
         self.is_delta = is_delta
         self.str_bound = str_bound
+        self.chunks = chunks
 
 
 def _decompress(codec: str, payload: bytes, uncompressed: int) -> bytes:
@@ -758,6 +770,10 @@ def plan_chunk(f, col_md, descriptor, engine_dtype: dt.DataType,
 
     packed = b"".join(packed_parts)
     def_packed = b"".join(def_packed_parts)
+    words = _as_words(packed)
+    if not packed_words_fit(words.shape[0]):
+        raise HostFallback(f"packed stream of {len(packed)}B: bit "
+                           "positions past int32", "size-guard")
     run_tab = np.zeros((max(len(runs), 1), 4), np.int64)
     for i, r in enumerate(runs):
         run_tab[i] = r
@@ -816,7 +832,7 @@ def plan_chunk(f, col_md, descriptor, engine_dtype: dt.DataType,
     return ChunkPlan(n_rows, lane,
                      dictionary if dictionary is not None
                      else np.zeros(1, lane),
-                     _as_words(packed), run_tab,
+                     words, run_tab,
                      _as_words(def_packed), def_tab, values_seen, encoded,
                      str_dict=str_dict, str_char_cap=str_char_cap,
                      str_max_len=str_max_len, is_delta=has_delta,
@@ -840,6 +856,22 @@ def _as_words(b: bytes) -> np.ndarray:
 
 def encoded_nbytes(plan: ChunkPlan) -> int:
     return plan.encoded_bytes
+
+
+def null_free_chunks(plans) -> int:
+    """Column chunks among these (possibly merged) ChunkPlans that
+    decode without a definition-level pass: the ``nullFreeChunks``
+    counter and the ``null_free`` argument of ``scan.dispatch``."""
+    return sum(plan.chunks for plan in plans if not plan.has_nulls)
+
+
+def packed_words_fit(n_words: int) -> bool:
+    """May a packed stream of this many words ride the device decode?
+    Its arena slice (guard words, bucketed) must stay under the int32
+    bit positions of ``_expand``; plan_chunk sends a larger chunk to
+    the host (`size-guard`) and the coalescer (io/scan.py) does not
+    merge row groups past it."""
+    return _seg_bucket(n_words + 2) < _MAX_PACKED_WORDS
 
 
 def merge_chunk_plans(plans: Sequence[ChunkPlan]) -> ChunkPlan:
@@ -869,7 +901,7 @@ def merge_chunk_plans(plans: Sequence[ChunkPlan]) -> ChunkPlan:
     chars_parts: List[bytes] = []
     w_words = dw_words = 0
     dense_base = row_base = dict_base = char_base = 0
-    n_rows = n_valid = encoded = 0
+    n_rows = n_valid = encoded = chunks = 0
     str_max_len = 0
     str_bound = 0
     for p in plans:
@@ -917,6 +949,7 @@ def merge_chunk_plans(plans: Sequence[ChunkPlan]) -> ChunkPlan:
         n_rows += p.n_rows
         n_valid += p.n_valid
         encoded += p.encoded_bytes
+        chunks += p.chunks
         str_max_len = max(str_max_len, p.str_max_len)
         str_bound += p.str_bound
     str_dict = None
@@ -940,14 +973,18 @@ def merge_chunk_plans(plans: Sequence[ChunkPlan]) -> ChunkPlan:
         dictionary = np.zeros(1, lane)
     else:
         dictionary = np.concatenate(dict_parts)
-    return ChunkPlan(n_rows, lane, dictionary,
-                     np.concatenate(words_parts),
-                     np.concatenate(run_tabs),
-                     np.concatenate(def_parts),
-                     np.concatenate(def_tabs),
-                     n_valid, encoded, str_dict=str_dict,
-                     str_char_cap=str_char_cap, str_max_len=str_max_len,
-                     is_delta=is_delta, str_bound=str_bound)
+    merged = ChunkPlan(n_rows, lane, dictionary,
+                       np.concatenate(words_parts),
+                       np.concatenate(run_tabs),
+                       np.concatenate(def_parts),
+                       np.concatenate(def_tabs),
+                       n_valid, encoded, str_dict=str_dict,
+                       str_char_cap=str_char_cap, str_max_len=str_max_len,
+                       is_delta=is_delta, str_bound=str_bound,
+                       chunks=chunks)
+    # a part the scan holds to the definition-level pass holds the whole
+    merged.has_nulls = any(p.has_nulls for p in plans)
+    return merged
 
 
 # --- device kernel ---------------------------------------------------------
@@ -961,9 +998,16 @@ def _run_ids(starts, cap: int, t_n: int):
     return jnp.clip(dense_run_counts(starts, cap) - 1, 0, t_n - 1)
 
 
-def _expand(words, tab, cap: int, delta: bool = False):
+def _expand(words, tab, cap: int, delta: bool = False, wide: bool = True):
     """Expand the run table at the dense positions 0..cap-1: uint64 raw
-    bits + (is_rle, is_dict, width) lanes for the caller's interpretation.
+    bits + the is_dict lane for the caller's interpretation. Only the
+    packed words are gathered per row: what a run holds (width, flags,
+    where its bits start, its literal) is constant over the run's
+    positions, so it is folded into four int32 per-run constants and
+    EXPANDED by the scatter + prefix that finds the run
+    (``ops.gather.dense_run_expand``), never gathered by a run id.
+    ``wide`` (static) says a run may be 64 bits wide — PLAIN values of
+    an 8-byte lane, the only ones that need a third word.
     With ``delta`` (static), the expanded lanes are per-value DELTA
     contributions (bit-packed delta + the run's min_delta; a page's
     first value rides an RLE run) and the return value is the
@@ -971,17 +1015,42 @@ def _expand(words, tab, cap: int, delta: bool = False):
     is its own delta stream."""
     import jax.numpy as jnp
     from jax import lax
-    idx = jnp.arange(cap, dtype=jnp.int64)
-    starts = tab[:, 0]
-    rid = _run_ids(starts, cap, tab.shape[0])
-    meta = tab[rid, 1]
-    width = (meta & 0xFF).astype(jnp.uint64)
-    is_rle = (meta >> 8) & 1
+    from ..ops.gather import dense_run_expand
+    if words.shape[0] >= _MAX_PACKED_WORDS:
+        # a bit position is an int32 lane (plan_chunk and the coalescer
+        # send larger chunks to the host before they get here)
+        raise ValueError(f"packed stream of {words.shape[0]} words")
+    idx = jnp.arange(cap, dtype=jnp.int32)
+    starts, meta, raw = tab[:, 0], tab[:, 1], tab[:, 2]
+    run_rle = (meta >> 8) & 1
+    run_ident = (meta >> 10) & 1
+    run_delta = (meta >> 11) & 1
+    # bit position of dense position i: bit0 + i * width
+    bit0 = tab[:, 3] - starts * (meta & 0xFF)
+    # what a position adds to its unpacked bits: the run's literal
+    # (RLE), its min_delta (DELTA miniblocks), or for identity runs
+    # (PLAIN / DELTA_LENGTH strings: value_i = literal + i - start) the
+    # literal less the run's start; and the merged row group's index
+    # base (meta bits 16+: 0 for PLAIN runs and unmerged plans), so a
+    # dictionary index points into its own group's slice of the
+    # concatenated dictionary/store. int64 wraparound ==
+    # two's-complement addition, as the uint64 lanes below
+    addend = jnp.where((run_rle | run_ident | run_delta) == 1, raw, 0) \
+        - jnp.where(run_ident == 1, starts, 0) + (meta >> 16)
+    fields = [meta & 0xFFF, bit0, addend, addend >> 32]
+    if delta:
+        # the dense position of each run's page start (its last RLE run)
+        fields.append(lax.cummax(
+            jnp.where(run_rle == 1, starts, jnp.int64(-1))))
+    lanes = dense_run_expand(
+        starts, jnp.stack([_low32(f) for f in fields]), cap)
+    meta, bit0 = lanes[0], lanes[1]
+    addend = (_u64(lanes[3]) << jnp.uint64(32)) | _u64(lanes[2])
+    literal = ((meta >> 8) | (meta >> 10)) & 1   # RLE or identity run
     is_dict = (meta >> 9) & 1
     is_ident = (meta >> 10) & 1
-    is_delta = (meta >> 11) & 1
-    bitpos = (tab[rid, 3] + (idx - starts[rid]) * (meta & 0xFF)) \
-        .astype(jnp.int64)
+    bitpos = bit0 + idx * (meta & 0xFF)
+    width = (meta & 0xFF).astype(jnp.uint64)
     widx = jnp.clip(bitpos >> 5, 0, words.shape[0] - 2)
     lo = words[widx].astype(jnp.uint64)
     hi = words[widx + 1].astype(jnp.uint64)
@@ -990,53 +1059,60 @@ def _expand(words, tab, cap: int, delta: bool = False):
     mask = jnp.where(width >= 64, jnp.uint64(0xFFFFFFFFFFFFFFFF),
                      (jnp.uint64(1) << width) - jnp.uint64(1))
     bits = (window >> sh) & mask
-    # w == 64 PLAIN regions are 8-byte aligned (sh is 0 mod 32): the
-    # 64-bit window IS the value, but sh==32 can occur when the region
-    # starts on an odd word — handle by re-gathering the next word pair
-    hi2 = words[jnp.clip(widx + 2, 0, words.shape[0] - 1)] \
-        .astype(jnp.uint64)
-    full64 = jnp.where(sh == 0, window, (hi2 << jnp.uint64(32)) | hi)
-    bits = jnp.where(width >= 64, full64, bits)
-    raw = tab[rid, 2].astype(jnp.uint64)
-    bits = jnp.where(is_rle == 1, raw, bits)
-    # identity runs (PLAIN / DELTA_LENGTH strings): the value IS the
-    # dense position's index into the chunk's string store
-    bits = jnp.where(is_ident == 1,
-                     raw + (idx - starts[rid]).astype(jnp.uint64), bits)
-    # delta miniblock runs: packed value + the run's min_delta
-    # (uint64 wraparound == two's-complement int64 addition)
-    bits = jnp.where(is_delta == 1, bits + raw, bits)
-    # merged row groups: dictionary-index and string runs carry their
-    # group's index base in meta bits 16+ (0 for PLAIN runs and
-    # unmerged plans), so the index points into its own group's slice
-    # of the concatenated dictionary/store
-    bits = bits + (meta >> 16).astype(jnp.uint64)
+    if wide:
+        # w == 64 PLAIN regions are 8-byte aligned (sh is 0 mod 32): the
+        # 64-bit window IS the value, but sh==32 can occur when the
+        # region starts on an odd word — handle by re-gathering the next
+        # word pair
+        hi2 = words[jnp.clip(widx + 2, 0, words.shape[0] - 1)] \
+            .astype(jnp.uint64)
+        full64 = jnp.where(sh == 0, window, (hi2 << jnp.uint64(32)) | hi)
+        bits = jnp.where(width >= 64, full64, bits)
+    bits = jnp.where(literal == 1, jnp.uint64(0), bits) \
+        + jnp.where(is_ident == 1, idx, 0).astype(jnp.uint64) + addend
     if delta:
         # value_i = page_first + Σ deltas: inclusive prefix sum minus
         # the sum just before the page's first-value (RLE) run
-        page_start = lax.cummax(
-            jnp.where(((tab[:, 1] >> 8) & 1) == 1, starts,
-                      jnp.int64(-1)))[rid]
+        page_start = lanes[4]
         csum = jnp.cumsum(bits)
         before = csum[jnp.clip(page_start - 1, 0, idx.shape[0] - 1)]
         bits = csum - jnp.where(page_start > 0, before, jnp.uint64(0))
     return bits, is_dict
 
 
-def _decode_device(words, tab, dict_arr, def_words, def_tab, n_rows,
-                   cap: int, delta: bool = False):
-    """The whole chunk decode as one jittable program: returns
-    (values[cap] in the DICTIONARY/lane dtype, validity[cap])."""
+def _low32(x):
+    """The low 32 bits of an int64 lane as int32."""
     import jax.numpy as jnp
     from jax import lax
+    return lax.bitcast_convert_type(
+        (x & 0xFFFFFFFF).astype(jnp.uint32), jnp.int32)
+
+
+def _u64(x):
+    """An int32 lane's 32 bits as the low half of a uint64."""
+    import jax.numpy as jnp
+    from jax import lax
+    return lax.bitcast_convert_type(x, jnp.uint32).astype(jnp.uint64)
+
+
+def _decode_device(words, tab, dict_arr, def_words, def_tab, n_rows,
+                   cap: int, delta: bool = False, has_nulls: bool = True):
+    """The whole chunk decode as one jittable program: returns
+    (values[cap] in the DICTIONARY/lane dtype, validity[cap]). A chunk
+    without nulls (``has_nulls`` false, static: ``ChunkPlan.has_nulls``)
+    runs no definition-level pass: every row below ``n_rows`` is valid
+    and the value stream is already dense over the rows."""
+    import jax.numpy as jnp
+    from jax import lax
+    from ..ops.gather import blocked_int_cumsum
     i = jnp.arange(cap, dtype=jnp.int64)
-    def_bits, _ = _expand(def_words, def_tab, cap)
-    valid = (def_bits & jnp.uint64(1)) != 0
-    valid = valid & (i < n_rows)
-    # dense index of each valid row into the value stream
-    didx = jnp.cumsum(valid.astype(jnp.int32)) - 1
-    bits, is_dict = _expand(words, tab, cap, delta=delta)
+    valid = i < n_rows
+    if has_nulls:
+        def_bits, _ = _expand(def_words, def_tab, cap, wide=False)
+        valid = valid & ((def_bits & jnp.uint64(1)) != 0)
     lane = dict_arr.dtype
+    bits, is_dict = _expand(words, tab, cap, delta=delta,
+                            wide=lane.itemsize == 8)
     if lane == jnp.bool_:
         vals = (bits & jnp.uint64(1)) != 0
     elif lane.itemsize == 8:
@@ -1046,9 +1122,12 @@ def _decode_device(words, tab, dict_arr, def_words, def_tab, n_rows,
     dgot = dict_arr[jnp.clip(bits.astype(jnp.int32), 0,
                              dict_arr.shape[0] - 1)]
     vals = jnp.where(is_dict == 1, dgot, vals)
-    # nullable: values are dense over valid rows — gather back to rows
-    out = vals[jnp.clip(didx, 0, cap - 1)]
-    out = jnp.where(valid, out, jnp.zeros((), lane))
+    if has_nulls:
+        # values are dense over valid rows — gather back to rows by the
+        # dense index of each valid row into the value stream
+        didx = blocked_int_cumsum(valid) - 1
+        vals = vals[jnp.clip(didx, 0, cap - 1)]
+    out = jnp.where(valid, vals, jnp.zeros((), lane))
     return out, valid
 
 
@@ -1208,7 +1287,8 @@ def decode_row_group_device(plans: Dict[str, Tuple[ChunkPlan, dt.DataType]],
                          if eng_dtype.np_dtype is not None else "str",
                          w_off, w_len, t_off, t.shape[0],
                          dw_off, dw_len, dt_off, dtab.shape[0],
-                         dict_off, d.shape[0], str_info, plan.is_delta))
+                         dict_off, d.shape[0], str_info, plan.is_delta,
+                         bool(plan.has_nulls)))
         total = _seg_bucket(off + 4)  # trailing slice-overrun guard
     _await_staging_arena(clock)
     with clock.stage("assemble", rows=max(nrs, default=0),
@@ -1235,7 +1315,7 @@ def decode_row_group_device(plans: Dict[str, Tuple[ChunkPlan, dt.DataType]],
                 outs = []
                 for j, (lane_s, eng_s, w_off, w_len, t_off, t_n, dw_off,
                         dw_len, dt_off, dt_n, d_off, d_n,
-                        str_info, is_delta) in enumerate(spec):
+                        str_info, is_delta, has_nulls) in enumerate(spec):
                     lane = np.dtype(lane_s)
                     words = b[w_off: w_off + w_len]
                     tab = lax.bitcast_convert_type(
@@ -1256,7 +1336,7 @@ def decode_row_group_device(plans: Dict[str, Tuple[ChunkPlan, dt.DataType]],
                             b[d_off: d_off + d_n], jnp.dtype(lane))
                     vals, valid = _decode_device(
                         words, tab, dict_arr, def_words, def_tab,
-                        nr[j], cap, delta=is_delta)
+                        nr[j], cap, delta=is_delta, has_nulls=has_nulls)
                     if str_info is not None:
                         so_off, so_n, sc_off, char_cap = str_info
                         d_offs = lax.bitcast_convert_type(
@@ -1331,7 +1411,9 @@ def decode_row_group_device(plans: Dict[str, Tuple[ChunkPlan, dt.DataType]],
             blob = jax.device_put(view)
             nr_dev = jnp.asarray(np.asarray(nrs, np.int64))
         with clock.stage("dispatch", program=module_name(program),
-                         fused=chain is not None):
+                         fused=chain is not None,
+                         null_free=null_free_chunks(
+                             plan for plan, _ in plans.values())):
             if chain is not None:
                 extras = tuple((extra_cols or {}).values())
                 outs = fn(blob, nr_dev, np.int32(row_count), extras, ectx)

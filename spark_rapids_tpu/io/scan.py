@@ -891,16 +891,22 @@ class TpuFileScanExec(LeafExec):
         return True
 
     @staticmethod
-    def _string_bound_ok(group, item) -> bool:
-        """The merged plan's worst-case string expansion must stay under
-        the device cap plan_chunk enforces per chunk, AND the merged
-        store's character count must fit int32 offsets. Each group's
-        rows only index its own store slice, so the merged bound is the
-        SUM of per-plan bounds."""
+    def _merge_fits(group, item) -> bool:
+        """What plan_chunk enforces per chunk must hold for the merged
+        plan: its packed stream stays under the decoder's int32 bit
+        positions (each part may add an alignment word), its worst-case
+        string expansion under the device cap, AND the merged store's
+        character count fits int32 offsets. Each group's rows only index
+        its own store slice, so the merged bound is the SUM of per-plan
+        bounds."""
         import numpy as np
-        from .parquet_device import STR_EXPANSION_CAP
+        from .parquet_device import STR_EXPANSION_CAP, packed_words_fit
         i32max = np.iinfo(np.int32).max
         for k, p in item[1].items():
+            if not packed_words_fit(
+                    sum(g[1][k].packed.shape[0] + 1 for g in group)
+                    + p.packed.shape[0]):
+                return False
             if p.str_dict is None:
                 continue
             bound = sum(g[1][k].str_bound for g in group) + p.str_bound
@@ -922,7 +928,7 @@ class TpuFileScanExec(LeafExec):
         for item in planned:
             if group and (rows + item[0] > max_rows
                           or not self._coalesce_compatible(group[0], item)
-                          or not self._string_bound_ok(group, item)):
+                          or not self._merge_fits(group, item)):
                 yield group
                 group, rows, est = [], 0, 0
             group.append(item)
@@ -1015,6 +1021,8 @@ class TpuFileScanExec(LeafExec):
         dec_m = ctx.metric(self, "decodedBytes")
         dev_chunks_m = ctx.metric(self, "deviceChunks")
         fb_chunks_m = ctx.metric(self, "fallbackChunks")
+        # chunks decoded without a definition-level pass (no null in them)
+        null_free_m = ctx.metric(self, "nullFreeChunks")
         # dispatch-granularity observability: scanPrograms counts every
         # program this scan dispatches (decode or chain), and
         # fusedDispatches the ones where decode+chain ran as ONE
@@ -1038,6 +1046,7 @@ class TpuFileScanExec(LeafExec):
         target_bytes = conf.get(SCAN_COALESCE_TARGET_BYTES)
         max_rows = max(1, conf.batch_size_rows)
         from ..memory import DeviceMemoryManager
+        from .parquet_device import null_free_chunks
         mgr = DeviceMemoryManager.shared(conf)
         pool = concurrent.futures.ThreadPoolExecutor(
             nthreads, thread_name_prefix="scan-plan")
@@ -1058,6 +1067,8 @@ class TpuFileScanExec(LeafExec):
                     columns=cols, file_columns=file_cols)
             return item
 
+        seen_nulls: set = set()  # columns that have shown a null
+
         def planned():
             pending: List = []
             it = iter(tasks)
@@ -1077,6 +1088,17 @@ class TpuFileScanExec(LeafExec):
                     item = pending.pop(0).result()
                 scan_t.value += wait.dur
                 topup()
+                # A column keeps the definition-level pass from its
+                # first chunk with a null on (ChunkPlan.has_nulls): over
+                # a scan the flags of k columns only rise, and row
+                # groups arrive here in task order on ONE thread, so a
+                # program shape compiles in at most k + 1 variants of
+                # the flags, the same ones on every scan of these files
+                # — not one for each of the 2^k mixtures.
+                for name, plan in item[1].items():
+                    if plan.has_nulls:
+                        seen_nulls.add(name)
+                    plan.has_nulls = name in seen_nulls
                 yield item
 
         inflight: set = set()  # ledger entries not yet handed over
@@ -1091,6 +1113,7 @@ class TpuFileScanExec(LeafExec):
             with clock.stage("assemble"):
                 n_rows, plans, host_rb, part_vals, fb_reasons = \
                     self._merge_planned(group)
+            null_free = null_free_chunks(plans.values())
             batch, encoded, decoded, prog = self._assemble_device_batch(
                 n_rows, plans, host_rb, part_vals, clock=clock,
                 mm=mgr, chain=chain, chain_key=chain_key,
@@ -1109,7 +1132,7 @@ class TpuFileScanExec(LeafExec):
                 if sb is not None:
                     inflight.add(sb)
             return (batch, sb, n_rows, encoded, decoded, clock.seconds,
-                    dev_chunks, fb_reasons, prog)
+                    dev_chunks, null_free, fb_reasons, prog)
 
         groups = self._coalesced_groups(planned(), target_bytes, max_rows)
         # the in-flight window is bounded in decoded BYTES too: string
@@ -1136,7 +1159,7 @@ class TpuFileScanExec(LeafExec):
                 if item is done:
                     break
                 (batch, sb, n_rows, encoded, decoded, seconds,
-                 dev_chunks, fb_reasons, prog) = item
+                 dev_chunks, null_free, fb_reasons, prog) = item
                 assemble_s = seconds.get("assemble", 0.0)
                 upload_s = seconds.get("upload", 0.0) \
                     + seconds.get("dispatch", 0.0)
@@ -1148,6 +1171,7 @@ class TpuFileScanExec(LeafExec):
                 enc_m.value += encoded
                 dec_m.value += decoded
                 dev_chunks_m.value += dev_chunks
+                null_free_m.value += null_free
                 fb_chunks_m.value += len(fb_reasons)
                 if prog != "none":
                     programs_m.value += 1

@@ -455,8 +455,8 @@ def _fmt_metric(name: str, v) -> str:
 
 _COMPACT_METRICS = ("rows", "batches", "opTime", "spillTime",
                     "uploadWaitTime", "ledgerWaitTime", "deviceChunks",
-                    "fallbackChunks", "fusedDispatches", "scanPrograms",
-                    "columnsRead", "columnsPruned")
+                    "fallbackChunks", "nullFreeChunks", "fusedDispatches",
+                    "scanPrograms", "columnsRead", "columnsPruned")
 
 
 def render_analyzed(root, folded: Dict[str, Dict],

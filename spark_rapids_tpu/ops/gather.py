@@ -16,7 +16,8 @@ from ..columnar.column import TpuColumnVector
 from ..programs import named_jit
 from .strings import gather_strings
 
-__all__ = ["compaction_indices", "dense_run_counts", "exclusive_cumsum",
+__all__ = ["compaction_indices", "dense_run_counts", "dense_run_expand",
+           "blocked_int_cumsum", "exclusive_cumsum",
            "varlen_gather_plan",
            "invert_permutation", "gather_column", "gather_batch",
            "gather_columns", "compact_batch", "ensure_compacted",
@@ -38,6 +39,30 @@ def inclusive_int_cumsum(x: jax.Array) -> jax.Array:
 _PREFIX_BLOCK = 1024
 
 
+def _blocked_int_cumsum(x: jax.Array) -> jax.Array:
+    """Inclusive int32 prefix sum along the last axis, whose length is a
+    multiple of 1024: within rows of 1024 lanes, plus the prefix of the
+    row totals. On the v5e (PR 27, 2^20 lanes) it compiles in 0.4 s
+    where the 1-D ``jnp.cumsum`` takes 30 s and runs as fast alone (0.9
+    against 0.8 ms). Sums wrap modulo 2^32 like every int32 add."""
+    rows = x.shape[-1] // _PREFIX_BLOCK
+    inner = jnp.cumsum(x.reshape(x.shape[:-1] + (rows, _PREFIX_BLOCK)),
+                       axis=-1)
+    totals = inner[..., -1]
+    before = jnp.cumsum(totals, axis=-1) - totals
+    return (inner + before[..., None]).reshape(x.shape)
+
+
+def blocked_int_cumsum(x: jax.Array) -> jax.Array:
+    """``jnp.cumsum`` of a 1-D array as int32, in the 1024-blocked form
+    (``_blocked_int_cumsum``): the prefix for any new site over row or
+    character lanes."""
+    n = x.shape[0]
+    pad = -n % _PREFIX_BLOCK
+    return _blocked_int_cumsum(
+        jnp.pad(x.astype(jnp.int32), (0, pad)))[:n]
+
+
 def dense_run_counts(starts: jax.Array, n: int) -> jax.Array:
     """out[i] = how many of the non-negative `starts` are <= i, for the
     dense positions i = 0..n-1: what ``jnp.searchsorted(starts,
@@ -51,18 +76,40 @@ def dense_run_counts(starts: jax.Array, n: int) -> jax.Array:
     runs share a start and each counts. A start >= n — a padding run at
     int32.max among them — counts for no position below n.
 
-    The prefix is blocked: within rows of 1024 lanes, plus the prefix of
-    the row totals. On the v5e (PR 27, 2^20 lanes) it compiles in 0.4 s
-    where the 1-D ``jnp.cumsum`` takes 30 s, runs as fast alone (0.9
-    against 0.8 ms), and q6's decode program built on it runs 6 % faster
-    (5.78 against 6.13 s a query: XLA places more of the run tables in
-    fast memory beside it)."""
+    The prefix is blocked (``_blocked_int_cumsum``); q6's decode program
+    built on it runs 6 % faster than on the 1-D form (5.78 against
+    6.13 s a query, PR 27: XLA places more of the run tables in fast
+    memory beside it)."""
     rows = -(-n // _PREFIX_BLOCK)
     flags = jnp.zeros((rows * _PREFIX_BLOCK,), jnp.int32) \
         .at[starts.astype(jnp.int32)].add(1, mode="drop")
-    inner = jnp.cumsum(flags.reshape(rows, _PREFIX_BLOCK), axis=1)
-    before = exclusive_cumsum(inner[:, -1])
-    return (inner + before[:, None]).reshape(-1)[:n]
+    return _blocked_int_cumsum(flags)[:n]
+
+
+def dense_run_expand(starts: jax.Array, fields: jax.Array,
+                     n: int) -> jax.Array:
+    """out[k, i] = fields[k, r] for the run r that covers the dense
+    position i (the last run of the SORTED `starts` with starts[r] <= i;
+    0 below the first start): ``fields[:, searchsorted(starts,
+    arange(n), "right") - 1]`` without a gather over n lanes. A per-run
+    field is piecewise constant over the positions, so scatter-ADD its
+    step ``fields[:, r] - fields[:, r - 1]`` at ``starts[r]`` and take
+    the prefix that ``dense_run_counts`` takes to FIND the run: one
+    scatter of len(starts) columns and one blocked int32 prefix for all
+    the fields stacked, where each ``field[rid]`` was a gather of 9-24 ms
+    over 2^20 lanes on the v5e whatever it read (PR 30). `add`:
+    zero-length runs share a start and their steps telescope. `drop`:
+    runs that start at or past n — padding runs at int32.max — fall
+    away, and being the tail of a sorted table they take no step of a
+    run below n with them. Every lane is int32 and exact modulo 2^32 (a
+    telescoped sum needs no carry), so a 64-bit field rides as its two
+    32-bit halves."""
+    fields = fields.astype(jnp.int32)
+    steps = fields - jnp.pad(fields[:, :-1], ((0, 0), (1, 0)))
+    rows = -(-n // _PREFIX_BLOCK)
+    flat = jnp.zeros((fields.shape[0], rows * _PREFIX_BLOCK), jnp.int32) \
+        .at[:, starts.astype(jnp.int32)].add(steps, mode="drop")
+    return _blocked_int_cumsum(flat)[:, :n]
 
 
 def exclusive_cumsum(x: jax.Array) -> jax.Array:

@@ -121,23 +121,46 @@ def test_q6_filter_project_partial_agg_chain_compiles(chip):
         batch, ExecCtx().eval_ctx).compile()
 
 
-def test_parquet_dictionary_rle_chunk_decode_compiles(chip):
+@pytest.mark.parametrize("has_nulls, gathers", [
+    pytest.param(False, 3, id="null-free"),
+    pytest.param(True, 6, id="nullable")])
+def test_parquet_dictionary_rle_chunk_decode_compiles(chip, has_nulls,
+                                                      gathers):
     """One dictionary/RLE float32 column chunk of a 2^20-row group through
     the device decoder (run table -> funnel-shift unpack -> dictionary
-    gather -> null scatter), built from shapes alone. Its run lookup is
-    a prefix count of run-start flags: a `while` in the compiled program
-    would be the per-row binary search back (2^20 lanes x log2(runs)
-    rounds; 7.1 s of q6's 13.0 device-busy seconds, ledger, PR 26)."""
+    gather -> null scatter), built from shapes alone, holding the CAUSES
+    of its device and compiler seconds in the chip compiler's HLO:
+    - no `while`: the run lookup is a prefix count of run-start flags,
+      not a per-row binary search (2^20 lanes x log2(runs) rounds; 7.1 s
+      of q6's 13.0 device-busy seconds, ledger, PR 26);
+    - per row only the packed words and the dictionary are gathered
+      (9-24 ms a gather over 2^20 lanes on the v5e whatever it reads):
+      two word gathers (a third only for an 8-byte lane, whose PLAIN
+      values are 64 bits wide) and the dictionary's for a chunk without
+      nulls, which runs no definition-level pass; a nullable chunk adds
+      the two word gathers of its levels and the dense-to-row gather.
+      The run table's fields ride ``dense_run_expand``'s ONE scatter an
+      expansion. This sandbox's compiler read 20 gathers a column
+      before PR 32 (3 and 6 now);
+    - no 1-D prefix over the 2^20 lanes (`didx` was one: the cold
+      cell's 50 s `reduce-window`, which the compiler tiles to
+      [8192,128]; alone it compiles in 30 s where the 1024-blocked
+      prefix takes 0.4 s, builder, PR 27): the program handed to the
+      compiler holds no `reduce_window` whose window is `cap` lanes."""
     from spark_rapids_tpu.io.parquet_device import _decode_device
     cap = 1 << 20
-    compiled = jax.jit(_decode_device, static_argnums=(6,)).lower(
+    lowered = jax.jit(_decode_device, static_argnums=(6, 7, 8)).lower(
         chip((cap // 2,), jnp.uint32),      # bit-packed index words
         chip((2048, 4), jnp.int64),         # run table (q6's value tables)
         chip((4096,), jnp.float32),         # dictionary page
         chip((cap // 32 + 2,), jnp.uint32),  # definition-level words
         chip((8, 4), jnp.int64),            # definition-level runs
-        chip((), jnp.int64), cap).compile()
-    assert " while(" not in compiled.as_text()
+        chip((), jnp.int64), cap, False, has_nulls)
+    assert f"window_dimensions = array<i64: {cap}>" not in lowered.as_text()
+    hlo = lowered.compile().as_text()
+    assert " while(" not in hlo
+    assert hlo.count(" gather(") <= gathers, hlo.count(" gather(")
+    assert hlo.count(" scatter(") <= (2 if has_nulls else 1)
 
 
 # --- the scan chains of the pruned plans (column pruning, exec/pruning.py) ------

@@ -208,6 +208,149 @@ def _to_arrow(batch):
     return device_to_arrow(batch)
 
 
+def _run_expand_cases():
+    """The run lookup's tables with fields worth expanding: a 64-bit
+    literal whose high word is set, riding as its two halves; fields
+    that fall from run to run; a field at both ends of int32."""
+    out = []
+    for param in _run_lookup_cases():
+        tab, cap = param.values
+        tab = tab.copy()
+        r = np.arange(tab.shape[0], dtype=np.int64)
+        tab[:, 2] = (0x7F3A_0000_0000_0000 >> (r % 5)) - r * 0x1_2345_6789
+        tab[:, 3] = np.where(r % 2 == 0, np.iinfo(np.int32).max - r,
+                             np.iinfo(np.int32).min + r)
+        out.append(pytest.param(tab, cap, id=param.id))
+    return out
+
+
+@pytest.mark.parametrize("tab, cap", _run_expand_cases())
+def test_dense_run_expand_is_the_gather_by_run(tab, cap):
+    """Expanding per-run fields by scatter + prefix gives, lane for lane,
+    what gathering them by the searched run id gave — zero-length runs,
+    padding runs at int32.max, starts at and past the capacity, int32
+    wrap-around and a 64-bit field as two 32-bit halves included."""
+    import jax.numpy as jnp
+    from spark_rapids_tpu.ops.gather import dense_run_expand
+    starts, raw = tab[:, 0], tab[:, 2]
+    fields = np.stack([tab[:, 1], raw & 0xFFFFFFFF, raw >> 32, tab[:, 3]])
+    fields = fields.astype(np.uint32).view(np.int32)  # low 32 bits each
+    rid = np.searchsorted(starts, np.arange(cap), "right") - 1
+    assert rid.min() >= 0  # every table starts at position 0
+    got = np.asarray(dense_run_expand(
+        jnp.asarray(starts), jnp.asarray(fields), cap))
+    assert got.dtype == np.int32 and got.shape == (4, cap)
+    np.testing.assert_array_equal(got, fields[:, rid])
+    raw_back = (got[2].astype(np.int64) << 32) | got[1].view(np.uint32)
+    np.testing.assert_array_equal(raw_back, raw[rid])
+
+
+def _bits(table):
+    """A table's values as comparable bits: floats by their integer
+    view (nulls zeroed), so -0.0, NaN payloads and the last ulp count."""
+    out = {}
+    for name, col in zip(table.schema.names, table.columns):
+        arr = col.combine_chunks()
+        if pa.types.is_floating(arr.type):
+            width = np.int64 if arr.type == pa.float64() else np.int32
+            out[name] = (arr.is_null().to_pylist(), arr.fill_null(0)
+                         .to_numpy(zero_copy_only=False).view(width).tolist())
+        else:
+            out[name] = arr.to_pylist()
+    return out
+
+
+@pytest.mark.parametrize("target", ["0", "1g"],
+                         ids=["per-group", "coalesced"])
+@pytest.mark.parametrize("written", ["required", "optional-no-nulls",
+                                     "optional-with-nulls"])
+def test_parquet_device_decode_null_free_chunks(tmp_path, written, target):
+    """The SAME values written `nullable=False`, optional without a null
+    and optional with nulls decode to pyarrow's bits; the first two run
+    no definition-level pass on any chunk (`nullFreeChunks`: the chunk's
+    own null count decides, not the schema), the third on every chunk.
+    Dictionary, PLAIN 64-bit, boolean, DELTA and string chunks, one
+    dispatch a row group and coalesced."""
+    rng = np.random.default_rng(41)
+    n = 20_000
+    values = {
+        "dict_i32": rng.integers(0, 9, n).astype(np.int32),
+        "dict_f64": rng.integers(0, 11, n) / 100,
+        "plain_f64": rng.uniform(-1, 1, n),
+        "plain_i64": rng.integers(-(1 << 40), 1 << 40, n),
+        "b": rng.integers(0, 2, n).astype(bool),
+        "delta_i64": rng.integers(-1000, 1000, n).cumsum(),
+        "s": np.array([f"brand #{i % 17}" for i in range(n)], object),
+    }
+    # at least one null in every row group of every column
+    mask = {name: (np.arange(n) % 5000 == 7 * k) | (rng.uniform(0, 1, n) < .2)
+            for k, name in enumerate(values)}
+    nulls = written == "optional-with-nulls"
+    table = pa.table(
+        [pa.array(v, mask=mask[name] if nulls else None)
+         for name, v in values.items()],
+        schema=pa.schema([pa.field(name, pa.array(v[:1]).type,
+                                   nullable=written != "required")
+                          for name, v in values.items()]))
+    p = os.path.join(str(tmp_path), f"{written}.parquet")
+    pq.write_table(table, p, row_group_size=5000, compression="snappy",
+                   data_page_size=8 << 10,
+                   use_dictionary=["dict_i32", "dict_f64", "s"],
+                   column_encoding={"delta_i64": "DELTA_BINARY_PACKED"})
+    conf = RapidsConf({"spark.rapids.sql.scan.coalesceTargetBytes": target})
+    scan = TpuFileScanExec([p], conf=conf)
+    ctx = ExecCtx(conf)
+    batches = [_to_arrow(b) for b in scan.execute(ctx)]
+    assert len(batches) == (4 if target == "0" else 1)
+    got = pa.Table.from_batches(batches)
+    assert _bits(got) == _bits(pq.read_table(p))
+    m = ctx.metrics[scan.node_label()]
+    chunks = 4 * len(values)
+    assert m["deviceChunks"].value == chunks and not m["fallbackChunks"].value
+    assert m["nullFreeChunks"].value == (0 if nulls else chunks)
+
+
+def test_parquet_device_decode_null_free_variants_bounded(tmp_path):
+    """Row groups that differ in which of three columns hold a null must
+    not compile a decode program per mixture (2^3): a column keeps the
+    definition-level pass from its first chunk with a null on
+    (io/scan.py), so the flags only rise over a scan — at most k + 1
+    variants for k columns, the same ones on a re-scan."""
+    from spark_rapids_tpu.io import parquet_device as pd_
+    rng = np.random.default_rng(43)
+    groups, rows, names = 8, 4000, ("a", "b", "c")
+    n = groups * rows
+    # row group g holds ONE null in column k iff bit k of this order's
+    # g-th entry is set: all eight mixtures, the null-free one first
+    order = [0, 1, 2, 4, 3, 5, 6, 7]
+    arrays = {}
+    for k, name in enumerate(names):
+        mask = np.zeros(n, bool)
+        for g, bits in enumerate(order):
+            mask[g * rows + 11 + k] = (bits >> k) & 1
+        arrays[name] = pa.array(rng.integers(0, 7, n).astype(np.int32),
+                                mask=mask)
+    p = os.path.join(str(tmp_path), "mix.parquet")
+    pq.write_table(pa.table(arrays), p, row_group_size=rows)
+    conf = RapidsConf({"spark.rapids.sql.scan.coalesceTargetBytes": "0"})
+    pd_._JIT_CACHE.clear()
+    scan = TpuFileScanExec([p], conf=conf)
+    ctx = ExecCtx(conf)
+    got = pa.Table.from_batches([_to_arrow(b) for b in scan.execute(ctx)])
+    assert got.equals(pq.read_table(p))
+    flags = {tuple(col[-1] for col in k[3])
+             for k in pd_._JIT_CACHE if k[0] == "rg"}
+    assert (False,) * 3 in flags and (True,) * 3 in flags
+    assert len(flags) <= len(names) + 1, flags
+    assert len(pd_._JIT_CACHE) <= len(names) + 1, list(pd_._JIT_CACHE)
+    # the chunks before their column's first null ran without the pass:
+    # one of a, two of b, three of c
+    assert ctx.metrics[scan.node_label()]["nullFreeChunks"].value == 6
+    before = len(pd_._JIT_CACHE)
+    list(TpuFileScanExec([p], conf=conf).execute(ExecCtx(conf)))
+    assert len(pd_._JIT_CACHE) == before
+
+
 def test_parquet_device_decode_dict_strings(tmp_path):
     """Dictionary-encoded STRING chunks decode on device: indices cross
     the link at bit-packed width, the device gathers the strings from

@@ -634,6 +634,12 @@ def test_profiler_session_carries_every_span_of_a_parquet_query(
     assert len(programs) == 9 and all(
         p["program"] == "jit_scan_decode_chain" and p["fused"]
         for p in programs)
+    # no chunk of these files holds a null: none ran a definition-level
+    # pass, and the span says what the ExecCtx counter says
+    assert [p["null_free"] for p in programs] == [4] * 9
+    assert sum(int(m["nullFreeChunks"].value)
+               for m in pp.last_ctx.metrics.values()
+               if "nullFreeChunks" in m) == 36
     ops = {s[3]["op"] for s in spans if s[0] == "spark:op"}
     assert ops and all("#" not in o and ":op" in o for o in ops)
     uploads = [s[3]["bytes"] for s in spans if s[0] == "spark:scan.upload"]
