@@ -34,6 +34,7 @@ import json  # noqa: E402
 import os  # noqa: E402
 import re  # noqa: E402
 import shutil  # noqa: E402
+import statistics  # noqa: E402
 import sys  # noqa: E402
 
 HERE = os.path.dirname(os.path.abspath(__file__))
@@ -46,6 +47,7 @@ for _p in (ROOT, HERE):
         sys.path.insert(0, _p)
 
 EXIT_NO_CHIP, EXIT_BAD_PLAN, EXIT_USAGE = 3, 4, 2
+TAIL_MIN_QUERIES = 20  # fewer walls, and a percentile is a maximum by another name
 
 
 class Refused(Exception):
@@ -256,6 +258,27 @@ def run(args) -> dict:
                        peaks.get(kind), meter)
 
 
+def nearest_rank(values, percent: int) -> float:
+    """The nearest-rank percentile: the ceil(percent / 100 x n)-th smallest
+    (the 42nd of 46 for 90: the fifth largest)."""
+    ordered = sorted(values)
+    return ordered[max(-(-percent * len(ordered) // 100), 1) - 1]
+
+
+def warm_metrics(walls, window_s: float) -> dict:
+    """The warm window's end-to-end numbers (PERF.md section 2).
+    ``query_s`` is taken over all the work and all the time of the window,
+    so one stalled query moves it by its share; ``query_p50_s``, the median
+    of the queries' wall seconds, does not see that query at all.
+    ``query_p90_s`` is their nearest-rank tail, there only where the window
+    completed ``TAIL_MIN_QUERIES``."""
+    measured = {"query_s": window_s / len(walls),
+                "query_p50_s": statistics.median(walls)}
+    if len(walls) >= TAIL_MIN_QUERIES:
+        measured["query_p90_s"] = nearest_rank(walls, 90)
+    return measured
+
+
 def measure(args, bench, config, config_file, traffic, devices, peak,
             meter) -> dict:
     import jax
@@ -351,14 +374,19 @@ def measure(args, bench, config, config_file, traffic, devices, peak,
     if not args.trace:
         measured = {"setup_s": setup_s}
         if walls and not cold:
-            measured["query_s"] = window_s / len(walls)
-            measured["query_max_s"] = max(walls)
+            measured.update(warm_metrics(walls, window_s))
         if walls and cold:  # the first query of its shape in this process
             measured["cold_query_s"] = walls[0]
+        listed = [m for m in bench["end_to_end"]
+                  if applies(m, args.workload)]
         line["metrics"] = {
             m["name"]: {"value": measured[m["name"]], "unit": m["unit"]}
-            for m in bench["end_to_end"] if applies(m, args.workload)
-            and m["name"] in measured}
+            for m in listed if m["name"] in measured}
+        for m in listed:
+            if m["name"] not in measured:
+                print(f"{m['name']} is not printed: the window completed "
+                      f"{len(walls)} queries (a tail is printed from "
+                      f"{TAIL_MIN_QUERIES} on)", file=sys.stderr)
     else:
         from trace_reduce import reduce_trace
         trace = reduce_trace(trace_dir)

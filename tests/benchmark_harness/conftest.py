@@ -3,7 +3,7 @@
 put ``benchmark/`` on the path, keep every file a run writes (tables,
 traces, the compile cache) in a directory of this pytest process, put
 jax's compile-cache settings back after each test, and read the cells
-from a copy of BENCHMARK.json that holds one more (``later_cell.py``)."""
+from a copy of BENCHMARK.json."""
 import json
 import os
 import sys
@@ -16,10 +16,9 @@ if BENCH_DIR not in sys.path:
     sys.path.insert(0, BENCH_DIR)
 
 import run  # noqa: E402
-from later_cell import with_later_cell  # noqa: E402
 
 with open(run.BENCH_FILE) as f:
-    BENCH = with_later_cell(json.load(f))
+    BENCH = json.load(f)
 
 _JAX_KEYS = ("jax_enable_compilation_cache", "jax_compilation_cache_dir",
              "jax_persistent_cache_min_compile_time_secs",

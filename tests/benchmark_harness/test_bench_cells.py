@@ -1,5 +1,4 @@
-"""CPU rehearsal of every cell of BENCHMARK.json, and of the one that
-``later_cell.py`` adds to a copy of it, at a few thousand rows:
+"""CPU rehearsal of every cell of BENCHMARK.json at a few thousand rows:
 the whole control flow of a run (set-up, window, reference, result
 line) through the harness's own functions, with and without the trace.
 No number it prints is a device number."""
@@ -14,11 +13,9 @@ import sys
 import pytest
 
 import run
-from later_cell import with_later_cell
 
 with open(os.path.join(run.ROOT, "BENCHMARK.json")) as f:
-    COMMITTED = json.load(f)
-BENCH = with_later_cell(COMMITTED)
+    BENCH = json.load(f)
 CELLS = [w["name"] for w in BENCH["workloads"]]
 WARM = [c for c in CELLS if not c.endswith(".cold")]
 CONTRACT_KEYS = ["correct", "attempted", "failed", "metrics", "device"]
@@ -38,6 +35,8 @@ def test_cell_runs_end_to_end_and_is_correct(bench_run, cell):
     assert line["correct"] is True and line["failed"] == 0
     assert line["attempted"] >= 1
     want = {m["name"] for m in BENCH["end_to_end"] if run.applies(m, cell)}
+    if line["attempted"] < run.TAIL_MIN_QUERIES:  # a window too short for a tail
+        want -= {"query_p90_s"}
     assert set(line["metrics"]) == want and "setup_s" in want
     assert all(v["value"] > 0 for v in line["metrics"].values())
     # the contract's keys and, last, each number compared beside its limit
@@ -124,7 +123,7 @@ def test_a_cell_is_added_by_new_files_and_entries_alone(tmp_path):
     is edited, and the copy's own command runs the new cell."""
     root = str(tmp_path)
     shutil.copytree(run.HERE, os.path.join(root, "benchmark"))
-    bench = json.loads(json.dumps(COMMITTED))
+    bench = json.loads(json.dumps(BENCH))
     config = run.load_json(run.HERE, "configs", "tpch-sf1.json")
     config.update(name="dummy-config", source="a test's own deployment")
     config["tables"]["lineitem"]["files"] = 2

@@ -15,10 +15,8 @@ import pytest
 
 import run
 
-from later_cell import with_later_cell
-
 with open(os.path.join(run.ROOT, "BENCHMARK.json")) as f:
-    CELLS = [w["name"] for w in with_later_cell(json.load(f))["workloads"]]
+    CELLS = [w["name"] for w in json.load(f)["workloads"]]
 ROWS = 60000
 
 
