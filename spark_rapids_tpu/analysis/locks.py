@@ -81,6 +81,11 @@ LOCK_HIERARCHY: Tuple[LockLevel, ...] = (
               ("memory.py", None, "DeviceMemoryManager"),
               "process-level manager cache; held across __init__ "
               "(which publishes gauges and flight events)"),
+    LockLevel("*ici.py::_LOCAL_LOCK", 15,
+              ("ici.py", None, "<module>"),
+              "the process's one ICI transport per device set "
+              "(local_transport); held across the transport's __init__, "
+              "which takes no lock"),
     LockLevel("HostShuffleTransport._lock", 20,
               ("host.py", "HostShuffleTransport", "__init__"),
               "shuffle bookkeeping (futures/manifests/stats)"),

@@ -213,8 +213,14 @@ SHUFFLE_MODE = register(
     "Shuffle transport: LOCAL (device-resident spillable store — the "
     "single-process default), HOST (Arrow IPC files, synchronous), "
     "MULTITHREADED (Arrow IPC files with parallel codec threads), ICI "
-    "(SPMD all-to-all collectives over the device mesh; requires an "
-    "explicit IciShuffleTransport since it needs the mesh).")
+    "(SPMD all-to-all collectives over ONE mesh of this process's local "
+    "devices, the first min(devices, spark.sql.shuffle.partitions) of "
+    "them, built with the session; an aggregation over scans, filters, "
+    "projections and hash joins then runs as one task per chip — "
+    "sliced fact scan, every other table whole on every chip, partial "
+    "aggregates through the all-to-all — and EXPLAIN's `ici:` line "
+    "says so, or why the plan runs as one task; with one device the "
+    "mesh is one wide and every plan runs as one task).")
 SHUFFLE_COMPRESSION = register(
     "spark.rapids.shuffle.compression.codec", "lz4",
     "Codec for host shuffle partitions: none, lz4, zstd (the codecs "

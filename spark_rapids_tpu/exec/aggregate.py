@@ -58,7 +58,14 @@ def _unalias(e: Expression) -> Tuple[AggregateFunction, str]:
 
 
 class TpuHashAggregateExec(UnaryExec):
-    """Sort-based group-by with partial/merge phases."""
+    """Sort-based group-by with partial/merge phases.
+
+    One node runs both (``mode`` "complete", what every frontend builds);
+    a gang of one task per chip (exec/gang.py) splits it as Spark's
+    planner does, around an exchange of the partial buffers:
+    ``as_partial`` (update, and merge what a task's batches gave into ONE
+    batch of the partial-buffer schema) below, ``as_final`` (merge,
+    evaluate) above."""
 
     FUSION_NOTE = ("barrier: grouped reduction ACROSS batches; the "
                    "per-batch PARTIAL phase fuses as a chain tail "
@@ -93,6 +100,7 @@ class TpuHashAggregateExec(UnaryExec):
                                           f.nullable) for f in bf)
             off += len(bf)
         self._partial_schema = dt.Schema(bfields)
+        self.mode = "complete"
         self._jit_partial = None
         self._jit_final = None
         self._jit_merge = None
@@ -100,7 +108,28 @@ class TpuHashAggregateExec(UnaryExec):
 
     @property
     def output_schema(self):
-        return self._schema
+        return self._partial_schema if self.mode == "partial" \
+            else self._schema
+
+    def _in_mode(self, mode: str, child: TpuExec):
+        import copy
+        clone = copy.copy(self)
+        clone.children = (child,)
+        clone.mode = mode
+        clone.__dict__.pop("_fused_jit_cache", None)
+        return clone
+
+    def as_partial(self, child: TpuExec) -> "TpuHashAggregateExec":
+        """The update half over ``child`` (this node's child's schema):
+        yields at most one batch, in the partial-buffer schema."""
+        clone = self._in_mode("partial", child)
+        clone.__dict__.pop("_op_id", None)  # a node of no planned tree
+        return clone
+
+    def as_final(self, child: TpuExec) -> "TpuHashAggregateExec":
+        """The merge-and-evaluate half over a child that yields batches
+        in the partial-buffer schema (an exchange of ``as_partial``s)."""
+        return self._in_mode("final", child)
 
     def resident_footprint(self):
         # collect_* / exact-percentile aggregates concatenate the whole
@@ -111,7 +140,8 @@ class TpuHashAggregateExec(UnaryExec):
         g = ", ".join(map(repr, self.group_exprs))
         a = ", ".join(f"{type(x).__name__.lower()}({', '.join(map(repr, x.children))})"
                       for x in self.aggs)
-        return f"HashAggregateExec [keys=[{g}] aggs=[{a}]]"
+        mode = "" if self.mode == "complete" else f" mode={self.mode}"
+        return f"HashAggregateExec [keys=[{g}] aggs=[{a}]{mode}]"
 
     def tpu_supported_conf(self, conf):
         """Conf-dependent eligibility (planner hook): float aggregation
@@ -505,10 +535,13 @@ class TpuHashAggregateExec(UnaryExec):
             self._jit_final = named_jit("agg_final", self._final,
                                         static_argnums=1)
         op_time = ctx.metric(self, "opTime")
-        # the partial phase fuses with the project/filter chain feeding it
-        # into one XLA program per batch (fused_batches)
-        partials = list(fused_batches(self, ctx, tail_fn=self._partial,
-                                      metric=op_time))
+        if self.mode == "final":  # the child hands over partial buffers
+            partials = list(self.child.execute(ctx))
+        else:
+            # the partial phase fuses with the project/filter chain
+            # feeding it into one XLA program per batch (fused_batches)
+            partials = list(fused_batches(self, ctx, tail_fn=self._partial,
+                                          metric=op_time))
         t0 = time.perf_counter()
         if not partials:
             if self.group_exprs:
@@ -516,6 +549,17 @@ class TpuHashAggregateExec(UnaryExec):
                 return
             partials = [self._jit_partial(self._empty_child_batch(),
                                           ctx.eval_ctx)]
+        if self.mode == "partial":
+            if len(partials) > 1:  # one block a task for the exchange
+                if self._jit_merge is None:
+                    self._jit_merge = named_jit(
+                        "agg_merge", self._merge_only, static_argnums=1)
+                from ..ops.concat import concat_batches_bounded
+                partials = [self._jit_merge(
+                    concat_batches_bounded(partials), ctx.eval_ctx)]
+            op_time.value += time.perf_counter() - t0
+            yield partials[0]
+            return
         if not self.group_exprs:
             from ..ops.concat import concat_batches_bounded
             merged = concat_batches_bounded(partials)

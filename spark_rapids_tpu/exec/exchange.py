@@ -118,9 +118,10 @@ class TpuShuffleExchangeExec(UnaryExec):
                 weakref.finalize(self, HostShuffleTransport.close, t)
                 self.transport = t
             elif mode == "ICI":
-                raise ValueError(
-                    "ICI shuffle needs an explicit IciShuffleTransport "
-                    "(it binds to a device mesh)")
+                # the process's ONE transport over its local devices
+                # (the session brought it up when it was made)
+                from ..shuffle.ici import local_transport
+                self.transport = local_transport(ctx.conf)
             else:
                 raise ValueError(f"unknown shuffle mode {mode!r}")
         return self.transport

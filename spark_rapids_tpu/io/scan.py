@@ -527,9 +527,10 @@ class TpuFileScanExec(LeafExec):
             read = (f" ReadSchema=[{', '.join(self._schema.names)}] "
                     f"({len(self._schema.fields)} of "
                     f"{len(self._file_schema.fields)} columns)")
+        k, n = getattr(self, "_slice", (0, 1))
         return (f"FileScanExec [{self.fmt} x{len(self.paths)}"
                 + (f" pushdown={self._conjuncts}" if self._conjuncts else "")
-                + read + "]")
+                + read + (f" slice={k}/{n}" if n > 1 else "") + "]")
 
     # --- column pruning (exec/pruning.py) ---------------------------------
     PRUNING_NOTE = ("plans, reads, stages and decodes only the required "
@@ -566,6 +567,17 @@ class TpuFileScanExec(LeafExec):
         part_names = {f.name for f in parts}
         clone.columns = [f.name for f in clone._schema.fields
                          if f.name not in part_names]
+        return clone
+
+    def sliced(self, k: int, n: int) -> "TpuFileScanExec":
+        """This scan cut to member ``k`` of ``n``'s share of its row
+        groups (``_device_rg_tasks``): the ``n`` shares are disjoint and
+        together are every row group of every file."""
+        import copy
+        clone = copy.copy(self)
+        clone.__dict__.pop("_chain_jit_cache", None)
+        clone.__dict__.pop("_pf_local", None)
+        clone._slice = (k, n)
         return clone
 
     def registered_as(self, names: Sequence[str]) -> "TpuFileScanExec":
@@ -676,21 +688,26 @@ class TpuFileScanExec(LeafExec):
                 and conf.get(PARQUET_READER_TYPE) != "COALESCING")
 
     def _device_rg_tasks(self) -> List[Tuple[str, int]]:
-        """(path, row_group) work list honoring row-group pruning."""
-        tasks: List[Tuple[str, int]] = []
+        """(path, row_group) work list honoring row-group pruning; of a
+        ``sliced`` scan, its share: a contiguous run of the files' row
+        groups in file order, cut BEFORE the pruning (what a member
+        reads never depends on what another's statistics pruned)."""
+        tasks: List[Tuple[str, int, object]] = []
         for split in self._splits():
             md = pq.ParquetFile(split.path).metadata
             groups = split.row_groups
             if groups is None:
                 groups = list(range(md.num_row_groups))
-            if self._conjuncts:
-                name_to_idx = {md.schema.column(i).name: i
-                               for i in range(md.num_columns)}
-                groups = [g for g in groups
-                          if _rg_may_match(md, g, name_to_idx,
-                                           self._conjuncts)]
-            tasks.extend((split.path, g) for g in groups)
-        return tasks
+            tasks.extend((split.path, g, md) for g in groups)
+        k, n = getattr(self, "_slice", (0, 1))
+        tasks = tasks[k * len(tasks) // n:(k + 1) * len(tasks) // n]
+        if self._conjuncts:
+            tasks = [(path, g, md) for path, g, md in tasks
+                     if _rg_may_match(
+                         md, g, {md.schema.column(i).name: i
+                                 for i in range(md.num_columns)},
+                         self._conjuncts)]
+        return [(path, g) for path, g, _ in tasks]
 
     def _thread_pf(self, path: str) -> "pq.ParquetFile":
         """Per-(thread, path) ParquetFile: one footer parse per pool

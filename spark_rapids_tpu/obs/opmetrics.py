@@ -456,7 +456,8 @@ def _fmt_metric(name: str, v) -> str:
 _COMPACT_METRICS = ("rows", "batches", "opTime", "spillTime",
                     "uploadWaitTime", "ledgerWaitTime", "deviceChunks",
                     "fallbackChunks", "nullFreeChunks", "fusedDispatches",
-                    "scanPrograms", "columnsRead", "columnsPruned")
+                    "scanPrograms", "columnsRead", "columnsPruned",
+                    "gangMembers", "iciEpochs", "iciBytes")
 
 
 def render_analyzed(root, folded: Dict[str, Dict],

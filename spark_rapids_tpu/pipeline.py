@@ -122,6 +122,9 @@ def pipelined_map(fn: Callable[[T], R], items: Iterable[T],
       window) and the consumer raises the classified QueryCancelled at
       its next ``next()``, early-draining in-flight work through the
       normal close path.
+    - The workers run ``fn`` under the consumer's ``jax.default_device``
+      where it has set one (the thread that first advances this
+      generator).
     - ``thread_name`` prefixes the names of the worker threads and of
       the source's feeder (``<prefix>_<n>``, ``<prefix>-src``): a trace
       then says whose lines they are.
@@ -132,6 +135,18 @@ def pipelined_map(fn: Callable[[T], R], items: Iterable[T],
                 token.check()
             yield fn(x)
         return
+
+    # a consumer pinned to a chip (a gang member: exec/gang.py) has its
+    # workers upload and dispatch there too; jax's default device is the
+    # calling thread's alone
+    import jax
+    device = jax.config.jax_default_device
+    if device is not None:
+        work = fn
+
+        def fn(x):
+            with jax.default_device(device):
+                return work(x)
 
     out: "queue.Queue" = queue.Queue()
     slots = _WeightedWindow(window,

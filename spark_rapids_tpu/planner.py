@@ -146,6 +146,7 @@ class PhysicalPlan:
         self.last_ctx: Optional[ExecCtx] = None  # metrics of last collect
         self.last_qctx = None  # lifecycle context of last collect
         self.last_profile_path: Optional[str] = None
+        self._gang = None  # gang_split(), decided once
 
     @property
     def output_schema(self):
@@ -165,11 +166,35 @@ class PhysicalPlan:
         rec(self.meta)
         return out
 
+    def gang_split(self):
+        """``(split, verdict)`` of ``exec/gang.py::split`` for this plan,
+        decided once: whether it runs as one member task per chip under
+        ``spark.rapids.shuffle.mode=ICI``, or as one task, and why."""
+        if self._gang is None:
+            from .config import SHUFFLE_MODE
+            if self.conf.get(SHUFFLE_MODE) != "ICI":
+                self._gang = (None, "")  # nothing to say of a plan that
+                # never asked for the mesh
+            elif not self.root_on_device or self.fallback_nodes():
+                self._gang = (None, "one task: the plan leaves the device")
+            else:
+                from .exec.gang import split
+                self._gang = split(self.root, self.conf)
+        return self._gang
+
+    def gang_verdict(self) -> str:
+        """How the plan runs across the session's chips, in a line (empty
+        unless the conf asks for the ICI mesh)."""
+        return self.gang_split()[1]
+
     def explain(self, mode: Optional[str] = None) -> str:
         mode = mode or self.conf.get(EXPLAIN)
         if mode == "NONE":
             return ""
-        return "\n".join(self.meta.explain_lines(mode))
+        lines = self.meta.explain_lines(mode)
+        if mode == "ALL" and self.gang_verdict():
+            lines.append("ici: " + self.gang_verdict())
+        return "\n".join(lines)
 
     def collect(self, ctx: Optional[ExecCtx] = None,
                 qctx=None) -> pa.Table:
@@ -284,7 +309,13 @@ class PhysicalPlan:
                     slot.enter_context(ctx.mm.task_slot(qctx))
                 ctx.metric(self.root, "ledgerWaitTime").value += wait.dur
                 rbs = []
-                for b in self.root.execute(ctx):
+                gang, _ = self.gang_split()
+                if gang is not None:  # one member task per chip
+                    from .exec.gang import run as run_gang
+                    batches = run_gang(gang, ctx)
+                else:
+                    batches = self.root.execute(ctx)
+                for b in batches:
                     with ctx.tracer.span("download", cat="query") as down:
                         rb = device_to_arrow(b)
                         down.set(rows=rb.num_rows, bytes=rb.nbytes)
@@ -392,9 +423,12 @@ class PhysicalPlan:
             return self.explain("ALL") + \
                 "\n(no metrics: run collect() first)"
         ctx.opm.finalize()
-        return render_analyzed(self.root, fold_ctx(ctx),
+        text = render_analyzed(self.root, fold_ctx(ctx),
                                wall_s=getattr(self, "last_wall_s", None),
                                formatted=formatted, cluster="local")
+        if self.gang_verdict():
+            text += "\nici: " + self.gang_verdict()
+        return text
 
     def metrics_report(self, ctx: Optional[ExecCtx] = None) -> str:
         """Explain-style tree annotated with the metrics the last

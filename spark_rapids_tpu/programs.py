@@ -43,6 +43,15 @@ PROGRAM_NAMES = frozenset((
     "agg_single",         # ... single-pass (collect_*, exact percentile)
     "sort_batch",         # exec/sort.py: sort (and truncate) one batch
     "sort_merge",         # ... one round of the out-of-core run merge
+    # shuffle/ici.py: the SPMD all-to-all of an exchange epoch (the one
+    # program that spans the mesh), the broadcast's all-gather, an
+    # epoch's sizing reduction, and the landed ragged rebuilds (strings
+    # from flat payloads; arrays and broadcast strings from matrices)
+    "exchange_all_to_all",
+    "exchange_all_gather",
+    "exchange_caps",
+    "exchange_strings",
+    "exchange_ragged",
 ))
 
 #: single-primitive programs JAX compiles for EAGER ``jnp`` calls on the

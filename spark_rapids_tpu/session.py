@@ -390,6 +390,13 @@ class TpuSession:
         self.conf = conf or RapidsConf()
         self._tables: Dict[str, DataFrame] = {}
         self._cluster = None  # set_cluster: EXPLAIN ANALYZE target
+        from .config import SHUFFLE_MODE
+        if self.conf.get(SHUFFLE_MODE) == "ICI":
+            # the ONE mesh over the local devices and the ONE transport
+            # of its exchanges, up before the first query (a mesh that
+            # cannot be built fails here, not mid-query)
+            from .shuffle.ici import local_transport
+            self.ici_transport = local_transport(self.conf)
 
     def set_cluster(self, cluster) -> None:
         """Attach a TpuProcessCluster: ``EXPLAIN ANALYZE`` statements

@@ -27,7 +27,7 @@ from __future__ import annotations
 
 import threading
 from functools import partial
-from typing import Dict, List, Optional, Sequence, Tuple
+from typing import Dict, List, NamedTuple, Optional, Sequence, Tuple
 
 import jax
 import jax.numpy as jnp
@@ -36,11 +36,23 @@ from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
 from ..columnar.batch import TpuBatch, bucket_bytes, bucket_rows
 from ..columnar.column import TpuColumnVector
+from ..programs import named_jit
 from .transport import ShuffleTransport, ShuffleWriteHandle
 
 __all__ = ["make_ici_all_to_all", "make_ici_broadcast",
-           "IciShuffleTransport", "ici_broadcast_batches"]
+           "IciShuffleTransport", "IciGang", "ici_broadcast_batches",
+           "local_transport"]
 
+#: floors of the buckets an epoch sizes from what the data holds (the
+#: bytes of strings bound for one chip, the rows and string bytes that
+#: landed on one). A small exchange — a partial aggregate's few hundred
+#: rows — then has ONE shape whatever the seed drew: without them the
+#: per-pair payload of such an exchange sits at a bucket edge (11 rows
+#: of 12 characters against 128 bytes) and every other seed compiles the
+#: all-to-all and what consumes it anew.
+_PAIR_BYTES_FLOOR = 1 << 12
+_LANDED_ROWS_FLOOR = 1 << 10
+_LANDED_BYTES_FLOOR = 1 << 16
 
 
 def _axis_size(mesh: Mesh, axis) -> int:
@@ -171,8 +183,8 @@ def make_ici_all_to_all(mesh: Mesh, axis: str = "x"):
                      tuple(P(axis, None) for _ in ndims),
                      P(axis, None), P(axis),
                      tuple(P(axis, None) for _ in range(n_char)))
-        return jax.jit(jax.shard_map(spmd, mesh=mesh, in_specs=in_specs,
-                                     out_specs=out_specs))
+        return named_jit("exchange_all_to_all", jax.shard_map(
+            spmd, mesh=mesh, in_specs=in_specs, out_specs=out_specs))
 
     def program(datas, char_offs, char_caps):
         key = (tuple(d.ndim for d in datas), len(char_offs),
@@ -229,8 +241,8 @@ def make_ici_broadcast(mesh: Mesh, axis: str = "x"):
                     tuple(P(axis, None) for _ in ndims), P(axis, None))
         out_specs = (tuple(lane(nd) for nd in ndims),
                      tuple(P(axis, None) for _ in ndims), P(axis, None))
-        return jax.jit(jax.shard_map(spmd, mesh=mesh, in_specs=in_specs,
-                                     out_specs=out_specs))
+        return named_jit("exchange_all_gather", jax.shard_map(
+            spmd, mesh=mesh, in_specs=in_specs, out_specs=out_specs))
 
     def fn(datas, valids, live):
         datas = tuple(datas)
@@ -322,7 +334,7 @@ def _discover_widths(blocks: List[TpuBatch], spec,
             return jnp.stack([
                 _blocks_max_len(bs, ci, path)
                 for ci, path, _ in var_nodes])
-        fn = jax.jit(widths_fn)
+        fn = named_jit("exchange_caps", widths_fn)
         jit_cache[caps_key] = fn
     vals = np.asarray(jax.device_get(fn(blocks)))
     return {(ci, path): bucket_bytes(max(int(v), 1), minimum=8)
@@ -370,7 +382,7 @@ def _discover_epoch_caps(blocks, spec, ndev: int, fold: bool,
                     m = jnp.maximum(m, jnp.max(sums, initial=0))
                 outs.append(m)
             return jnp.stack(outs)
-        fn = jax.jit(caps_fn)
+        fn = named_jit("exchange_caps", caps_fn)
         jit_cache[key] = fn
     vals = np.asarray(jax.device_get(
         fn([(b, pids) for _, b, pids in blocks])))
@@ -378,7 +390,7 @@ def _discover_epoch_caps(blocks, spec, ndev: int, fold: bool,
     widths = {arr_nodes[i]: bucket_bytes(max(int(vals[i]), 1), minimum=8)
               for i in range(na)}
     char_caps = {str_nodes[j]: bucket_bytes(max(int(vals[na + j]), 1),
-                                            minimum=16)
+                                            minimum=_PAIR_BYTES_FLOOR)
                  for j in range(len(str_nodes))}
     return widths, char_caps
 
@@ -459,7 +471,8 @@ def _owner_rows(garr) -> List[jax.Array]:
         return [np.zeros(garr.shape[1:], garr.dtype)] * garr.shape[0]
     rows: List[jax.Array] = [None] * garr.shape[0]
     for s in garr.addressable_shards:
-        rows[s.index[0].start] = s.data.reshape(s.data.shape[1:])
+        # (a mesh of one shards nothing: its index is slice(None))
+        rows[s.index[0].start or 0] = s.data.reshape(s.data.shape[1:])
     return rows
 
 
@@ -479,8 +492,8 @@ def _tight(landed: TpuBatch, rows) -> TpuBatch:
     concats multiply it. The count costs nothing: the epoch's one
     readback already holds it."""
     from ..ops.gather import ensure_compacted, shrink_batch
-    return shrink_batch(ensure_compacted(landed),
-                        bucket_rows(max(int(rows), 1)))
+    return shrink_batch(ensure_compacted(landed), bucket_rows(
+        max(int(rows), 1), minimum=_LANDED_ROWS_FLOOR))
 
 
 def _on_device(b: TpuBatch, device) -> TpuBatch:
@@ -652,7 +665,6 @@ def _string_to_matrix(col: TpuColumnVector, cap: int, width: int):
     return _ragged_to_matrix(col.offsets, col.chars, cap, width)
 
 
-@partial(jax.jit, static_argnums=(3,))
 def _matrix_to_ragged(mat, lengths, live, flat_cap: int):
     """Inverse: ((n, B), (n,), (n,)) -> (offsets (n+1,), flat values)."""
     n = lengths.shape[0]
@@ -668,10 +680,11 @@ def _matrix_to_ragged(mat, lengths, live, flat_cap: int):
     return offsets, flat
 
 
+_matrix_to_ragged = named_jit("exchange_ragged", _matrix_to_ragged,
+                              static_argnums=(3,))
 _matrix_to_string = _matrix_to_ragged
 
 
-@partial(jax.jit, static_argnums=(3, 4, 5))
 def _payload_to_ragged(payload, lens, live, CB: int, ndev: int,
                        flat_cap: int):
     """Rebuild (offsets, chars) for one device's landed strings from
@@ -698,11 +711,19 @@ def _payload_to_ragged(payload, lens, live, CB: int, ndev: int,
     return offsets, flat
 
 
+_payload_to_ragged = named_jit("exchange_strings", _payload_to_ragged,
+                               static_argnums=(3, 4, 5))
+
+
 class _IciWriter(ShuffleWriteHandle):
-    def __init__(self, transport: "IciShuffleTransport", sid: int,
+    """Takes whole map batches with their partition ids into ``sink``
+    (the transport's pending list of a shuffle, or a gang member's own
+    blocks)."""
+
+    def __init__(self, transport: "IciShuffleTransport", sink: list,
                  map_id: int):
         self._t = transport
-        self._sid = sid
+        self._sink = sink
         self._mid = map_id
 
     def write(self, partition_id: int, batch: TpuBatch) -> None:
@@ -724,7 +745,7 @@ class _IciWriter(ShuffleWriteHandle):
                 f"({self._t.max_payload}) x mesh size {self._t.ndev}; "
                 "emit smaller map batches or raise the conf")
         with self._t._lock:
-            self._t._pending[self._sid].append((self._mid, batch, pids))
+            self._sink.append((self._mid, batch, pids))
 
 
 class IciShuffleTransport(ShuffleTransport):
@@ -740,7 +761,12 @@ class IciShuffleTransport(ShuffleTransport):
     the mesh size: partition p lands on device p mod D, with the original
     partition id riding an extra lane so `read_partition` can split the
     landed rows by selection mask (geometry folding, VERDICT r3 weak #3).
-    Strings ride as (byte-matrix, lengths) lane pairs."""
+    Strings ride as (byte-matrix, lengths) lane pairs.
+
+    Two callers: ONE task that writes every block and reads every
+    partition on its own device (``read_partition``), and an in-process
+    gang of one task per chip (``IciGang``), whose members pack their
+    blocks where they are and keep what lands on their chip there."""
 
     supports_unsplit = True
 
@@ -762,6 +788,15 @@ class IciShuffleTransport(ShuffleTransport):
         self._stats: Dict[int, np.ndarray] = {}  # (2, nparts) rows/bytes
         self._lock = threading.Lock()
         self._jit_widths: Dict[tuple, object] = {}
+
+    def slot_devices(self) -> list:
+        """The local device that holds row ``s`` of an array sharded over
+        its leading axis, for every ``s`` this process addresses (all of
+        them in one process)."""
+        sh = NamedSharding(self.mesh, P(self.axis, None))
+        index = sh.addressable_devices_indices_map((self.ndev, 1))
+        return [d for d, _ in sorted(
+            index.items(), key=lambda kv: kv[1][0].start or 0)]
 
     def register_shuffle(self, shuffle_id: int, num_partitions: int):
         with self._lock:
@@ -796,7 +831,7 @@ class IciShuffleTransport(ShuffleTransport):
         return [int(v) for v in s[1]]
 
     def writer(self, shuffle_id: int, map_id: int) -> ShuffleWriteHandle:
-        return _IciWriter(self, shuffle_id, map_id)
+        return _IciWriter(self, self._pending[shuffle_id], map_id)
 
     def _realize_classified(self, shuffle_id: int, partition_id: int):
         """Run the collective with host-transport failure parity: a
@@ -844,6 +879,7 @@ class IciShuffleTransport(ShuffleTransport):
         SHUF_PARTS_FETCHED.labels("ici").inc()
         # landed rows stay on the chip that owns the partition until
         # read; the single consuming task takes them on its own device
+        # (a gang's members never come here: IciGangMember)
         consumer = _consumer_device()
         for b in self._results.get(shuffle_id, [[]] * nparts)[
                 partition_id]:
@@ -856,8 +892,7 @@ class IciShuffleTransport(ShuffleTransport):
         it is still pending) — chip_smoke.py's evidence that an
         exchange really spread over the mesh."""
         self._realize(shuffle_id)
-        return [sorted({d.id for b in part for c in b.columns
-                        for a in c.arrays() for d in a.devices()})
+        return [_devices_of(part)
                 for part in self._results.get(shuffle_id, [])]
 
     def unregister_shuffle(self, shuffle_id: int):
@@ -897,61 +932,99 @@ class IciShuffleTransport(ShuffleTransport):
             self._pending.pop(sid, None)
 
     def _run_epoch(self, blocks, nparts: int, results, sid: int = -1):
+        """One task's epoch: every block is packed where the task left
+        it and sent to its slot's chip; every chip's landing is kept."""
         schema = blocks[0][1].schema
+        spec = _lane_spec(schema)
+        sizing = _EpochSizing.of(blocks, spec, self.ndev,
+                                 nparts != self.ndev, self._jit_widths)
+        devices = self.slot_devices()
+        packed = [self._pack_slot(blocks[s] if s < len(blocks) else None,
+                                  schema, spec, nparts, sizing, devices[s])
+                  for s in range(self.ndev)]
+        ep = self._launch(packed, schema, spec, nparts, sizing)
+        if sid >= 0 and sid in self._stats:
+            rows = np.asarray(ep.pcounts, dtype=np.float64)
+            total_rows = max(float(rows.sum()), 1.0)
+            epoch_bytes = float(sum(b.device_size_bytes()
+                                    for _, b, _ in blocks))
+            st = self._stats[sid]
+            st[0, :len(rows)] += rows
+            st[1, :len(rows)] += rows * (epoch_bytes / total_rows)
+        for d in range(self.ndev):
+            for p, b in self._land(ep, d):
+                results[p].append(b)
+
+    def _pack_slot(self, block, schema, spec, nparts: int,
+                   sizing: "_EpochSizing", device) -> "_Packed":
+        """One slot of an epoch: the block's lanes (``None``: an empty
+        slot's) padded to the epoch's common shapes, each under a leading
+        axis of one and committed to ``device``, the chip that holds the
+        slot. Run it where the block lives: every operation here follows
+        the calling thread's default device."""
+        fold = nparts != self.ndev
+        cap = sizing.cap
+        _, lane_datas, lane_valids = _lane_layout(spec)
+        if block is not None:
+            _, b, pids = block
+            live = _pad1(b.live_mask(), cap)
+            pids = _pad1(pids.astype(jnp.int32), cap)
+        else:
+            b = None
+            pids = jnp.zeros((cap,), jnp.int32)
+            live = jnp.zeros((cap,), jnp.bool_)
+        char_stacks: Dict[tuple, tuple] = {}
+        _pack_block(b, schema, cap, sizing.widths, lane_datas, lane_valids,
+                    spec, char_stacks=char_stacks)
+        if fold:  # one extra lane carries the ORIGINAL partition id
+            lane_datas.append([pids])
+            lane_valids.append([live])
+        put = lambda a: jax.device_put(a[None], device)  # noqa: E731
+        offs, chars = [], []
+        for key in sizing.str_keys:
+            o, c = char_stacks[key]
+            offs.append(put(o[0]))
+            chars.append(put(_pad1(c[0], sizing.src_caps[key])))
+        return _Packed([put(ls[0]) for ls in lane_datas],
+                       [put(ls[0]) for ls in lane_valids],
+                       # routing: partition p belongs to device p mod D
+                       put(pids % self.ndev if fold else pids), put(live),
+                       offs, chars)
+
+    def _assemble(self, parts):
+        """``ndev`` arrays ``(1, ...)``, slot ``s``'s on slot ``s``'s chip,
+        as the one array ``(ndev, ...)`` sharded over its leading axis:
+        no byte moves."""
+        sh = NamedSharding(self.mesh, P(self.axis, *(
+            [None] * (parts[0].ndim - 1))))
+        return jax.make_array_from_single_device_arrays(
+            (self.ndev,) + parts[0].shape[1:], sh, list(parts))
+
+    def _launch(self, packed, schema, spec, nparts: int,
+                sizing: "_EpochSizing") -> "_Epoch":
+        """The collective over the packed slots, and the epoch's ONE
+        readback: per-device landed row counts + per-device live payload
+        totals + (folded geometry) per-ORIGINAL-partition landed counts —
+        the AQE stats ride the same transfer, so adaptivity costs no
+        extra sync on this transport (VERDICT r4 weak #5)."""
         ndev = self.ndev
         fold = nparts != ndev
-        cap = max(b.capacity for _, b, _ in blocks)
-        spec = _lane_spec(schema)
-        widths, char_caps = _discover_epoch_caps(blocks, spec, ndev,
-                                                 fold, self._jit_widths)
-
-        # shared lane layout, plus with folding one extra lane carrying
-        # the ORIGINAL partition id
-        lane_meta, lane_datas, lane_valids = _lane_layout(spec)
-        if fold:
-            lane_meta.append((-1, (), "pid", None))
-            lane_datas.append([])
-            lane_valids.append([])
-
-        pids_all, live_all = [], []
-        char_stacks: Dict[tuple, tuple] = {}
-        for slot in range(ndev):
-            if slot < len(blocks):
-                _, b, pids = blocks[slot]
-                live = _pad1(b.live_mask(), cap)
-                pids = _pad1(pids.astype(jnp.int32), cap)
-            else:
-                b = None
-                pids = jnp.zeros((cap,), jnp.int32)
-                live = jnp.zeros((cap,), jnp.bool_)
-            # routing: partition p belongs to device p mod D
-            pids_all.append(pids % ndev if fold else pids)
-            live_all.append(live)
-            _pack_block(b, schema, cap, widths, lane_datas, lane_valids,
-                        spec, char_stacks=char_stacks)
-            if fold:
-                lane_datas[-1].append(pids)
-                lane_valids[-1].append(live)
-
-        shard = _mesh_shard(self.mesh, self.axis)
-        datas = tuple(shard(jnp.stack(ls)) for ls in lane_datas)
-        valids = tuple(shard(jnp.stack(ls)) for ls in lane_valids)
-        pids_g = shard(jnp.stack(pids_all))
-        live_g = shard(jnp.stack(live_all))
-
-        # string payload lanes, in spec order of their str_mat entries
-        str_keys = [(ci, path) for ci, path, kind, _ in spec
-                    if kind == "str_mat"]
-        char_offs, char_bytes, cb_list = [], [], []
-        for keyk in str_keys:
-            offs_list, chars_list = char_stacks[keyk]
-            ch_cap = bucket_bytes(
-                max([c.shape[0] for c in chars_list] + [1]), minimum=16)
-            char_offs.append(shard(jnp.stack(offs_list)))
-            char_bytes.append(shard(jnp.stack(
-                [_pad1(c, ch_cap) for c in chars_list])))
-            cb_list.append(char_caps[keyk])
-
+        lane_meta = list(spec) + ([(-1, (), "pid", None)] if fold else [])
+        nl = len(lane_meta)
+        datas = tuple(self._assemble([p.datas[li] for p in packed])
+                      for li in range(nl))
+        valids = tuple(self._assemble([p.valids[li] for p in packed])
+                       for li in range(nl))
+        pids_g = self._assemble([p.pids for p in packed])
+        live_g = self._assemble([p.live for p in packed])
+        nstr = len(sizing.str_keys)
+        char_offs = [self._assemble([p.char_offs[k] for p in packed])
+                     for k in range(nstr)]
+        char_bytes = [self._assemble([p.char_bytes[k] for p in packed])
+                      for k in range(nstr)]
+        cb_list = [sizing.char_caps[key] for key in sizing.str_keys]
+        sent = sum(a.nbytes for a in (*datas, *valids, pids_g, live_g,
+                                      *char_offs, *char_bytes))
         out_datas, out_valids, out_live, out_rc, out_chars = \
             self._exchange(datas, valids, pids_g, live_g,
                            char_offs=char_offs, char_bytes=char_bytes,
@@ -962,18 +1035,12 @@ class IciShuffleTransport(ShuffleTransport):
             if kind == "str_mat":
                 payloads[li] = (_owner_rows(out_chars[si]), cb_list[si])
                 si += 1
-
-        # ONE readback for everything host sizing needs this epoch:
-        # per-device landed row counts + per-device live payload totals
-        # + (folded geometry) per-ORIGINAL-partition landed counts — the
-        # AQE stats ride the same transfer, so adaptivity costs no extra
-        # sync on this transport (VERDICT r4 weak #5)
         len_lanes = _len_lane_indices(spec)
         sizes = [out_rc] + [
             jnp.sum(jnp.where(out_live, out_datas[li], 0), axis=1)
             for li in len_lanes]
         if fold:
-            pid_all = out_datas[len(lane_meta) - 1]
+            pid_all = out_datas[nl - 1]
             ids = jnp.where(out_live,
                             jnp.clip(pid_all, 0, nparts - 1),
                             jnp.int32(nparts)).reshape(-1)
@@ -985,43 +1052,292 @@ class IciShuffleTransport(ShuffleTransport):
         else:
             sizes_host = np.asarray(jax.device_get(jnp.stack(sizes)))
             pcounts_host = sizes_host[0][:nparts]
-        if sid >= 0 and sid in self._stats:
-            rows = np.asarray(pcounts_host, dtype=np.float64)
-            total_rows = max(float(rows.sum()), 1.0)
-            epoch_bytes = float(sum(b.device_size_bytes()
-                                    for _, b, _ in blocks))
-            st = self._stats[sid]
-            st[0, :len(rows)] += rows
-            st[1, :len(rows)] += rows * (epoch_bytes / total_rows)
+        return _Epoch(schema, spec, lane_meta, nparts, sizing.cap,
+                      [_owner_rows(a) for a in out_datas],
+                      [_owner_rows(a) for a in out_valids],
+                      _owner_rows(out_live), payloads, sizes_host,
+                      np.asarray(pcounts_host), sent)
 
-        # each chip rebuilds its own partition from the rows it holds
-        own_datas = [_owner_rows(a) for a in out_datas]
-        own_valids = [_owner_rows(a) for a in out_valids]
-        own_live = _owner_rows(out_live)
-        for d in range(ndev):
-            if sizes_host[0][d] == 0:
-                continue
-            flat_caps = {}
-            for si, li in enumerate(len_lanes):
-                total = max(int(sizes_host[1 + si][d]), 1)
-                if spec[li][2] == "str_len":
-                    flat_caps[li - 1] = bucket_bytes(total, minimum=16)
-                else:  # arr_len sits after (arr_mat, arr_vmat)
-                    flat_caps[li - 2] = bucket_rows(total)
-            cols, pid_lane = _unpack_device(
-                schema, lane_meta, own_datas, own_valids, d, own_live[d],
-                flat_caps, payloads=payloads, ndev=ndev)
-            landed = TpuBatch(cols, schema, ndev * cap,
-                              selection=own_live[d])
-            if not fold:
-                results[d].append(_tight(landed, sizes_host[0][d]))
-            else:
-                # split the landed rows by original partition id
-                for p in range(d, nparts, ndev):
-                    if pcounts_host[p]:
-                        results[p].append(_tight(
-                            landed.with_selection(pid_lane == p),
-                            pcounts_host[p]))
+    def _land(self, ep: "_Epoch", d: int, keep_empty: bool = False):
+        """``(partition, batch)`` of what the epoch landed on chip ``d``:
+        the chip rebuilds its own partitions from the rows it holds,
+        where it holds them (every input is committed to ``d``). A
+        partition no row landed in is left out, unless ``keep_empty`` (a
+        gang's member then runs the same programs whatever the data
+        sent it)."""
+        if ep.sizes[0][d] == 0 and not keep_empty:
+            return
+        ndev = self.ndev
+        flat_caps = {}
+        for si, li in enumerate(_len_lane_indices(ep.spec)):
+            total = max(int(ep.sizes[1 + si][d]), 1)
+            if ep.spec[li][2] == "str_len":
+                flat_caps[li - 1] = bucket_bytes(
+                    total, minimum=_LANDED_BYTES_FLOOR)
+            else:  # arr_len sits after (arr_mat, arr_vmat)
+                flat_caps[li - 2] = bucket_rows(
+                    total, minimum=_LANDED_ROWS_FLOOR)
+        cols, pid_lane = _unpack_device(
+            ep.schema, ep.lane_meta, ep.datas, ep.valids, d, ep.live[d],
+            flat_caps, payloads=ep.payloads, ndev=ndev)
+        landed = TpuBatch(cols, ep.schema, ndev * ep.cap,
+                          selection=ep.live[d])
+        if ep.nparts == ndev:
+            yield d, _tight(landed, ep.sizes[0][d])
+            return
+        # split the landed rows by original partition id
+        for p in range(d, ep.nparts, ndev):
+            if ep.pcounts[p] or keep_empty:
+                yield p, _tight(landed.with_selection(pid_lane == p),
+                                ep.pcounts[p])
+
+
+def _devices_of(batches) -> List[int]:
+    return sorted({d.id for b in batches for c in b.columns
+                   for a in c.arrays() for d in a.devices()})
+
+
+class _Packed(NamedTuple):
+    """One slot's lanes of an epoch (``_pack_slot``)."""
+    datas: list
+    valids: list
+    pids: jax.Array
+    live: jax.Array
+    char_offs: list
+    char_bytes: list
+
+
+class _Epoch(NamedTuple):
+    """What one collective left on the chips, per chip, and the host's
+    counts of it (``_launch``)."""
+    schema: object
+    spec: list
+    lane_meta: list
+    nparts: int
+    cap: int
+    datas: list
+    valids: list
+    live: list
+    payloads: dict
+    sizes: np.ndarray
+    pcounts: np.ndarray
+    sent: int  # bytes handed to the all-to-all
+
+
+class _EpochSizing(NamedTuple):
+    """The static shapes every slot of an epoch is packed to: the row
+    capacity, matrix widths of array nodes, per string node the per-pair
+    payload bucket (``char_caps``) and the source chars capacity
+    (``src_caps``). ``of`` sizes a set of blocks (one jitted reduction and
+    one readback, on the device they live on); ``merged`` takes the
+    field-wise maxima of several, so that the members of a gang, each
+    sizing its own blocks on its own chip, enter one program."""
+
+    cap: int
+    widths: dict
+    char_caps: dict
+    src_caps: dict
+    str_keys: list
+    nblocks: int
+
+    @classmethod
+    def of(cls, blocks, spec, ndev: int, fold: bool, jit_cache):
+        str_keys = [(ci, path) for ci, path, kind, _ in spec
+                    if kind == "str_mat"]
+        if not blocks:
+            return cls(1, {}, {}, {}, str_keys, 0)
+        widths, char_caps = _discover_epoch_caps(blocks, spec, ndev, fold,
+                                                 jit_cache)
+        src_caps = {key: bucket_bytes(max(
+            [_node_at(b.column(key[0]), key[1]).chars.shape[0]
+             for _, b, _ in blocks] + [1]), minimum=16) for key in str_keys}
+        return cls(max(b.capacity for _, b, _ in blocks), widths,
+                   char_caps, src_caps, str_keys, len(blocks))
+
+    @classmethod
+    def merged(cls, sizings):
+        def top(attr):
+            out: Dict[tuple, int] = {}
+            for s in sizings:
+                for k, v in getattr(s, attr).items():
+                    out[k] = max(out.get(k, 0), v)
+            return out
+        str_keys = sizings[0].str_keys
+        char_caps, src_caps = top("char_caps"), top("src_caps")
+        for key in str_keys:  # a gang whose blocks all came from others
+            char_caps.setdefault(key, _PAIR_BYTES_FLOOR)
+            src_caps.setdefault(key, 16)
+        return cls(max(s.cap for s in sizings), top("widths"), char_caps,
+                   src_caps, str_keys, max(s.nblocks for s in sizings))
+
+
+class GangAborted(RuntimeError):
+    """Another member of the gang failed; this one stops where it waits."""
+
+
+class IciGang:
+    """One exchange of an in-process gang: ``ndev`` member tasks, member
+    ``k`` a thread whose default device is the mesh's slot ``k``, each
+    with its own view of the transport (``member(k)``). Every member
+    writes its map blocks into its own slot, and the first read is the
+    rendezvous: the members size their blocks (each on its chip), agree
+    on the maxima, pack, and member 0 hands the packed slots to the
+    all-to-all as they lie — a collective between chips, no byte through
+    host memory; then every member rebuilds what landed on its chip, on
+    its chip, and reads only that. A member with fewer blocks than the
+    others packs empty slots. A member that fails must ``abort`` the gang
+    (its thread's owner does: ``exec/gang.py``): the barrier breaks and
+    every member waiting at it, now or later, raises ``GangAborted``.
+
+    Spans (``tracer``; children of ``parent_span``): ``exchange.ici``
+    around each collective epoch on member 0 (``shuffle``, ``epoch``,
+    ``blocks``, ``bytes`` handed to the all-to-all, ``partitions``), and
+    ``exchange.wait`` wherever a member waits for the others."""
+
+    def __init__(self, transport: IciShuffleTransport, num_partitions: int,
+                 schema, tracer=None, parent_span=None,
+                 shuffle_id: int = -1):
+        from ..obs.tracer import NULL_TRACER
+        self.transport = transport
+        self.ndev = transport.ndev
+        self.nparts = num_partitions
+        self.schema = schema
+        self.shuffle_id = shuffle_id
+        self.tracer = tracer or NULL_TRACER
+        self.parent_span = parent_span
+        self.devices = transport.slot_devices()
+        self.epochs = 0   # collective epochs run
+        self.bytes = 0    # bytes handed to the all-to-all
+        self._barrier = threading.Barrier(self.ndev)
+        self._sizing: List[Optional[_EpochSizing]] = [None] * self.ndev
+        self._packed: List[Optional[_Packed]] = [None] * self.ndev
+        self._epoch: Optional[_Epoch] = None
+        self._landed_ids: Dict[int, List[int]] = {}
+        self._members = [IciGangMember(self, k) for k in range(self.ndev)]
+
+    def member(self, k: int) -> "IciGangMember":
+        return self._members[k]
+
+    def abort(self) -> None:
+        self._barrier.abort()
+
+    def landed_devices(self) -> List[List[int]]:
+        """Per partition, the device ids its landed batches sat on when
+        they landed, read off the arrays."""
+        return [self._landed_ids.get(p, []) for p in range(self.nparts)]
+
+    def _wait(self, on: str) -> None:
+        with self.tracer.span("exchange.wait", cat="exchange",
+                              parent_id=self.parent_span,
+                              args={"on": on}):
+            try:
+                self._barrier.wait()
+            except threading.BrokenBarrierError:
+                raise GangAborted(
+                    "another member of the gang failed") from None
+
+    def _exchange(self, k: int, blocks) -> Dict[int, List[TpuBatch]]:
+        """Member ``k``'s part of the rendezvous, on its own thread; what
+        landed on its chip, by partition."""
+        t = self.transport
+        spec = _lane_spec(self.schema)
+        fold = self.nparts != self.ndev
+        self._sizing[k] = _EpochSizing.of(blocks, spec, self.ndev, fold,
+                                          t._jit_widths)
+        self._wait("sizing")
+        sizing = _EpochSizing.merged(self._sizing)
+        landed: Dict[int, List[TpuBatch]] = {}
+        for e in range(sizing.nblocks):
+            self._packed[k] = t._pack_slot(
+                blocks[e] if e < len(blocks) else None, self.schema, spec,
+                self.nparts, sizing, self.devices[k])
+            self._wait("pack")
+            if k == 0:
+                nblocks = sum(e < s.nblocks for s in self._sizing)
+                with self.tracer.span(
+                        "exchange.ici", cat="exchange",
+                        parent_id=self.parent_span,
+                        args={"shuffle": self.shuffle_id, "epoch": e,
+                              "blocks": nblocks,
+                              "partitions": self.nparts}) as sp:
+                    self._epoch = t._launch(list(self._packed), self.schema,
+                                            spec, self.nparts, sizing)
+                    sp.set(bytes=int(self._epoch.sent))
+                self.epochs += 1
+                self.bytes += int(self._epoch.sent)
+            self._wait("collective")
+            for p, b in t._land(self._epoch, k, keep_empty=True):
+                landed.setdefault(p, []).append(b)
+        self._landed_ids.update(
+            {p: _devices_of(bs) for p, bs in landed.items()})
+        return landed
+
+
+class IciGangMember(ShuffleTransport):
+    """What member ``k`` of an ``IciGang`` sees of the transport: the
+    gang's one shuffle under whatever id the member's exchange gives it,
+    its writes in its own slot, and of the landed partitions only those
+    of its chip (``p mod ndev == k``), handed over where they are."""
+
+    supports_unsplit = True
+
+    def __init__(self, gang: IciGang, k: int):
+        self._gang = gang
+        self._k = k
+        self.max_payload = gang.transport.max_payload
+        self.ndev = gang.ndev
+        self._lock = gang.transport._lock
+        self._blocks: List[Tuple[int, TpuBatch, object]] = []
+        self._landed: Optional[Dict[int, List[TpuBatch]]] = None
+
+    def register_shuffle(self, shuffle_id: int, num_partitions: int):
+        if num_partitions != self._gang.nparts:
+            raise ValueError(
+                f"gang exchange of {self._gang.nparts} partitions asked "
+                f"for {num_partitions}")
+
+    def writer(self, shuffle_id: int, map_id: int) -> ShuffleWriteHandle:
+        return _IciWriter(self, self._blocks, map_id)
+
+    def read_partition(self, shuffle_id: int, partition_id: int):
+        from .host import SHUF_BYTES_FETCHED, SHUF_PARTS_FETCHED
+        if self._landed is None:
+            self._landed = self._gang._exchange(self._k, self._blocks)
+            self._blocks = []
+        if partition_id % self.ndev != self._k:
+            return
+        SHUF_PARTS_FETCHED.labels("ici").inc()
+        for b in self._landed.get(partition_id, []):
+            SHUF_BYTES_FETCHED.labels("ici").inc(b.device_size_bytes())
+            yield b
+
+    def unregister_shuffle(self, shuffle_id: int):
+        self._blocks = []
+        self._landed = None
+
+
+_LOCAL: Dict[tuple, IciShuffleTransport] = {}
+_LOCAL_LOCK = threading.Lock()
+
+
+def local_transport(conf) -> IciShuffleTransport:
+    """The process's ONE transport for sessions whose conf says
+    ``spark.rapids.shuffle.mode=ICI``: over ONE mesh of this process's
+    local devices, the first ``min(devices, spark.sql.shuffle.partitions)``
+    of ``jax.local_devices()`` (a chip beyond the partition count would
+    have no partition to land). With one device it is a mesh of one and
+    the exchange a collective with itself."""
+    from ..config import ICI_MAX_PAYLOAD, SHUFFLE_PARTITIONS
+    devices = jax.local_devices()
+    devices = devices[:max(1, min(len(devices),
+                                  conf.get(SHUFFLE_PARTITIONS)))]
+    key = (tuple(d.id for d in devices), conf.get(ICI_MAX_PAYLOAD))
+    with _LOCAL_LOCK:
+        t = _LOCAL.get(key)
+        if t is None:
+            t = _LOCAL[key] = IciShuffleTransport(
+                Mesh(np.array(devices), ("x",)), conf=conf)
+    return t
 
 
 def _pad1(a, cap: int):

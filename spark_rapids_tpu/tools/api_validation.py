@@ -175,10 +175,21 @@ def generate_supported_ops() -> str:
     ]
     lines += [
         "", "## Shuffle transports", "",
-        "`TpuShuffleExchangeExec` is transport-agnostic. In-process "
-        "collects materialize it through `IciShuffleTransport` "
-        "(`shuffle/ici.py`): the all-to-all repartition runs as one "
-        "XLA collective over the local device mesh. On a "
+        "`TpuShuffleExchangeExec` is transport-agnostic; "
+        "`spark.rapids.shuffle.mode` picks the transport of an "
+        "in-process collect (LOCAL by default). With `ICI` the session "
+        "builds one mesh over its local devices and one "
+        "`IciShuffleTransport` (`shuffle/ici.py`): the all-to-all "
+        "repartition runs as one XLA collective over that mesh, and a "
+        "plan of the shape *unary operators / hash aggregate / hash "
+        "exchange / scans, filters, projections, hash joins* runs as a "
+        "gang of one member task per chip (`exec/gang.py`: the scan of "
+        "most row groups sliced, every other table whole in every "
+        "member, partial aggregates through the exchange, each "
+        "partition finalized where it landed); UNION ALL, an outer "
+        "join whose preserved side is not the sliced one, `collect_*` "
+        "aggregates and an adaptive reader over the exchange run as "
+        "one task, and EXPLAIN's `ici:` line says which. On a "
         "`TpuProcessCluster` the default is the file-based HOST "
         "transport (Arrow IPC map outputs through the filesystem "
         "rendezvous, CRC-footed, lineage-recoverable); with "
