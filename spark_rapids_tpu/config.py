@@ -269,7 +269,14 @@ PARQUET_READER_TYPE = register(
     "(merge small files into one decode).")
 PARQUET_MULTITHREADED_THREADS = register(
     "spark.rapids.sql.format.parquet.multiThreadedRead.numThreads", 8,
-    "Reader thread pool size for MULTITHREADED parquet.")
+    "Reader thread pool size for MULTITHREADED parquet. The host-decode "
+    "readers decode this many splits at once. The device-decode scan "
+    "FETCHES with it (the footer and the byte ranges of a row group's "
+    "wanted column chunks: I/O, worth many at once where storage is "
+    "slow; numThreads + scan.prefetchBatches row groups fetched ahead "
+    "at most) and WALKS what was fetched (page headers, decompression, "
+    "run headers: Python under one interpreter lock) one row group at "
+    "a time, in order, whatever this says.")
 PARQUET_DEVICE_DECODE = register(
     "spark.rapids.sql.format.parquet.deviceDecode.enabled", True,
     "Decode Parquet pages on the device: encoded column chunks "

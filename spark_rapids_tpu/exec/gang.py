@@ -56,9 +56,11 @@ __all__ = ["GangSplit", "split", "run"]
 #: join(slice_k(A), B) over all k is join(A, B)
 _SLICE_LEFT = ("inner", "cross", "left_outer", "left_semi", "left_anti")
 _SLICE_RIGHT = ("inner", "cross", "right_outer")
-#: metrics a member SETS (every member the same value): folded by taking
-#: one, where every other metric is a count or a time and adds up
-_SET_METRICS = ("fusedInto", "fusedChainOps", "numPartitions")
+#: metrics a member SETS (every member the same value) or keeps a maximum
+#: of: folded by taking the largest, where every other metric is a count
+#: or a time and adds up
+_SET_METRICS = ("fusedInto", "fusedChainOps", "numPartitions",
+                "fetchAheadMax")
 
 
 class GangSplit:
@@ -209,7 +211,9 @@ def _fold_metrics(ctx: ExecCtx, members: List[_Member]) -> None:
                 into = ctx.metrics.setdefault(label, {})
                 if name not in into:
                     into[name] = metric
-                elif name not in _SET_METRICS:
+                elif name in _SET_METRICS:
+                    into[name].value = max(into[name].value, metric.value)
+                else:
                     into[name].value += metric.value
 
 

@@ -71,7 +71,8 @@ from ..columnar.column import TpuColumnVector
 from ..obs.tracer import StageClock
 from ..programs import module_name, named_jit
 
-__all__ = ["plan_chunk", "decode_chunk_device",
+__all__ = ["plan_chunk", "chunk_envelope", "chunk_byte_range",
+           "decode_chunk_device",
            "decode_row_group_device", "merge_chunk_plans", "ChunkPlan",
            "HostFallback", "encoded_nbytes"]
 
@@ -501,11 +502,13 @@ def _decode_delta_ints(data: bytes, off: int):
     return out[:total], pos
 
 
-def plan_chunk(f, col_md, descriptor, engine_dtype: dt.DataType,
-               arrow_field_type) -> ChunkPlan:
-    """Plan one column chunk (one row group × one column) for device
-    decode. `f` is an open seekable file object; raises HostFallback
-    anywhere outside the envelope."""
+def chunk_envelope(col_md, descriptor, engine_dtype: dt.DataType,
+                   arrow_field_type):
+    """What the footer alone says of one column chunk: ``(phys,
+    is_string, lane, max_def, codec)``, or HostFallback where its type,
+    nesting or codec is outside the envelope. Reads no byte of the
+    chunk: the scan's fetch asks it which chunks to fetch at all, and
+    ``plan_chunk`` starts from it."""
     phys = col_md.physical_type
     is_string = phys == "BYTE_ARRAY" \
         and isinstance(engine_dtype, (dt.StringType, dt.BinaryType))
@@ -544,13 +547,29 @@ def plan_chunk(f, col_md, descriptor, engine_dtype: dt.DataType,
             raise HostFallback(
                 f"file type {arrow_field_type} vs engine {eng_arrow}",
                 "phys-type")
+    return phys, is_string, lane, max_def, codec
 
-    n_rows = col_md.num_values
+
+def chunk_byte_range(col_md) -> Tuple[int, int]:
+    """``(start, size)`` of one column chunk's pages in its file."""
     start = col_md.data_page_offset
     if col_md.dictionary_page_offset is not None:
         start = min(start, col_md.dictionary_page_offset)
+    return start, col_md.total_compressed_size
+
+
+def plan_chunk(f, col_md, descriptor, engine_dtype: dt.DataType,
+               arrow_field_type) -> ChunkPlan:
+    """Plan one column chunk (one row group × one column) for device
+    decode. `f` is an open seekable file object (or the scan's view
+    over the chunk's fetched bytes); raises HostFallback anywhere
+    outside the envelope."""
+    phys, is_string, lane, max_def, codec = chunk_envelope(
+        col_md, descriptor, engine_dtype, arrow_field_type)
+    n_rows = col_md.num_values
+    start, size = chunk_byte_range(col_md)
     f.seek(start)
-    buf = f.read(col_md.total_compressed_size)
+    buf = f.read(size)
 
     dictionary: Optional[np.ndarray] = None
     # string store: dictionary-page values first, then PLAIN /

@@ -92,6 +92,37 @@ _INT_RE = re.compile(r"[+-]?\d+\Z")
 _FLOAT_RE = re.compile(r"[+-]?(\d+\.?\d*|\.\d+)([eE][+-]?\d+)?\Z")
 
 
+class _FetchedRowGroup:
+    """One row group as its fetch left it: the file's footer, the scan's
+    file columns against it (``chunks``: ``(field, column index or
+    None)`` in schema order — None where the footer has no leaf of that
+    name: a nested column, or schema evolution) and the encoded bytes of
+    the column chunks the walk will ask for, in memory. ``seek`` and
+    ``read`` serve ``plan_chunk`` where the open file did — a read at a
+    fetched chunk's offset gives that chunk's bytes, as many as the file
+    gave — so the walk touches no storage."""
+
+    __slots__ = ("metadata", "schema", "schema_arrow", "num_rows",
+                 "chunks", "nbytes", "_ranges", "_pos")
+
+    def __init__(self, pf: "pq.ParquetFile", g: int, chunks,
+                 ranges: Dict[int, bytes]):
+        self.metadata = pf.metadata
+        self.num_rows = pf.metadata.row_group(g).num_rows
+        self.schema = pf.schema            # the ParquetSchema (leaves)
+        self.schema_arrow = pf.schema_arrow
+        self.chunks = chunks
+        self.nbytes = sum(len(b) for b in ranges.values())
+        self._ranges = ranges
+        self._pos = 0
+
+    def seek(self, pos: int) -> None:
+        self._pos = pos
+
+    def read(self, size: int) -> bytes:
+        return self._ranges[self._pos][:size]
+
+
 class FileSplit:
     """A unit of scan work: one file, optionally restricted to a row-group
     range (Parquet). The FilePartition analog."""
@@ -722,52 +753,81 @@ class TpuFileScanExec(LeafExec):
             pf = cache[path] = pq.ParquetFile(path)
         return pf
 
-    def _plan_row_group(self, path: str, g: int):
-        """Host side of the device-decode path for one row group: page
-        walk + codec decompress + run-header parse per eligible column
-        chunk; pyarrow decode for the rest. Runs on the reader pool.
-        The trailing element is the tuple of bounded fallback-reason
-        slugs for the chunks that dropped to host decode — the scan's
-        decode-coverage counters ride it."""
-        from .parquet_device import HostFallback, plan_chunk
+    def _fetch_row_group(self, path: str, g: int) -> _FetchedRowGroup:
+        """The I/O of one row group, and nothing else: the footer (one
+        parse per pool thread and file) and ONE ``seek`` + ``read`` per
+        column chunk the walk will ask for — what ``plan_chunk`` would
+        read from the open file, so a file shorter than its footer says
+        hands the walk the same short bytes. Chunks the footer alone
+        sends to the host (``chunk_envelope``) are not fetched: pyarrow
+        reads those itself. Runs on the reader pool, many at once: a
+        read releases the interpreter lock."""
+        from .parquet_device import (HostFallback, chunk_byte_range,
+                                     chunk_envelope)
         pf = self._thread_pf(path)
         md = pf.metadata
         rg = md.row_group(g)
-        n_rows = rg.num_rows
         name_to_ci = {md.schema.column(i).name: i
                       for i in range(md.num_columns)}
         part_fields = {f.name for f in self._part_schema.fields} \
             if self._part_schema is not None else set()
-        arrow_names = set(pf.schema_arrow.names)
+        chunks = [(fld, name_to_ci.get(fld.name))
+                  for fld in self._schema.fields
+                  if fld.name not in part_fields]
+        ranges: Dict[int, bytes] = {}
+        with open(path, "rb") as f:
+            for fld, ci in chunks:
+                if ci is None:
+                    continue
+                col = rg.column(ci)
+                try:
+                    chunk_envelope(col, pf.schema.column(ci), fld.dtype,
+                                   pf.schema_arrow.field(fld.name).type)
+                except HostFallback:
+                    continue
+                start, size = chunk_byte_range(col)
+                f.seek(start)
+                ranges[start] = f.read(size)
+        return _FetchedRowGroup(pf, g, chunks, ranges)
+
+    def _plan_row_group(self, path: str, g: int,
+                        fetched: _FetchedRowGroup):
+        """Host side of the device-decode path for one row group, over
+        the bytes ``_fetch_row_group`` brought in: page walk + codec
+        decompress + run-header parse per eligible column chunk; pyarrow
+        decode for the rest. Python under the interpreter lock, so the
+        scan runs ONE at a time, in task order (``planned``). The
+        trailing element is the tuple of bounded fallback-reason slugs
+        for the chunks that dropped to host decode — the scan's
+        decode-coverage counters ride it."""
+        from .parquet_device import HostFallback, plan_chunk
+        rg = fetched.metadata.row_group(g)
+        arrow_names = set(fetched.schema_arrow.names)
         plans: Dict[str, object] = {}
         host_cols: List[str] = []
         fb_reasons: List[str] = []
-        with open(path, "rb") as f:
-            for fld in self._schema.fields:
-                if fld.name in part_fields:
-                    continue
-                ci = name_to_ci.get(fld.name)
-                if ci is None:
-                    if fld.name in arrow_names:
-                        # a nested column: its leaves go by other
-                        # names; pyarrow assembles it on the host
-                        host_cols.append(fld.name)
-                        fb_reasons.append("nested")
-                    continue  # schema evolution: nulls at assembly
-                try:
-                    plans[fld.name] = plan_chunk(
-                        f, rg.column(ci), pf.schema.column(ci), fld.dtype,
-                        pf.schema_arrow.field(fld.name).type)
-                except HostFallback as hf:
+        for fld, ci in fetched.chunks:
+            if ci is None:
+                if fld.name in arrow_names:
+                    # a nested column: its leaves go by other
+                    # names; pyarrow assembles it on the host
                     host_cols.append(fld.name)
-                    fb_reasons.append(hf.reason)
+                    fb_reasons.append("nested")
+                continue  # schema evolution: nulls at assembly
+            try:
+                plans[fld.name] = plan_chunk(
+                    fetched, rg.column(ci), fetched.schema.column(ci),
+                    fld.dtype, fetched.schema_arrow.field(fld.name).type)
+            except HostFallback as hf:
+                host_cols.append(fld.name)
+                fb_reasons.append(hf.reason)
         host_rb = None
         if host_cols:
-            t = pf.read_row_group(g, columns=host_cols)
+            t = self._thread_pf(path).read_row_group(g, columns=host_cols)
             host_rb = t.combine_chunks().to_batches()[0] if t.num_rows \
                 else None
-        return (n_rows, plans, host_rb, self._part_values.get(path),
-                tuple(fb_reasons))
+        return (fetched.num_rows, plans, host_rb,
+                self._part_values.get(path), tuple(fb_reasons))
 
     def _assemble_device_batch(self, n_rows, plans, host_rb, part_vals,
                                clock=None, mm=None, chain=None,
@@ -939,10 +999,14 @@ class TpuFileScanExec(LeafExec):
                           max_rows: int):
         """Group consecutive planned row groups toward the target batch
         byte size (split-ordered, so output order is deterministic).
-        target_bytes <= 0 keeps one group per dispatch."""
+        target_bytes <= 0 keeps one group per dispatch. ``planned``
+        yields ``(item, next_rows)``: where the row count of the row
+        group that follows is already known (its footer is in) and says
+        it will not fit, the group is handed on NOW and not after that
+        row group's walk — the same groups, a walk earlier."""
         group: List = []
         rows = est = 0
-        for item in planned:
+        for item, next_rows in planned:
             if group and (rows + item[0] > max_rows
                           or not self._coalesce_compatible(group[0], item)
                           or not self._merge_fits(group, item)):
@@ -952,7 +1016,9 @@ class TpuFileScanExec(LeafExec):
             rows += item[0]
             est += self._decoded_estimate(item)
             if target_bytes <= 0 or est >= target_bytes \
-                    or rows >= max_rows:
+                    or rows >= max_rows \
+                    or (next_rows is not None
+                        and rows + next_rows > max_rows):
                 yield group
                 group, rows, est = [], 0, 0
         if group:
@@ -1001,13 +1067,15 @@ class TpuFileScanExec(LeafExec):
 
     def _execute_device_decode(self, ctx: ExecCtx, chain=None,
                                chain_key=None):
-        """The overlapped upload tunnel: row-group planning runs on the
-        reader pool, blob assembly + device_put + fused-decode dispatch
-        run on upload feeder thread(s) a bounded window ahead, and the
-        consumer computes on batch N while batch N+1 crosses the link —
-        the same feeder shape the legacy arrow path has, generalized
-        through pipeline.pipelined_map. In-flight batches are registered
-        with the device memory ledger until the consumer takes them.
+        """The overlapped upload tunnel: row groups are fetched on the
+        reader pool and walked one at a time on the feeders' source
+        thread (``planned``), blob assembly + device_put + fused-decode
+        dispatch run on upload feeder thread(s) a bounded window ahead,
+        and the consumer computes on batch N while batch N+1 crosses the
+        link — the same feeder shape the legacy arrow path has,
+        generalized through pipeline.pipelined_map. In-flight batches
+        are registered with the device memory ledger until the consumer
+        takes them.
         With ``chain`` (see ``fused_scan_execute``) the feeder
         dispatches the spliced decode+chain program and yields the
         chain's outputs; ``fusedDispatches``/``scanPrograms`` count the
@@ -1015,14 +1083,16 @@ class TpuFileScanExec(LeafExec):
 
         Every stage is timed at ONE site, by the span that is also its
         record (``scan.*`` in the trace JSON, ``spark:scan.*`` on the
-        profiler): ``scan.read`` per row group on the ``scan-plan``
-        pool; ``scan.assemble`` / ``arena_wait`` / ``upload`` /
-        ``dispatch`` per batch on the ``scan-upload`` feeders (a
-        ``StageClock`` each: ``assembleTime``, ``arenaWaitTime``, and
-        ``uploadTime`` = upload + dispatch); ``scan.wait`` where a
-        thread waits for the stage before it (``on=read``, by the
-        feeders' source thread: ``scanTime``; ``on=upload``, by the
-        consumer: ``uploadWaitTime``). Pool and feeder spans name their
+        profiler): ``scan.fetch`` per row group on the ``scan-fetch``
+        pool (``fetchTime``); ``scan.read``, the row group's walk, on
+        the feeders' source thread; ``scan.assemble`` / ``arena_wait`` /
+        ``upload`` / ``dispatch`` per batch on the ``scan-upload``
+        feeders (a ``StageClock`` each: ``assembleTime``,
+        ``arenaWaitTime``, and ``uploadTime`` = upload + dispatch);
+        ``scan.wait`` where a thread waits for the stage before it
+        (``on=fetch``, by the source thread: with its walks,
+        ``scanTime``; ``on=upload``, by the consumer:
+        ``uploadWaitTime``). Pool and feeder spans name their
         parent explicitly: the consumer's innermost span when the scan
         starts."""
         conf = ctx.conf
@@ -1030,6 +1100,8 @@ class TpuFileScanExec(LeafExec):
         parent = tracer.current_span_id()
         rows = ctx.metric(self, "numOutputRows")
         scan_t = ctx.metric(self, "scanTime")
+        fetch_t = ctx.metric(self, "fetchTime")
+        ahead_m = ctx.metric(self, "fetchAheadMax")
         asm_t = ctx.metric(self, "assembleTime")
         up_t = ctx.metric(self, "uploadTime")
         wait_t = ctx.metric(self, "uploadWaitTime")
@@ -1066,27 +1138,34 @@ class TpuFileScanExec(LeafExec):
         from .parquet_device import null_free_chunks
         mgr = DeviceMemoryManager.shared(conf)
         pool = concurrent.futures.ThreadPoolExecutor(
-            nthreads, thread_name_prefix="scan-plan")
+            nthreads, thread_name_prefix="scan-fetch")
 
         widths: List[Tuple[int, int]] = []  # per row group: read, file's
 
-        def read(path, g):
-            with tracer.span("scan.read", cat="scan", parent_id=parent,
+        def fetch(path, g):
+            with tracer.span("scan.fetch", cat="scan", parent_id=parent,
                              args={"file": os.path.basename(path),
-                                   "rg": g}) as sp:
-                item = self._plan_row_group(path, g)
-                cols = len(item[1]) + (item[2].num_columns
-                                       if item[2] is not None else 0)
-                file_cols = self._thread_pf(path).metadata.num_columns
-                widths.append((cols, file_cols))
-                sp.set(chunks=len(item[1]), bytes=sum(
-                    plan.encoded_bytes for plan in item[1].values()),
-                    columns=cols, file_columns=file_cols)
-            return item
+                                   "rg": g}, timed=True) as sp:
+                fetched = self._fetch_row_group(path, g)
+                sp.set(bytes=fetched.nbytes)
+            return path, g, fetched, sp.dur
 
         seen_nulls: set = set()  # columns that have shown a null
 
         def planned():
+            """The read schedule. A read is two kinds of work that want
+            opposite schedules. The FETCH (footer, one seek + read per
+            wanted chunk) is I/O that releases the interpreter lock: the
+            pool keeps up to ``depth`` row groups fetched or in flight,
+            in task order, which is what ``numThreads`` is for where
+            storage is slow. The WALK over the fetched bytes (page
+            headers, decompression, run headers) is Python under the
+            lock: a second one at once only delays the first (six q6
+            walks at once finish together at 0.3-0.5 s; in order, the
+            first is done after 0.07 s), so this thread walks ONE row
+            group at a time, in task order, and the first dispatch
+            follows the first walk while the second runs. Yields
+            ``(item, next_rows)`` for ``_coalesced_groups``."""
             pending: List = []
             it = iter(tasks)
 
@@ -1096,15 +1175,34 @@ class TpuFileScanExec(LeafExec):
                         p, g = next(it)
                     except StopIteration:
                         return
-                    pending.append(pool.submit(read, p, g))
+                    pending.append(pool.submit(fetch, p, g))
             topup()
             while pending:
                 with tracer.span("scan.wait", cat="scan",
-                                 parent_id=parent, args={"on": "read"},
+                                 parent_id=parent, args={"on": "fetch"},
                                  timed=True) as wait:
-                    item = pending.pop(0).result()
-                scan_t.value += wait.dur
+                    path, g, fetched, fetch_s = pending.pop(0).result()
                 topup()
+                # fetched and waiting behind this one: 0 = the walk
+                # waits for bytes; depth - 1 (the slot just topped up is
+                # still in flight) = fetching is never the limit
+                ahead = sum(f.done() for f in pending)
+                with tracer.span("scan.read", cat="scan", parent_id=parent,
+                                 args={"file": os.path.basename(path),
+                                       "rg": g, "ahead": ahead},
+                                 timed=True) as sp:
+                    item = self._plan_row_group(path, g, fetched)
+                    cols = len(item[1]) + (item[2].num_columns
+                                           if item[2] is not None else 0)
+                    file_cols = fetched.metadata.num_columns
+                    widths.append((cols, file_cols))
+                    sp.set(chunks=len(item[1]), bytes=sum(
+                        plan.encoded_bytes for plan in item[1].values()),
+                        columns=cols, file_columns=file_cols)
+                del fetched  # the plans hold what they need of it
+                scan_t.value += wait.dur + sp.dur
+                fetch_t.value += fetch_s
+                ahead_m.value = max(ahead_m.value, ahead)
                 # A column keeps the definition-level pass from its
                 # first chunk with a null on (ChunkPlan.has_nulls): over
                 # a scan the flags of k columns only rise, and row
@@ -1116,7 +1214,16 @@ class TpuFileScanExec(LeafExec):
                     if plan.has_nulls:
                         seen_nulls.add(name)
                     plan.has_nulls = name in seen_nulls
-                yield item
+                # the next row group's row count, where its footer is in
+                # already: the coalescer closes a group it cannot join
+                # without waiting for its walk
+                next_rows = None
+                head = pending[0] if pending else None
+                if head is not None and head.done() \
+                        and not head.cancelled() \
+                        and head.exception() is None:
+                    next_rows = head.result()[2].num_rows
+                yield item, next_rows
 
         inflight: set = set()  # ledger entries not yet handed over
         ilock = threading.Lock()

@@ -81,13 +81,14 @@ HISTORY_DIR = register(
 _TIME_METRICS = frozenset((
     "opTime", "spillTime", "uploadTime", "uploadWaitTime", "scanTime",
     "assembleTime", "arenaWaitTime", "downloadTime", "writeTime",
-    "concatTime", "ledgerWaitTime", "dispatchTime"))
+    "concatTime", "ledgerWaitTime", "dispatchTime", "fetchTime"))
 
 #: metrics that are identifiers/flags (fold by max across tasks), not
 #: accumulators (fold by sum): the fused-program membership id and the
-#: chain length are the same value on every task that executed the node
+#: chain length are the same value on every task that executed the node;
+#: the scan's fetchAheadMax is a maximum already
 _IDENTITY_METRICS = frozenset(("fusedInto", "fusedChainOps",
-                               "cpuFallback"))
+                               "cpuFallback", "fetchAheadMax"))
 
 
 # process-wide fused-stage completion watcher: ONE daemon thread per

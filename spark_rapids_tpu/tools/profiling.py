@@ -72,11 +72,21 @@ def profile_report(pp, ctx=None) -> str:
             if wait is not None and up.value > 0:
                 # uploadWaitTime is ALL consumer blocking on the next
                 # batch — when planning (scanTime) outweighs uploadTime
-                # the feeder was starved by the reader pool, not the
-                # host->device link, and uploadThreads is the wrong lever
+                # the feeder was starved by the reads, not the
+                # host->device link, and uploadThreads is the wrong
+                # lever. Which part of a read: a walk that never found a
+                # fetched row group waiting (fetchAheadMax 0) waited for
+                # storage; else the one-at-a-time page walk is the limit
                 hidden = max(0.0, 1.0 - wait.value / up.value)
+                ahead = ms.get("fetchAheadMax")
                 if hidden >= 0.5:
                     lever = "keep data device-resident between stages"
+                elif scan_v > up.value and ahead is not None \
+                        and ahead.value > 0:
+                    lever = ("the wait is bound by the page walk (one "
+                             "row group at a time under the interpreter "
+                             "lock), not by fetching: more reader "
+                             "threads or uploadThreads will not help")
                 elif scan_v > up.value:
                     lever = ("the wait is planning-bound — raise the "
                              "parquet multiThreadedRead.numThreads "

@@ -296,10 +296,10 @@ def test_a_member_that_raises_fails_the_query_and_leaves_nothing_behind(
     s = _session(CONF, {"t": paths})
     real = TpuFileScanExec._plan_row_group
 
-    def fail_one(self, path, g):
+    def fail_one(self, path, g, fetched):
         if getattr(self, "_slice", (0, 1))[0] == 2:
             raise OSError("member 2 cannot read its slice")
-        return real(self, path, g)
+        return real(self, path, g, fetched)
     monkeypatch.setattr(TpuFileScanExec, "_plan_row_group", fail_one)
     before = {t.name for t in threading.enumerate()}
     pp = _plan(s, "select k, count(*) c, sum(v) s from t group by k")

@@ -494,7 +494,8 @@ _SCAN_CONF = {"spark.sql.shuffle.partitions": "1",
               "spark.rapids.sql.scan.coalesceTargetBytes": "0",
               "spark.rapids.sql.scan.uploadThreads": "1"}
 
-_SPAN_TABLE = ("spark:query", "spark:admit", "spark:op", "spark:scan.read",
+_SPAN_TABLE = ("spark:query", "spark:admit", "spark:op",
+               "spark:scan.fetch", "spark:scan.read",
                "spark:scan.wait", "spark:scan.assemble",
                "spark:scan.arena_wait", "spark:scan.upload",
                "spark:scan.dispatch", "spark:download", "spark:finish")
@@ -538,8 +539,9 @@ def _scan_metrics(pp):
     by_name = {}
     for node_metrics in pp.last_ctx.metrics.values():
         for k, m in node_metrics.items():
-            if k in ("scanTime", "assembleTime", "uploadTime",
-                     "uploadWaitTime", "arenaWaitTime"):
+            if k in ("scanTime", "fetchTime", "fetchAheadMax",
+                     "assembleTime", "uploadTime", "uploadWaitTime",
+                     "arenaWaitTime"):
                 by_name[k] = by_name.get(k, 0.0) + m.value
     return by_name
 
@@ -603,23 +605,25 @@ def test_profiler_session_carries_every_span_of_a_parquet_query(
         at = s
         while at is not root:  # KeyError: a parent that is not there
             at = by_id[at[3]["parent"]]
-    # whose line each span is on: reads on the pool, the feeder's stages
-    # on a scan-upload thread, the rest on the caller's
+    # whose line each span is on: fetches on the pool, the walks all on
+    # the feeders' source thread (where it waits for the fetches), the
+    # feeder's stages on a scan-upload thread, the rest on the caller's
     line_of = lambda n: {s[4] for s in spans if s[0] == n}  # noqa: E731
     caller, = line_of("spark:query")
     for n in ("spark:admit", "spark:op", "spark:download", "spark:finish"):
         assert line_of(n) == {caller}
-    assert caller not in line_of("spark:scan.read")
-    assert any(name.startswith("scan-plan")
-               for _, name in line_of("spark:scan.read"))
+    assert any(name.startswith("scan-fetch")
+               for _, name in line_of("spark:scan.fetch"))
+    source, = line_of("spark:scan.read")
+    assert source != caller and source not in line_of("spark:scan.fetch")
     feeder = line_of("spark:scan.dispatch")
-    assert len(feeder) == 1 and caller not in feeder
+    assert len(feeder) == 1 and not feeder & {caller, source}
     assert next(iter(feeder))[1].startswith("scan-upload")
     for n in ("spark:scan.assemble", "spark:scan.arena_wait",
               "spark:scan.upload"):
         assert line_of(n) == feeder
     waits = {s[3]["on"]: s[4] for s in spans if s[0] == "spark:scan.wait"}
-    assert waits["upload"] == caller and waits["read"] != caller
+    assert waits["upload"] == caller and waits["fetch"] == source
     # what the spans carry: nine row groups read, nine programs
     # dispatched under the name they compile under, the node label with
     # its '#' replaced, the bytes handed to the device
@@ -630,6 +634,14 @@ def test_profiler_session_carries_every_span_of_a_parquet_query(
         and r["bytes"] > 0 for r in reads)
     assert {r["file"] for r in reads} == {f"lineitem-0{i}.parquet"
                                           for i in range(3)}
+    # ... each walked from what its fetch brought in (`bytes` there:
+    # the chunks as the file holds them), and saying how many fetched
+    # row groups waited behind it when it started
+    fetches = [s[3] for s in spans if s[0] == "spark:scan.fetch"]
+    assert sorted((f["file"], f["rg"]) for f in fetches) == \
+        sorted((r["file"], r["rg"]) for r in reads)
+    assert all(f["bytes"] > 0 for f in fetches)
+    assert all(0 <= r["ahead"] <= 8 for r in reads)
     programs = [s[3] for s in spans if s[0] == "spark:scan.dispatch"]
     assert len(programs) == 9 and all(
         p["program"] == "jit_scan_decode_chain" and p["fused"]
@@ -695,8 +707,12 @@ def test_scan_counters_are_their_spans_and_chrome_nests_them(tmp_path):
     m = _scan_metrics(pp)
     rel = 1e-5  # the JSON rounds a span to a thousandth of a microsecond
     assert m["scanTime"] == pytest.approx(total(
-        lambda s: s["name"] == "scan.wait" and s["args"]["on"] == "read"),
-        rel=rel)
+        lambda s: s["name"] == "scan.read" or s["name"] == "scan.wait"
+        and s["args"]["on"] == "fetch"), rel=rel)
+    assert m["fetchTime"] == pytest.approx(total(
+        lambda s: s["name"] == "scan.fetch"), rel=rel)
+    reads = [s for s in spans if s["name"] == "scan.read"]
+    assert m["fetchAheadMax"] == max(s["args"]["ahead"] for s in reads)
     assert m["uploadWaitTime"] == pytest.approx(total(
         lambda s: s["name"] == "scan.wait"
         and s["args"]["on"] == "upload"), rel=rel)
@@ -710,8 +726,8 @@ def test_scan_counters_are_their_spans_and_chrome_nests_them(tmp_path):
     query, = [s for s in spans if s["name"] == "query"]
     scan = [s for s in spans if s["cat"] == "scan"]
     assert {s["name"] for s in scan} == {
-        "scan.read", "scan.wait", "scan.assemble", "scan.arena_wait",
-        "scan.upload", "scan.dispatch"}
+        "scan.fetch", "scan.read", "scan.wait", "scan.assemble",
+        "scan.arena_wait", "scan.upload", "scan.dispatch"}
     assert all(s["parent_id"] == query["span_id"] for s in scan)
     for n in ("admit", "download", "finish"):
         s, = [s for s in spans if s["name"] == n]

@@ -164,10 +164,10 @@ def test_device_decode_feeder_exception_propagates(tmp_path, monkeypatch):
     p = _write_rg_file(tmp_path)
     orig = TpuFileScanExec._plan_row_group
 
-    def boom(self, path, g):
+    def boom(self, path, g, fetched):
         if g >= 2:
             raise OSError("disk gone")
-        return orig(self, path, g)
+        return orig(self, path, g, fetched)
 
     monkeypatch.setattr(TpuFileScanExec, "_plan_row_group", boom)
     scan = TpuFileScanExec([p])
