@@ -22,7 +22,13 @@ Attribution sources, chosen to avoid double counting:
   worker crashes and attribute per query, counters do neither;
 * scan chunks, fused dispatches, scan programs, per-operator
   rows/times: the query's OWN folded operator metrics — exact
-  per-query values, immune to concurrent queries in the process.
+  per-query values, immune to concurrent queries in the process;
+* the compile side of the split: ``split.jit_s`` (host seconds of
+  jax's trace, lowering and compile request, persistent-cache fetch
+  included) and ``fusion.jit_misses`` (programs traced anew, named
+  programs and eager primitives alike): registry counter deltas of the
+  ``rapids_jit_*`` families ``programs.py``'s listener feeds, so a
+  query running beside it in the process adds its own.
 
 ``finish`` never raises past its boundary and performs no device
 syncs: a telemetry failure must not fail (or slow) the query it
@@ -49,7 +55,10 @@ _SPILL_SLOTS: List[Tuple[str, str, str]] = [
     ("rapids_memory_disk_spill_bytes_total", "", "disk_write_bytes"),
     ("rapids_spill_read_bytes_total", "", "read_bytes"),
 ]
-_TRACKED = {name for name, _, _ in _BYTE_SLOTS + _SPILL_SLOTS}
+_JIT_SECONDS = "rapids_jit_host_seconds_total"
+_JIT_TRACES = "rapids_jit_traces_total"
+_TRACKED = {name for name, _, _ in _BYTE_SLOTS + _SPILL_SLOTS} \
+    | {_JIT_SECONDS, _JIT_TRACES}
 
 
 def _flatten(snap: Dict) -> Dict[Tuple[str, str], float]:
@@ -79,6 +88,13 @@ def _worker_totals(root: str) -> Dict[Tuple[str, str], float]:
 def _delta(now: Dict, base: Dict, name: str, label: str) -> int:
     d = now.get((name, label), 0.0) - base.get((name, label), 0.0)
     return max(0, int(d))
+
+
+def _family_delta(now: Dict, base: Dict, name: str) -> float:
+    """A family's growth over every label (a label first seen in
+    ``now`` grew from 0)."""
+    return sum(max(0.0, v - base.get(k, 0.0))
+               for k, v in now.items() if k[0] == name)
 
 
 def _device_kind() -> str:
@@ -256,6 +272,12 @@ class QueryAttribution:
         ops, scan, fusion, op_time, dispatch, reasons = \
             self._op_rollup(folded)
         fusion["jit_variants"] = _jit_variants(root)
+
+        def jit(name: str) -> float:
+            return (_family_delta(now, self._base, name)
+                    + _family_delta(wnow, self._worker_base, name))
+
+        fusion["jit_misses"] = int(jit(_JIT_TRACES))
         ladder_counts: Dict[str, int] = {}
         ladder = getattr(qctx, "ladder", None) if qctx is not None else None
         if ladder is not None and getattr(ladder, "counts", None):
@@ -284,7 +306,8 @@ class QueryAttribution:
             "admission_wait_s": round(float(
                 getattr(qctx, "admission_wait_s", 0.0) or 0.0), 6),
             "split": {"dispatch_s": round(dispatch, 6),
-                      "op_time_s": round(op_time, 6)},
+                      "op_time_s": round(op_time, 6),
+                      "jit_s": round(jit(_JIT_SECONDS), 6)},
             "ops": ops,
             "bytes": bytes_row,
             "spill": spill_row,

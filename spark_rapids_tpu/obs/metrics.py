@@ -11,9 +11,10 @@ Design points:
   GpuSemaphore/RapidsBufferCatalog are process singletons; their
   metrics are too);
 - **bounded label sets** — a family keeps at most ``MAX_CHILDREN``
-  distinct label combinations; overflow collapses into a single
-  ``__other__`` series so a runaway label (per-query ids, paths) cannot
-  leak memory;
+  distinct label combinations (a family that crosses a bounded label
+  with a fixed few may declare that multiple); overflow collapses into
+  a single ``__other__`` series so a runaway label (per-query ids,
+  paths) cannot leak memory;
 - recording is plain attribute arithmetic under a short lock — cheap
   enough to leave always-on; the *exporters* are the gated part:
   ``spark.rapids.metrics.port`` serves ``/metrics`` over HTTP and
@@ -142,11 +143,13 @@ class _Family:
 
     def __init__(self, kind: str, name: str, help_: str,
                  labelnames: Tuple[str, ...],
-                 buckets: Sequence[float] = DEFAULT_BUCKETS):
+                 buckets: Sequence[float] = DEFAULT_BUCKETS,
+                 max_children: int = MAX_CHILDREN):
         self.kind = kind
         self.name = name
         self.help = help_
         self.labelnames = labelnames
+        self.max_children = max_children
         self.buckets = tuple(buckets)
         # keep the family's bound list identical to its children's
         # (render zips them): _Histogram appends the +Inf terminal
@@ -164,8 +167,8 @@ class _Family:
         return _KINDS[self.kind]()
 
     def labels(self, *values):
-        """Child for one label combination; bounded — combination #65
-        and beyond share the ``__other__`` series."""
+        """Child for one label combination; bounded — combination
+        ``max_children + 1`` and beyond share the ``__other__`` series."""
         key = tuple(str(v) for v in values)
         if len(key) != len(self.labelnames):
             raise ValueError(
@@ -174,7 +177,7 @@ class _Family:
         with self._lock:
             child = self._children.get(key)
             if child is None:
-                if len(self._children) >= MAX_CHILDREN:
+                if len(self._children) >= self.max_children:
                     key = (_OTHER,) * len(self.labelnames)
                     child = self._children.get(key)
                     if child is None:
@@ -230,7 +233,8 @@ class MetricsRegistry:
 
     def _family(self, kind: str, name: str, help_: str,
                 labelnames: Sequence[str],
-                buckets: Sequence[float] = DEFAULT_BUCKETS) -> _Family:
+                buckets: Sequence[float] = DEFAULT_BUCKETS,
+                max_children: int = MAX_CHILDREN) -> _Family:
         with self._lock:
             f = self._families.get(name)
             if f is not None:
@@ -238,13 +242,16 @@ class MetricsRegistry:
                     raise ValueError(
                         f"metric {name} already registered as {f.kind}")
                 return f
-            f = _Family(kind, name, help_, tuple(labelnames), buckets)
+            f = _Family(kind, name, help_, tuple(labelnames), buckets,
+                        max_children)
             self._families[name] = f
             return f
 
     def counter(self, name: str, help_: str = "",
-                labelnames: Sequence[str] = ()) -> _Family:
-        return self._family("counter", name, help_, labelnames)
+                labelnames: Sequence[str] = (),
+                max_children: int = MAX_CHILDREN) -> _Family:
+        return self._family("counter", name, help_, labelnames,
+                            max_children=max_children)
 
     def gauge(self, name: str, help_: str = "",
               labelnames: Sequence[str] = ()) -> _Family:
@@ -284,6 +291,32 @@ QUERY_DURATION = REGISTRY.histogram(
     "collected result, by plan source (sql|plan) and execution tier "
     "(local|process).",
     ("source", "cluster"))
+
+# The host's side of every ``jax.jit`` cache miss, by program: the
+# ``fun_name`` jax.monitoring reports (a named program's registry name,
+# an eager primitive's own), tracing on or off. Fed by the one listener
+# of ``programs.py``; a scrape that shows ``fused_stage`` traced once a
+# query says every query re-jits it.
+JIT_TRACES = REGISTRY.counter(
+    "rapids_jit_traces_total",
+    "Programs traced to a jaxpr (jax.jit cache misses) by program: a "
+    "named program's registry name or an eager primitive's; a trace "
+    "nested inside another is the outer one's.",
+    ("program",))
+JIT_PHASES = ("trace", "lower", "compile")
+JIT_HOST_SECONDS = REGISTRY.counter(
+    "rapids_jit_host_seconds_total",
+    "Host seconds of jax.jit cache misses by program and phase: trace "
+    "(Python to a jaxpr), lower (jaxpr to an MLIR module), compile (the "
+    "backend compile request, the persistent-cache fetch included).",
+    ("program", "phase"),
+    # as many programs as the other two families, each in every phase
+    max_children=MAX_CHILDREN * len(JIT_PHASES))
+JIT_CACHE_HITS = REGISTRY.counter(
+    "rapids_jit_cache_hits_total",
+    "Backend compile requests served from jax's persistent compilation "
+    "cache, by program.",
+    ("program",))
 
 
 # --- Prometheus text exposition --------------------------------------------

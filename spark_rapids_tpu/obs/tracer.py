@@ -62,7 +62,7 @@ from .recorder import RECORDER as _FLIGHT, prune_oldest
 
 __all__ = ["TRACE_DIR", "TRACE_MAX_SPANS", "TRACE_MAX_FILES", "Span",
            "Tracer", "NULL_TRACER", "tracer_from_conf", "spans_to_chrome",
-           "load_chrome_trace", "PROFILER_PREFIX", "StageClock"]
+           "load_chrome_trace", "PROFILER_PREFIX", "StageClock", "LIVE"]
 
 #: every name this program writes to the profiler starts with it, so a
 #: reader tells the program's spans from the runtime's events by name
@@ -71,6 +71,18 @@ _WIRE_SAFE = str.maketrans({"#": ":", ",": ";"})
 
 
 _OS_NAMED = threading.local()
+
+
+class _Live(threading.local):
+    """The tracer of this thread's innermost open live span, ``None``
+    outside every one (and always under the null tracer): how
+    ``programs.named_jit``'s callable tells, in one attribute read,
+    whether a call of a named program opens a ``spark:program`` span."""
+
+    tracer = None
+
+
+LIVE = _Live()
 
 
 def _name_os_thread() -> None:
@@ -162,7 +174,7 @@ class _LiveSpan:
     and ``dur`` (seconds) once it has closed."""
 
     __slots__ = ("_tracer", "name", "cat", "span_id", "parent_id",
-                 "args", "kind", "dur", "_ts", "_t0", "_ann")
+                 "args", "kind", "dur", "_ts", "_t0", "_ann", "_outer")
 
     def __init__(self, tracer: "Tracer", name: str, cat: str,
                  parent_id: Optional[str], args: Optional[Dict],
@@ -195,14 +207,19 @@ class _LiveSpan:
         self._ann = TraceAnnotation(
             PROFILER_PREFIX + self.kind.translate(_WIRE_SAFE),
             **ids, **_wire(self.args or {}))
-        self._ts = time.time()
+        self._outer = LIVE.tracer
+        LIVE.tracer = self._tracer
         self._ann.__enter__()
+        # the two clocks read back to back: a child's ts + dur then
+        # stays inside its parent's
+        self._ts = time.time()
         self._t0 = time.perf_counter()
         return self
 
     def __exit__(self, *exc):
         self.dur = time.perf_counter() - self._t0
         self._ann.__exit__(*exc)
+        LIVE.tracer = self._outer
         stack = self._tracer._stack()
         if stack and stack[-1] == self.span_id:
             stack.pop()

@@ -4,14 +4,17 @@ Every query — collected plan or ``TpuProcessCluster.run_query``,
 whether it completed, was cancelled, degraded down the ladder, or
 crashed — leaves behind exactly ONE JSON row recording what it cost
 and why: tenant, plan/SQL fingerprints, ``device_kind``, admission
-wait, compile-vs-execute split, per-operator time/rows, bytes moved
-per transport (host file / ICI / gang-DCN), spill read+write bytes,
-scan device/fallback chunk counts, fused dispatch and JIT-variant
-counts, degradation rungs walked, and the classified cancel/fallback
-reasons.  The rows are the substrate the cost-model fitting (ROADMAP
-item 3) reads and the load harness (item 2) gates on; on a CPU-only
-host they are the *only* trustworthy regression signal (re-anchor
-note: structural counters, never wall time).
+wait, compile-vs-execute split (``split.jit_s``, the host's trace,
+lowering and compile seconds, beside ``dispatch_s`` and
+``op_time_s``), per-operator time/rows, bytes moved per transport
+(host file / ICI / gang-DCN), spill read+write bytes, scan
+device/fallback chunk counts, fused dispatch, JIT-variant and re-jit
+(``fusion.jit_misses``) counts, degradation rungs walked, and the
+classified cancel/fallback reasons.  The rows are the substrate the
+cost-model fitting (ROADMAP item 3) reads and the load harness (item 2)
+gates on; on a CPU-only host they are the *only* trustworthy
+regression signal (re-anchor note: structural counters, never wall
+time).
 
 Durability: rows append to sealed JSONL segments — every append
 rewrites the current segment through ``shuffle/integrity.py``'s

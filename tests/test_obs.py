@@ -73,6 +73,27 @@ def test_span_stack_is_per_thread():
     assert other["parent_id"] is None
 
 
+def test_live_holds_the_innermost_live_spans_tracer_per_thread():
+    """``tracer.LIVE``: what a named program's call reads to decide
+    whether it opens a span. Set by a live span for its extent, put back
+    as it closes, per thread; the null tracer never sets it."""
+    from spark_rapids_tpu.obs.tracer import LIVE
+    a, b = Tracer(), Tracer()
+    seen = []
+    assert LIVE.tracer is None
+    with a.span("outer"):
+        assert LIVE.tracer is a
+        with b.span("inner"):
+            assert LIVE.tracer is b
+            th = threading.Thread(target=lambda: seen.append(LIVE.tracer))
+            th.start()
+            th.join()
+        assert LIVE.tracer is a
+        with NULL_TRACER.span("off"), NULL_TRACER.span("t", timed=True):
+            assert LIVE.tracer is a
+    assert LIVE.tracer is None and seen == [None]
+
+
 def test_emit_deterministic_ids_and_absorb():
     t = Tracer(trace_id="abc", pid=0)
     sid = t.emit("attempt t1 a0", "attempt", ts=100.0, dur=2.0,
@@ -171,15 +192,21 @@ def test_family_redeclaration_idempotent_kind_checked():
         r.gauge("x")
 
 
-def test_bounded_label_sets_overflow_to_other():
-    from spark_rapids_tpu.obs.metrics import MAX_CHILDREN, _OTHER
+@pytest.mark.parametrize("bound", [None, 192])
+def test_bounded_label_sets_overflow_to_other(bound):
+    from spark_rapids_tpu.obs.metrics import (JIT_HOST_SECONDS, JIT_PHASES,
+                                              MAX_CHILDREN, _OTHER)
     r = MetricsRegistry()
-    c = r.counter("c", "", ("id",))
-    for i in range(MAX_CHILDREN + 10):
+    c = r.counter("c", "", ("id",), **({"max_children": bound}
+                                       if bound else {}))
+    bound = bound or MAX_CHILDREN
+    for i in range(bound + 10):
         c.labels(f"id{i}").inc()
     snap = r.snapshot()["c"]["samples"]
-    assert len(snap) == MAX_CHILDREN + 1
+    assert len(snap) == bound + 1
     assert snap[_OTHER] == 10  # the overflow collapsed into one series
+    # the re-jit's seconds keep MAX_CHILDREN programs in every phase
+    assert JIT_HOST_SECONDS.max_children == MAX_CHILDREN * len(JIT_PHASES)
 
 
 def test_prometheus_text_valid_per_checker():
@@ -498,7 +525,8 @@ _SPAN_TABLE = ("spark:query", "spark:admit", "spark:op",
                "spark:scan.fetch", "spark:scan.read",
                "spark:scan.wait", "spark:scan.assemble",
                "spark:scan.arena_wait", "spark:scan.upload",
-               "spark:scan.dispatch", "spark:download", "spark:finish")
+               "spark:scan.dispatch", "spark:download", "spark:finish",
+               "spark:program")
 
 
 def _lineitem_files(base, files=3, rows=1500, row_group=500, seed=7):
@@ -877,6 +905,23 @@ def _q3_star_files(base, seed=11):
     return paths
 
 
+def _q3_planner(base):
+    """A session over ``_q3_star_files`` and a function that plans the
+    benchmark's q3 text anew each call."""
+    from spark_rapids_tpu import TpuSession
+    from spark_rapids_tpu.planner import TpuOverrides
+    bench = os.path.join(os.path.dirname(os.path.dirname(
+        os.path.abspath(__file__))), "benchmark")
+    with open(os.path.join(bench, "queries", "tpcds", "q3.sql")) as f:
+        text = "\n".join(ln for ln in f.read().splitlines()
+                         if not ln.lstrip().startswith("--"))
+    s = TpuSession(dict(_SCAN_CONF,
+                        **{"spark.sql.shuffle.partitions": "1"}))
+    for name, paths in _q3_star_files(base).items():
+        s.register_table(name, s.read_parquet(paths))
+    return lambda: TpuOverrides(s.conf).apply(s.sql(text)._node)
+
+
 def test_q3_compiles_only_named_programs(tmp_path):
     """Every program a rehearsal-size TPC-DS q3 (two joins, a
     string-keyed group-by, order-by, limit) sends to the compiler is one
@@ -887,18 +932,8 @@ def test_q3_compiles_only_named_programs(tmp_path):
     here."""
     import jax.monitoring
 
-    from spark_rapids_tpu import TpuSession
-    from spark_rapids_tpu.planner import TpuOverrides
     from spark_rapids_tpu.programs import EAGER_OPS, PROGRAM_NAMES
-    bench = os.path.join(os.path.dirname(os.path.dirname(
-        os.path.abspath(__file__))), "benchmark")
-    with open(os.path.join(bench, "queries", "tpcds", "q3.sql")) as f:
-        text = "\n".join(ln for ln in f.read().splitlines()
-                         if not ln.lstrip().startswith("--"))
-    s = TpuSession(dict(_SCAN_CONF,
-                        **{"spark.sql.shuffle.partitions": "1"}))
-    for name, paths in _q3_star_files(tmp_path).items():
-        s.register_table(name, s.read_parquet(paths))
+    plan = _q3_planner(tmp_path)
     seen = []
 
     def listen(event, secs, **kw):
@@ -907,7 +942,7 @@ def test_q3_compiles_only_named_programs(tmp_path):
 
     jax.monitoring.register_event_duration_secs_listener(listen)
     try:
-        pp = TpuOverrides(s.conf).apply(s.sql(text)._node)
+        pp = plan()
         assert pp.collect().num_rows > 10
     finally:
         jax.monitoring.unregister_event_duration_listener(listen)
@@ -917,3 +952,196 @@ def test_q3_compiles_only_named_programs(tmp_path):
             "sort_batch"} <= names, names
     assert names <= PROGRAM_NAMES | EAGER_OPS, \
         names - PROGRAM_NAMES - EAGER_OPS
+
+
+# --- spark:program: the re-jit where it happens -----------------------------
+
+
+def _planner(query, base):
+    """Plans a rehearsal-size q6 or q3 anew each call, over files made
+    once (q6 at sizes no other test uses: its programs compile here)."""
+    if query == "q3":
+        return _q3_planner(base)
+    paths = _lineitem_files(base, rows=1203, row_group=401)
+    return lambda: _q6_plan(paths)
+
+
+def _program_spans(prof):
+    spans = _host_spans(prof)
+    by_id = {s[3]["span"]: s for s in spans}
+    return [s for s in spans if s[0] == "spark:program"], by_id
+
+
+@pytest.mark.parametrize("query", ["q6", "q3"])
+def test_every_named_program_call_is_a_program_span(tmp_path, monkeypatch,
+                                                    query):
+    """Under a profiler session every call of a named program on the
+    query's path (caller, feeders, fetch pool) is made inside a live span
+    and lies in a ``spark:program`` span on its thread, whose ``program``
+    is in the registry and whose parent is the span of the program that
+    made the call; a call inside another program's trace opens none."""
+    import collections
+
+    import jax
+
+    from spark_rapids_tpu import programs
+    from spark_rapids_tpu.obs.tracer import LIVE
+    calls = []
+    real = programs.NamedProgram.__call__
+
+    def spy(self, *a, **kw):
+        if programs._HERE.call is None and not programs._HERE.depth:
+            calls.append((self.name, LIVE.tracer is not None))
+        return real(self, *a, **kw)
+
+    monkeypatch.setattr(programs.NamedProgram, "__call__", spy)
+    pp = _planner(query, tmp_path)()
+    prof = str(tmp_path / "prof")
+    with jax.profiler.trace(prof):
+        assert pp.collect().num_rows >= 1
+    spans, by_id = _program_spans(prof)
+    assert calls and all(live for _, live in calls)
+    assert collections.Counter(s[3]["program"] for s in spans) == \
+        collections.Counter(name for name, _ in calls)
+    parents = collections.Counter()
+    for name, _, _, args, line in spans:
+        assert args["program"] in programs.PROGRAM_NAMES
+        assert args["miss"] in (0, 1) and args["cache_hits"] >= 0
+        parent = by_id[args["parent"]]
+        assert parent[0] != "spark:program" and parent[4] == line
+        parents[parent[0]] += 1
+    # the plan node: an operator's span names it; the decode programs
+    # are dispatched by the scan's stage span
+    assert parents["spark:op"] and parents["spark:scan.dispatch"]
+    ops = {by_id[a["parent"]][3].get("op") for _, _, _, a, _ in spans}
+    if query == "q3":
+        assert any(o and o.startswith("ShuffledHashJoinExec:") for o in ops)
+
+
+@pytest.mark.parametrize("query", ["q6", "q3"])
+def test_a_plan_made_anew_misses_once_a_program_and_again_never(
+        tmp_path, query):
+    """A plan made anew from the same text traces every program it
+    compiles: ``miss`` sums to the compile requests of its collect (2
+    for q6, 11 for q3, as on the chip), each miss with trace and lowering
+    seconds; the SAME plan collected again misses nothing and asks the
+    compiler for nothing."""
+    import jax
+    import jax.monitoring
+    plan = _planner(query, tmp_path)
+    assert plan().collect().num_rows >= 1  # every shape seen once
+    requests = []
+
+    def listen(event, secs, **kw):
+        if event == "/jax/core/compile/backend_compile_duration":
+            requests.append(kw["fun_name"])
+
+    pp = plan()
+    jax.monitoring.register_event_duration_secs_listener(listen)
+    try:
+        for k in (1, 2):
+            with jax.profiler.trace(str(tmp_path / f"prof{k}")):
+                assert pp.collect().num_rows >= 1
+            if k == 1:
+                anew, requests[:] = len(requests), []
+    finally:
+        jax.monitoring.unregister_event_duration_listener(listen)
+    spans, _ = _program_spans(str(tmp_path / "prof1"))
+    misses = [a for _, _, _, a, _ in spans if a["miss"]]
+    assert len(misses) == anew == {"q6": 2, "q3": 11}[query]
+    assert all(a["trace_s"] > 0 and a["lower_s"] > 0 and a["compile_s"] > 0
+               for a in misses)
+    assert all(a["trace_s"] == a["lower_s"] == a["compile_s"] == 0
+               for _, _, _, a, _ in spans if not a["miss"])
+    spans, _ = _program_spans(str(tmp_path / "prof2"))
+    assert spans and requests == [] and not [
+        a for _, _, _, a, _ in spans if a["miss"]]
+
+
+def test_untraced_program_calls_make_no_span_and_lower_as_before(
+        tmp_path, monkeypatch):
+    """No profiler and no trace directory: a named program's call makes
+    no span and no annotation (the tracer's thread-local is unset on
+    every calling thread), and ``named_jit(...).lower`` lowers the module
+    ``jit_<name>`` as the bare ``jax.jit`` did."""
+    import jax
+    import jax.numpy as jnp
+
+    from spark_rapids_tpu import programs
+    from spark_rapids_tpu.obs import tracer as tracer_mod
+    made, live = [], set()
+
+    class Spy(tracer_mod.TraceAnnotation):
+        def __init__(self, *a, **kw):
+            made.append(a)
+            super().__init__(*a, **kw)
+
+    real = programs.NamedProgram.__call__
+
+    def spy(self, *a, **kw):
+        live.add(tracer_mod.LIVE.tracer)
+        return real(self, *a, **kw)
+
+    monkeypatch.setattr(tracer_mod, "TraceAnnotation", Spy)
+    monkeypatch.setattr(programs.NamedProgram, "__call__", spy)
+    assert _q6_plan(_lineitem_files(tmp_path)).collect().num_rows == 1
+    assert made == [] and live == {None}
+    fn = programs.named_jit("sort_batch", lambda x: x * 2)
+    x = jnp.arange(5)
+    assert "module @jit_sort_batch" in fn.lower(x).as_text()
+    assert fn.trace(x).jaxpr is not None
+    assert fn.eval_shape(x).shape == (5,)
+    assert fn(x).tolist() == [0, 2, 4, 6, 8] and fn._cache_size() == 1
+    fn.clear_cache()
+    assert fn._cache_size() == 0
+    assert jax.jit(lambda x: x * 2).lower(x).as_text() \
+        .replace("jit__lambda", "jit_sort_batch") \
+        == fn.lower(x).as_text()
+
+
+def test_registry_counts_the_rejit_by_program_with_tracing_off():
+    """The ``rapids_jit_*`` families count every trace by program with
+    no tracer anywhere: a named program once a shape, its seconds by
+    phase; a jit traced inside its trace, or inside its lowering (a
+    scan's loop is traced again as it is lowered), is part of that
+    phase; an eager primitive under its own name; a warm call adds
+    nothing."""
+    import jax
+    import jax.numpy as jnp
+
+    from spark_rapids_tpu.obs.metrics import (JIT_HOST_SECONDS,
+                                              JIT_TRACES, REGISTRY)
+    from spark_rapids_tpu.obs.tracer import LIVE
+    from spark_rapids_tpu.programs import _HERE, named_jit
+
+    # a process that ran other tests may hold more than MAX_CHILDREN
+    # programs already, and a new one would count under __other__
+    REGISTRY.reset()
+
+    def traces(name):
+        return JIT_TRACES.labels(name).value
+
+    def nested_probe(x):
+        return x * 3
+
+    inner = jax.jit(nested_probe)
+    fn = named_jit("agg_single", lambda x: jax.lax.scan(
+        lambda c, v: (c + v, c), 0.0, inner(x))[1] + 1)
+    phases = [JIT_HOST_SECONDS.labels("agg_single", p).value
+              for p in ("trace", "lower", "compile")]
+    before = {n: traces(n) for n in ("agg_single", "nested_probe", "clip",
+                                     "less")}
+    assert LIVE.tracer is None
+    x = jnp.arange(1777.0)  # a shape no other test uses
+    fn(x)
+    fn(x)
+    assert traces("agg_single") == before["agg_single"] + 1
+    assert all(JIT_HOST_SECONDS.labels("agg_single", p).value > v
+               for p, v in zip(("trace", "lower", "compile"), phases))
+    fn(jnp.arange(1778.0))
+    assert traces("agg_single") == before["agg_single"] + 2
+    jnp.clip(jnp.arange(1779.0), 2.0, 9.0)
+    assert traces("clip") == before["clip"] + 1
+    assert [traces(n) for n in ("nested_probe", "less")] == \
+        [before["nested_probe"], before["less"]]
+    assert _HERE.depth == 0 and _HERE.call is None and _HERE.hits == 0

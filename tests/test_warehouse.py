@@ -153,6 +153,37 @@ def test_completed_row_attribution_consistent(tmp_path):
                                  "read_bytes"}
 
 
+def test_row_splits_out_the_rejit(tmp_path):
+    """``split.jit_s`` and ``fusion.jit_misses`` are the growth of the
+    registry's ``rapids_jit_*`` families over the query: a plan built
+    anew traces its programs, the same plan collected again traces
+    none. ``jit_variants``, which the drift sentinel reads, stays."""
+    from spark_rapids_tpu.obs.metrics import REGISTRY
+    conf = _conf(tmp_path)
+    rb = gen_table([IntegerGen(min_val=0, max_val=6, null_frac=0.0),
+                    LongGen(nullable=False)], 333, seed=5,
+                   names=["k", "v"])
+    pp = overrides(TpuHashAggregateExec(
+        [col("k")], [Alias(Sum(col("v")), "s")], HostBatchSourceExec([rb])),
+        conf)
+
+    def traces():
+        fam = REGISTRY.snapshot()["rapids_jit_traces_total"]["samples"]
+        return sum(fam.values())
+
+    before = traces()
+    pp.collect()
+    first = traces()
+    pp.collect()
+    first_row, again = read_rows(str(tmp_path))
+    assert first_row["fusion"]["jit_misses"] == first - before >= 1
+    assert first_row["split"]["jit_s"] > 0
+    assert again["fusion"]["jit_misses"] == traces() - first == 0
+    assert again["split"]["jit_s"] == 0
+    assert set(again["split"]) == {"dispatch_s", "op_time_s", "jit_s"}
+    assert "jit_variants" in again["fusion"]
+
+
 def test_exchange_write_side_rows_attributed(tmp_path):
     """BENCH_r07 regression: the AQE reader drives the exchange through
     materialize() (never execute()), so without write-side counting the
